@@ -123,6 +123,22 @@ def arrivals_for(sys: System, kind: str, T: int, seed: int = 7) -> np.ndarray:
     return trace_synthetic(rng, sys.rates, T + 64)
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads the variable
+    itself, so nothing else is set), else ``<checkout>/.jax_cache`` — a fixed
+    path, so that later runs from this checkout find what earlier ones
+    compiled. Entry points call this; importing ``repro`` never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+
+        path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 class timer:
     def __enter__(self):
         self.t0 = time.perf_counter()

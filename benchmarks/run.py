@@ -9,7 +9,9 @@ machine-readable JSON under one shared schema (``benchmarks/common.py``) to
 Set REPRO_BENCH_FULL=1 for the full (paper-scale) sweeps. ``--profile DIR``
 wraps the run in span tracing (``repro.obs.trace``) plus ``jax.profiler``,
 writing a Perfetto-loadable ``chrome_trace.json`` (and the XLA profile) to
-DIR (DESIGN.md §14).
+DIR (DESIGN.md §14). JAX's persistent compile cache is on
+(``benchmarks.common.enable_compile_cache``). A section that raises prints
+an ``ERROR`` row and the driver goes on, then exits 1.
 """
 from __future__ import annotations
 
@@ -20,7 +22,7 @@ import time
 
 def main() -> None:
     from . import disruption, paper_figures, serving_fleet, systems_bench, workload
-    from .common import write_bench_json
+    from .common import enable_compile_cache, write_bench_json
 
     sections = [
         ("workload", workload.workload_bench),
@@ -46,6 +48,7 @@ def main() -> None:
                     help="write span + jax.profiler traces to DIR (DESIGN.md §14)")
     args = ap.parse_args()
     only = args.only
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
 
     profile_ctx = None
     if args.profile:
@@ -62,6 +65,7 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for name, fn in sections:
         if only and only not in name:
             continue
@@ -69,8 +73,9 @@ def main() -> None:
         try:
             for row in fn():
                 print(row.csv(), flush=True)
-        except Exception as e:  # noqa: BLE001 — report and continue
+        except Exception as e:  # noqa: BLE001 — report, go on, exit 1 at the end
             print(f"{name},nan,ERROR:{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
     write_bench_json("BENCH_cohort.json", "REPRO_BENCH_COHORT_JSON",
                      systems_bench.COHORT_BENCH)
     write_bench_json("BENCH_disruption.json", "REPRO_BENCH_DISRUPTION_JSON",
@@ -89,6 +94,9 @@ def main() -> None:
         print(f"# profile: spans -> {out}; XLA profile -> {args.profile}",
               file=sys.stderr)
     print(f"# total {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"# failed sections: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

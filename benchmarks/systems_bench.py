@@ -4,11 +4,6 @@ the instance-sharded cohort engine (DESIGN.md §13), kernels, MoE routers,
 and the POTUS serving dispatcher."""
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
 import time
 
 import jax
@@ -263,87 +258,64 @@ def _cohort_grid_row() -> list[Row]:
 
 def _sharded_probe(I_target: int, T: int, age_cap: int, n_devices: int,
                    sharded: bool, reps: int = 2) -> dict:
-    """One cohort-fused measurement in a fresh subprocess.
+    """One cohort-fused measurement in this process: warm wall seconds (min
+    over ``reps`` post-compile runs) plus the per-slot cross-device payload
+    from ``cohort_slot_payload_floats``. Sharded runs go over
+    ``instance_mesh(I, devices=jax.devices()[:n_devices])``, so one process
+    drives every shard count the host's devices allow."""
+    from repro.core.cohort_fused import _run_cohort_fused_impl
+    from repro.core.sharded import cohort_slot_payload_floats
 
-    jax locks the device count at first init, so every shard count needs
-    its own process with ``--xla_force_host_platform_device_count`` (same
-    pattern as tests/test_distributed.py). The child prints a JSON row as
-    its last stdout line: warm wall seconds (min over ``reps`` post-compile
-    runs) plus the per-slot cross-device payload from
-    ``cohort_slot_payload_floats``.
-    """
-    code = textwrap.dedent(f"""
-        import json, time
-        import numpy as np
-        import jax
-        from benchmarks.systems_bench import _cohort_fleet
-        from repro.core import (EngineSpec, container_costs, fat_tree,
-                                feasible_rates, poisson_arrivals, simulate)
-        from repro.core.sharded import cohort_slot_payload_floats, instance_mesh
+    topo = _cohort_fleet(I_target)
+    I = topo.n_instances
+    server_dist, _ = fat_tree(4)
+    net = container_costs(f"cohort-fleet-{I}", server_dist, containers_per_server=8)
+    rng = np.random.default_rng(0)
+    placement = rng.integers(0, net.n_containers, I).astype(np.int32)
+    arr = poisson_arrivals(rng, feasible_rates(topo, utilization=0.85), T + 8)
+    mesh = instance_mesh(I, devices=jax.devices()[:n_devices]) if sharded else None
+    cfg = SimConfig(scheduler="potus", V=2.0, window=0)
 
-        topo = _cohort_fleet({I_target})
-        I = topo.n_instances
-        server_dist, _ = fat_tree(4)
-        net = container_costs(f"cohort-fleet-{{I}}", server_dist,
-                              containers_per_server=8)
-        rng = np.random.default_rng(0)
-        placement = rng.integers(0, net.n_containers, I).astype(np.int32)
-        rates = feasible_rates(topo, utilization=0.85)
-        arr = poisson_arrivals(rng, rates, {T} + 8)
-        spec = EngineSpec(topo=topo, net=net, placement=placement,
-                          arrivals=arr, T={T}, engine="cohort-fused",
-                          scheduler="potus", V=2.0, window=0,
-                          age_cap={age_cap}, sharded={sharded})
-        t0 = time.perf_counter()
-        res = simulate(spec)  # trace + compile + first run
-        compile_s = time.perf_counter() - t0
-        times = []
-        for _ in range({reps}):
-            t0 = time.perf_counter()
-            res = simulate(spec)
-            times.append(time.perf_counter() - t0)
-        n_shards = instance_mesh(I).shape["i"] if {sharded} else 1
-        atot = {age_cap} + 0 + 1  # age_cap + window + 1
-        print(json.dumps(dict(
-            I=int(I), devices=jax.device_count(), n_shards=int(n_shards),
-            wall_s=min(times), compile_s=compile_s,
-            payload_floats=int(cohort_slot_payload_floats(
-                I, topo.n_components, net.n_containers, atot, n_shards)),
-            C=int(topo.n_components), K=int(net.n_containers),
-            avg_backlog=float(np.mean(np.asarray(res.backlog))))))
-    """)
-    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                          text=True, env=env, cwd=root, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded probe failed (I={I_target}, devices={n_devices}, "
-            f"sharded={sharded}):\n{proc.stderr[-3000:]}")
-    return json.loads(proc.stdout.splitlines()[-1])
+    def run():
+        return _run_cohort_fused_impl(topo, net, placement, arr, None, T, cfg,
+                                      age_cap=age_cap, mesh=mesh)
+
+    compile_s = _timed(run)  # trace + compile + first run
+    res = None
+    times = []
+    for _ in range(reps):
+        with timer() as t:
+            res = run()
+        times.append(t.dt)
+    n_shards = mesh.shape["i"] if sharded else 1
+    return dict(
+        I=int(I), devices=n_shards, n_shards=int(n_shards),
+        wall_s=min(times), compile_s=compile_s,
+        payload_floats=int(cohort_slot_payload_floats(
+            I, topo.n_components, net.n_containers, age_cap + 1, n_shards)),
+        C=int(topo.n_components), K=int(net.n_containers),
+        avg_backlog=float(np.mean(np.asarray(res.backlog))))
 
 
 def cohort_sharded_scale() -> list[Row]:
     """Strong/weak scaling of the instance-sharded one-dispatch engine
-    (DESIGN.md §13) over forced host CPU devices.
+    (DESIGN.md §13) over this process's devices; shard counts past
+    ``jax.device_count()`` are skipped.
 
     Strong tier: fixed fleet (I=16384), 1 -> 4 shards, plus a dense
-    (non-``shard_map``) baseline in an identical 1-device subprocess;
-    ci.yml's bench smoke asserts the best sharded wall time stays within
-    10% of dense — at one shard every collective is the identity, so
-    sharding must cost ~nothing. Weak tier: fixed instances *per shard*,
-    the fleet growing with the mesh up to I=131072 at 4 shards.
+    (non-``shard_map``) baseline; ci.yml's bench smoke asserts the best
+    sharded wall time stays within 10% of dense — at one shard every
+    collective is the identity, so sharding must cost ~nothing. Weak tier:
+    fixed instances *per shard*, the fleet growing with the mesh up to
+    I=131072 at 4 shards.
 
     Every row reports the per-slot cross-device payload (floats) from
     ``cohort_slot_payload_floats`` — the O(I·C)-bounded collective traffic
     argued in §13.2 (atot and K are horizon/network constants, so the
-    I·atot landing term dominates and payload/IC stays bounded). Forced
-    host devices share this machine's cores, so strong-scaling wall times
-    measure shard_map + collective overhead rather than real speedup; the
-    honest claims here are the payload bound and the zero-overhead
-    single-shard row, with real distribution covered by the 4-device
-    differential in tests/test_distributed.py.
+    I·atot landing term dominates and payload/IC stays bounded). Wall times
+    are those of whatever backend runs the process; only a TPU run is a
+    device number, and real distribution is checked by the 4-device
+    differential in tests/test_distributed.py and ``chip_smoke.py --chips 4``.
     """
     rows: list[Row] = []
     age_cap = 4
@@ -351,7 +323,8 @@ def cohort_sharded_scale() -> list[Row]:
     # --- strong scaling: fixed fleet, growing mesh ---------------------------
     T_s = 4 if SMOKE else 16
     I_strong = 16384
-    strong_shards = (1, 4) if SMOKE else (1, 2, 4)
+    n_dev = jax.device_count()
+    strong_shards = [n for n in ((1, 4) if SMOKE else (1, 2, 4)) if n <= n_dev]
     dense = _sharded_probe(I_strong, T_s, age_cap, 1, sharded=False)
     rows.append(Row(f"cohort_sharded/strong/dense/I{dense['I']}",
                     dense["wall_s"] / T_s * 1e6,
@@ -378,7 +351,7 @@ def cohort_sharded_scale() -> list[Row]:
     # --- weak scaling: fixed instances per shard -----------------------------
     T_w = 2 if SMOKE else 6
     per_shard = 2048 if SMOKE else 32768
-    weak_shards = (1, 4) if SMOKE else (1, 2, 4)
+    weak_shards = [n for n in ((1, 4) if SMOKE else (1, 2, 4)) if n <= n_dev]
     base_wall = None
     for n in weak_shards:
         p = _sharded_probe(per_shard * n, T_w, age_cap, n, sharded=True)

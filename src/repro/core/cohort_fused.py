@@ -329,7 +329,7 @@ def _fused_step(
             )
             land = land.at[cs:ce].add(jax.lax.dot_general(
                 ratio_b, d_land, (((0,), (0,)), ((), ())),
-                preferred_element_type=q_rem.dtype,
+                precision=jax.lax.Precision.HIGHEST, preferred_element_type=q_rem.dtype,
             ))
 
     # -- 4. land last slot's transit, serve bolts ----------------------------
@@ -343,7 +343,9 @@ def _fused_step(
     # is accumulator column ``t + b`` (the accumulator spans the chunk's
     # global source-slot range [t0 - age_cap, t0 + Tc + W]; the host driver
     # adds each chunk's slab at offset t0 - age_cap, DESIGN.md §11.2)
-    cmass = comp_onehot.T @ (served_b * term_f[:, None])  # (C, Atot)
+    # full f32 (mass-carrying): the TPU's default precision rounds through bf16
+    cmass = jnp.dot(comp_onehot.T, served_b * term_f[:, None],
+                    precision=jax.lax.Precision.HIGHEST)  # (C, Atot)
     resp_per_b = jnp.maximum(
         age_cap - jnp.arange(Atot, dtype=q_rem.dtype), 0.0
     )  # clip(t - s, 0); saturated mass reports age_cap

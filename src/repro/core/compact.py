@@ -33,10 +33,12 @@ the documented POTUS split caveat, DESIGN.md §12).
 Every function here is pure ``jnp`` on plain arrays so the identical code
 runs (a) under the engine's ``lax.scan`` (XLA path) and (b) inside the Pallas
 fused-slot/megakernel bodies (``kernels/potus_slot.py``). ``kernel_safe=True``
-swaps the few ops Pallas TPU cannot lower — scatter/gather and ``lax.sort`` —
-for one-hot contractions, dynamic slices, and the O(C²) precedence-rank
-water-fill (the same substitution ``kernels/potus_schedule.py`` makes);
-both variants agree bitwise on the dyadic tier and to 1 ulp elsewhere.
+swaps the ops Mosaic cannot lower — gathers, scatters, ``cumsum``, slices at
+a traced offset and ``lax.sort`` — for masked per-component passes, one-hot
+contractions and placements, and the O(C²) precedence-rank water-fill (the
+same substitution ``kernels/potus_schedule.py`` makes), listed in DESIGN.md
+§12.2; both variants agree bitwise on the dyadic tier and to 1 ulp
+elsewhere.
 
 **Instance sharding** (DESIGN.md §13): the same row-independence that powers
 the collapse makes the decision shard over an instance mesh. With
@@ -77,6 +79,10 @@ __all__ = [
 _EPS = 1e-12  # same negligible-mass threshold as the engines' FIFOs
 _INF = jnp.inf
 _BIG = 1e30  # finite stand-in for +inf ahead of one-hot contractions (0*inf = NaN)
+#: contractions that move tuple mass or select values through a one-hot run
+#: at full f32 precision: the TPU's default precision rounds f32 operands
+#: through bf16, which would break mass conservation there
+_HI = jax.lax.Precision.HIGHEST
 
 #: schedulers with a compact one-dispatch decision (``potus-loop`` keeps the
 #: dense reference path in ``core.cohort_fused``)
@@ -110,19 +116,37 @@ def _onehot_cols(idx: jax.Array, n: int, dtype) -> jax.Array:
     return (idx[..., None] == iota).astype(dtype)
 
 
+def _row(x: jax.Array, kernel_safe: bool) -> jax.Array:
+    """(n,) -> (1, n). Inside a kernel a vector reduced along the lanes lives
+    down the sublanes, and Mosaic cannot broadcast it across them; an
+    explicit 2-D transpose of the column moves it instead."""
+    return x[:, None].T if kernel_safe else x[None, :]
+
+
+def _vsum(x: jax.Array, kernel_safe: bool) -> jax.Array:
+    """Total of a (n,) vector; Mosaic reduces a column, not a bare vector."""
+    return x[:, None].sum() if kernel_safe else x.sum()
+
+
 def _colmin_per_comp(t1: jax.Array, inst_comp: jax.Array, C: int, kernel_safe: bool):
     """Per-component column reduction of ``t1`` (K, I): value min ``M`` (K, C)
     and lowest-index argmin ``J`` (K, C); ``I`` where a component is empty."""
     K, I = t1.shape
     if kernel_safe:
-        oh = _onehot_cols(inst_comp, C, jnp.bool_)  # (I, C)
-        # _BIG, not inf: M flows through one-hot contractions downstream
-        M = jnp.min(jnp.where(oh[None], t1[:, :, None], jnp.asarray(_BIG, t1.dtype)),
-                    axis=1)
+        # one masked (K, I) pass per component: C is small and static, and
+        # selects move values without arithmetic, so M, J match the scatter
+        # path bitwise (_BIG, not inf: M flows into one-hot contractions)
         iota_i = jax.lax.broadcasted_iota(jnp.int32, (K, I), 1)
-        hit = jnp.where(t1 == M[:, inst_comp], iota_i, I)
-        J = jnp.min(jnp.where(oh[None], hit[:, :, None], I), axis=1)
-        return M, J
+        big = jnp.asarray(_BIG, t1.dtype)
+        comp_row = _row(inst_comp, True)  # (1, I)
+        m_cols, j_cols = [], []
+        for c in range(C):
+            in_c = comp_row == c
+            m_c = jnp.min(jnp.where(in_c, t1, big), axis=1, keepdims=True)  # (K, 1)
+            j_c = jnp.min(jnp.where(in_c & (t1 == m_c), iota_i, I), axis=1, keepdims=True)
+            m_cols.append(m_c)
+            j_cols.append(j_c)
+        return jnp.concatenate(m_cols, axis=1), jnp.concatenate(j_cols, axis=1)
     M = jnp.full((K, C), _INF, t1.dtype).at[:, inst_comp].min(t1)
     hit = jnp.where(t1 == M[:, inst_comp], jnp.arange(I, dtype=jnp.int32)[None, :], I)
     J = jnp.full((K, C), I, jnp.int32).at[:, inst_comp].min(hit)
@@ -135,7 +159,7 @@ def _rows_of(A: jax.Array, inst_cont: jax.Array, kernel_safe: bool) -> jax.Array
     ``A`` must be finite: ``0 * inf`` would poison the contraction."""
     if kernel_safe:
         oh = _onehot_cols(inst_cont, A.shape[0], A.dtype)  # (I, K)
-        return jax.lax.dot_general(oh, A, (((1,), (0,)), ((), ())),
+        return jax.lax.dot_general(oh, A, (((1,), (0,)), ((), ())), precision=_HI,
                                    preferred_element_type=A.dtype)
     return A[inst_cont]
 
@@ -144,7 +168,7 @@ def _u_cols(U: jax.Array, inst_cont: jax.Array, kernel_safe: bool) -> jax.Array:
     """(K, I) = U[:, k_j]."""
     if kernel_safe:
         oh = _onehot_cols(inst_cont, U.shape[0], U.dtype)  # (I, K)
-        return jax.lax.dot_general(U, oh, (((1,), (1,)), ((), ())),
+        return jax.lax.dot_general(U, oh, (((1,), (1,)), ((), ())), precision=_HI,
                                    preferred_element_type=U.dtype)
     return U[:, inst_cont]
 
@@ -158,10 +182,10 @@ def _u_col_sums(U: jax.Array, cp: CompactProblem, kernel_safe: bool,
     dense column order — invisible on the dyadic tier, identity on 1 shard).
     """
     C = cp.comp_count.shape[0]
-    u_cols = _u_cols(U, cp.inst_cont, kernel_safe) * cp.alive[None, :]  # (K, I)
+    u_cols = _u_cols(U, cp.inst_cont, kernel_safe) * _row(cp.alive, kernel_safe)  # (K, I)
     if kernel_safe:
         oh = _onehot_cols(cp.inst_comp, C, U.dtype)  # (I, C)
-        out = jax.lax.dot_general(u_cols, oh, (((1,), (0,)), ((), ())),
+        out = jax.lax.dot_general(u_cols, oh, (((1,), (0,)), ((), ())), precision=_HI,
                                   preferred_element_type=U.dtype)
     else:
         out = jnp.zeros((U.shape[0], C), U.dtype).at[:, cp.inst_comp].add(u_cols)
@@ -210,16 +234,15 @@ def _fill_rows_rank(m, j_c, budget, gamma):
     """(I, C) precedence-rank water-fill — the sort-free equivalent used
     inside kernels (same substitution as ``kernels/potus_schedule.py``):
     entry d precedes e iff ``(m_d, j_d) < (m_e, j_e)`` lexicographically, so
-    the budget mass ahead of each entry is one masked contraction instead of
-    a cumsum over a sorted axis. Agrees with the sort path bitwise whenever
-    the prefix sums round identically (always on the dyadic tier)."""
-    prec = (m[:, :, None] < m[:, None, :]) | (
-        (m[:, :, None] == m[:, None, :]) & (j_c[:, :, None] < j_c[:, None, :])
-    )  # (I, C, C): [i, d, e] = entry d precedes entry e
-    before = jax.lax.dot_general(
-        budget[:, None, :], prec.astype(budget.dtype),
-        (((2,), (1,)), ((0,), (0,))), preferred_element_type=budget.dtype,
-    )[:, 0, :]  # (I, C) = sum_d budget[i, d] * prec[i, d, e]
+    the budget mass ahead of each entry is a masked sum over the C entries
+    (one (I, C) pass each, C static) instead of a cumsum over a sorted axis.
+    Agrees with the sort path bitwise whenever the prefix sums round
+    identically (always on the dyadic tier)."""
+    before = jnp.zeros_like(budget)
+    for d in range(m.shape[1]):
+        m_d, j_d = m[:, d:d + 1], j_c[:, d:d + 1]
+        prec_d = (m_d < m) | ((m_d == m) & (j_d < j_c))  # entry d precedes e
+        before = before + jnp.where(prec_d, budget[:, d:d + 1], 0.0)
     after = before + budget
     g = gamma[:, None]
     return jnp.minimum(after, g) - jnp.minimum(before, g)
@@ -235,8 +258,8 @@ def _potus_decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
     # _BIG stands in for +inf so downstream one-hot contractions stay NaN-free;
     # it only ever reaches entries whose budget is 0.
     big = jnp.asarray(_BIG, U.dtype)
-    t1 = jnp.where((cp.alive > 0.0)[None, :],
-                   V * _u_cols(U, cp.inst_cont, kernel_safe) + q_in[None, :], big)
+    t1 = jnp.where(_row(cp.alive, kernel_safe) > 0.0,
+                   V * _u_cols(U, cp.inst_cont, kernel_safe) + _row(q_in, kernel_safe), big)
     M, J = _colmin_per_comp(t1, cp.inst_comp, C, kernel_safe)
     if axis is not None:
         # fold the shard-local (M, J) into the global cheapest candidate:
@@ -266,12 +289,20 @@ def _potus_decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
         k_j = _owner_gather(J, cp.inst_cont, off, I, U.shape[0] - 1, axis)  # (K, C)
         u_point = U[cp.inst_cont[:, None], _rows_of(k_j, cp.inst_cont, False)]
     elif kernel_safe:
-        oh_j = _onehot_cols(j_c, I, U.dtype)  # (I, C, I); index I -> all-zero
-        k_jc = jnp.sum(oh_j * cp.inst_cont.astype(U.dtype)[None, None, :],
-                       axis=-1).astype(jnp.int32)  # (I, C); 0 where j_c == I
-        u_rows = _rows_of(U, cp.inst_cont, True)  # (I, K) = U[k_i, :]
-        u_point = jnp.sum(_onehot_cols(k_jc, U.shape[0], U.dtype)
-                          * u_rows[:, None, :], axis=-1)  # fill is 0 where j_c == I
+        # U[k, container of J[k, c]] per (container, component), then the
+        # row lift: two masked (K, ·) passes per component, exact selects
+        K = U.shape[0]
+        cont_row = _row(cp.inst_cont.astype(U.dtype), True)  # (1, I)
+        iota_i = jax.lax.broadcasted_iota(jnp.int32, (K, I), 1)
+        iota_k = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1).astype(U.dtype)
+        u_tgt = []
+        for cc in range(C):
+            k_t = jnp.sum(jnp.where(J[:, cc:cc + 1] == iota_i, cont_row, 0.0),
+                          axis=1, keepdims=True)  # (K, 1); 0 where J == I
+            u_tgt.append(jnp.sum(jnp.where(k_t == iota_k, U, 0.0), axis=1, keepdims=True))
+        # fill is 0 wherever j_c is not J's target, so the rows agree with
+        # the gather below on every entry the cost reads
+        u_point = _rows_of(jnp.concatenate(u_tgt, axis=1), cp.inst_cont, True)
     else:
         jc_safe = jnp.minimum(j_c, I - 1)
         u_point = U[cp.inst_cont[:, None], cp.inst_cont[jc_safe]]
@@ -426,16 +457,10 @@ def _to_dense(c: StepConsts, x_cmp: jax.Array, kernel_safe: bool) -> jax.Array:
     return jnp.zeros((I, C + 1), x_cmp.dtype).at[rows, c.succ_map].add(x_cmp)[:, :C]
 
 
-def _to_dense3(c: StepConsts, x_cmp: jax.Array, kernel_safe: bool) -> jax.Array:
-    """(I, S, A) -> (I, C, A)."""
+def _to_dense3(c: StepConsts, x_cmp: jax.Array) -> jax.Array:
+    """(I, S, A) -> (I, C, A); the kernel landing works per slot instead."""
     I, S, A = x_cmp.shape
     C = c.comp_onehot.shape[1]
-    if kernel_safe:
-        out = jnp.zeros((I, C, A), x_cmp.dtype)
-        for s in range(S):
-            oh = _onehot_cols(c.succ_map[:, s], C, x_cmp.dtype)  # (I, C)
-            out = out + oh[:, :, None] * x_cmp[:, s, :][:, None, :]
-        return out
     rows = jnp.arange(I)[:, None]
     return jnp.zeros((I, C + 1, A), x_cmp.dtype).at[rows, c.succ_map, :].add(x_cmp)[:, :C]
 
@@ -454,9 +479,47 @@ def _to_cmp(c: StepConsts, x: jax.Array, kernel_safe: bool) -> jax.Array:
     return jnp.take_along_axis(x, gather_idx, axis=1) * c.valid_cmp
 
 
-def _drain_ages(buckets: jax.Array, amount: jax.Array) -> jax.Array:
-    # local copy of cohort_fused.drain_ages (import would be circular)
-    cum = jnp.cumsum(buckets, axis=-1)
+def _land_kernel(c: StepConsts, j_point, w_pt, w_ev, d_land):
+    """Kernel-safe landing, one successor slot at a time: the point part of
+    slot ``s`` lands through an (I, I) one-hot of its target, the even part
+    folds into (C, Atot) per-component sums. Returns ``(land, ev_cb)`` —
+    the scatter landing and the even-spread einsum of the XLA path."""
+    I, S, Atot = d_land.shape
+    C = w_pt.shape[1]
+    dt = d_land.dtype
+    dims = (((0,), (0,)), ((), ()))  # contract the source-instance axis
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (I, C), 1)
+    iota_i = jax.lax.broadcasted_iota(jnp.int32, (I, I), 1)
+    land = jnp.zeros((I, Atot), dt)
+    ev_cb = jnp.zeros((C, Atot), dt)
+    for s in range(S):  # S is tiny and static
+        in_s = c.succ_map[:, s:s + 1] == iota_c  # (I, C); no column for C
+        d_s = d_land[:, s, :]  # (I, Atot)
+        w_pt_s = jnp.sum(jnp.where(in_s, w_pt, 0.0), axis=1, keepdims=True)
+        j_s = jnp.min(jnp.where(in_s, j_point, I), axis=1, keepdims=True)  # (I, 1)
+        oh_t = (j_s == iota_i).astype(dt)  # (I, I); target I -> zero row
+        land = land + jax.lax.dot_general(oh_t, w_pt_s * d_s, dims, precision=_HI,
+                                          preferred_element_type=dt)
+        w_ev_c = jnp.where(in_s, w_ev, 0.0)  # (I, C)
+        ev_cb = ev_cb + jax.lax.dot_general(w_ev_c, d_s, dims, precision=_HI,
+                                            preferred_element_type=dt)
+    return land, ev_cb
+
+
+def _drain_ages(buckets: jax.Array, amount: jax.Array, kernel_safe: bool) -> jax.Array:
+    # local copy of cohort_fused.drain_ages (import would be circular); Pallas
+    # TPU has no cumsum, so kernels take the prefix sum as a contraction with
+    # an upper-triangular ones matrix (exact sums of dyadic masses)
+    if kernel_safe:
+        A = buckets.shape[-1]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (A, A), 0)
+        tri = (rows <= jax.lax.broadcasted_iota(jnp.int32, (A, A), 1)).astype(buckets.dtype)
+        flat = buckets.reshape(-1, A)
+        cum = jax.lax.dot_general(flat, tri, (((1,), (0,)), ((), ())),
+                                  precision=_HI,
+                                  preferred_element_type=buckets.dtype).reshape(buckets.shape)
+    else:
+        cum = jnp.cumsum(buckets, axis=-1)
     return jnp.clip(amount[..., None] - (cum - buckets), 0.0, buckets)
 
 
@@ -522,7 +585,7 @@ def compact_slot_step(
         if kernel_safe:
             comp_count = jax.lax.dot_general(
                 alive_row[None, :], c.comp_onehot, (((1,), (0,)), ((), ())),
-                preferred_element_type=dt)[0]
+                precision=_HI, preferred_element_type=dt)[0]
         else:
             comp_count = jnp.zeros((C,), dt).at[c.inst_comp].add(alive_row)
         if axis is not None:
@@ -536,7 +599,7 @@ def compact_slot_step(
                             c.adj_rows, jnp.ones((I,), dt))
     dec = compact_decide(scheduler, cp, c.U, q_in_arr, q_out_arr, must_send,
                          c.V, c.beta, kernel_safe, axis, n_shards)
-    backlog = q_in_arr.sum() + c.beta * q_out_arr.sum()
+    backlog = _vsum(q_in_arr, kernel_safe) + c.beta * q_out_arr.sum()
     cost = dec.cost
     if axis is not None:
         backlog = jax.lax.psum(backlog, axis)
@@ -549,79 +612,81 @@ def compact_slot_step(
     )
     src_bolt = jnp.concatenate([q_out_tag, jnp.zeros((I, S, 1), dt)], axis=-1)
     src_ext = jnp.where(spout_f[:, None, None] > 0, src_spout, src_bolt)  # (I, S, Atot+1)
-    drained = _drain_ages(src_ext, shipped_cmp)
+    drained = _drain_ages(src_ext, shipped_cmp, kernel_safe)
     q_rem = q_rem - drained[:, :, age_cap:Atot] * spout_f[:, None, None]
-    admit = admit - drained[:, :, -1] * spout_f[:, None]
+    admit = admit - drained[:, :, Atot] * spout_f[:, None]
     q_out_tag = q_out_tag - drained[:, :, :Atot] * bolt_f[:, None, None]
 
     # landing: the admission slot re-tags to age 0 (bucket age_cap) on landing
     d_land = jnp.concatenate(
         [drained[:, :, :age_cap],
-         drained[:, :, age_cap:age_cap + 1] + drained[:, :, -1:],
+         drained[:, :, age_cap:age_cap + 1] + drained[:, :, Atot:],
          drained[:, :, age_cap + 1:Atot]], axis=-1,
     )  # (I, S, Atot)
-    d_dense = _to_dense3(c, d_land, kernel_safe)  # (I, C, Atot)
     sh_safe = jnp.where(dec.shipped > 0, dec.shipped, 1.0)
     live = dec.shipped > _EPS
     w_pt = jnp.where(live, dec.point / sh_safe, 0.0)
     w_ev = jnp.where(live, dec.even_per / sh_safe, 0.0)
-    wd = (w_pt[:, :, None] * d_dense).reshape(I * C, Atot)
     if kernel_safe:
-        oh_t = _onehot_cols(dec.j_point.reshape(I * C), I, dt)  # (I*C, I); I -> zero row
-        land = jax.lax.dot_general(oh_t, wd, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=dt)
-    elif axis is not None:
-        # point targets are global ids: scatter the local sources' mass into
-        # the global landing buffer, fold it (the one O(I)-sized collective —
-        # the physical tuple transfer), keep our own row block
-        I_all = I * n_shards
-        land_g = jnp.zeros((I_all + 1, Atot), dt).at[
-            dec.j_point.reshape(I * C)].add(wd)[:I_all]
-        land_g = jax.lax.psum(land_g, axis)
-        land = jax.lax.dynamic_slice_in_dim(land_g, jax.lax.axis_index(axis) * I, I)
-    else:
-        land = jnp.zeros((I + 1, Atot), dt).at[dec.j_point.reshape(I * C)].add(wd)[:I]
-    # even spread: per-component contraction, then broadcast to alive instances
-    ev_cb = jnp.einsum("ic,icb->cb", w_ev, d_dense)  # (C, Atot)
-    if axis is not None:
-        ev_cb = jax.lax.psum(ev_cb, axis)
-    if kernel_safe:
+        land, ev_cb = _land_kernel(c, dec.j_point, w_pt, w_ev, d_land)
         ev_rows = jax.lax.dot_general(c.comp_onehot, ev_cb, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=dt)  # (I, Atot)
+                                      precision=_HI, preferred_element_type=dt)  # (I, Atot)
     else:
+        d_dense = _to_dense3(c, d_land)  # (I, C, Atot)
+        wd = (w_pt[:, :, None] * d_dense).reshape(I * C, Atot)
+        if axis is not None:
+            # point targets are global ids: scatter the local sources' mass
+            # into the global landing buffer, fold it (the one O(I)-sized
+            # collective — the physical tuple transfer), keep our row block
+            I_all = I * n_shards
+            land_g = jnp.zeros((I_all + 1, Atot), dt).at[
+                dec.j_point.reshape(I * C)].add(wd)[:I_all]
+            land_g = jax.lax.psum(land_g, axis)
+            land = jax.lax.dynamic_slice_in_dim(land_g, jax.lax.axis_index(axis) * I, I)
+        else:
+            land = jnp.zeros((I + 1, Atot), dt).at[dec.j_point.reshape(I * C)].add(wd)[:I]
+        # even spread: per-component contraction, broadcast to alive instances
+        ev_cb = jnp.einsum("ic,icb->cb", w_ev, d_dense, precision=_HI)  # (C, Atot)
+        if axis is not None:
+            ev_cb = jax.lax.psum(ev_cb, axis)
         ev_rows = ev_cb[c.inst_comp]
     land = land + cp.alive[:, None] * ev_rows
 
     # -- 4. land last slot's transit, serve bolts ----------------------------
     avail = q_in_tag + transit
     served_amt = jnp.minimum(avail.sum(-1), mu_eff) * bolt_f
-    served_b = _drain_ages(avail, served_amt)
+    served_b = _drain_ages(avail, served_amt, kernel_safe)
     q_in_tag = (avail - served_b) * bolt_f[:, None]
     cmass = jax.lax.dot_general(
         c.comp_onehot, served_b * c.term_f[:, None], (((0,), (0,)), ((), ())),
-        preferred_element_type=dt,
+        precision=_HI, preferred_element_type=dt,
     )  # (C, Atot)
     if axis is not None:
         # fold served mass so the replicated response accumulators see the
         # global per-component completions on every shard
         cmass = jax.lax.psum(cmass, axis)
     if kernel_safe:
-        ages = jax.lax.broadcasted_iota(dt, (1, Atot), 1)  # 2-D iota (Pallas TPU)
+        ages = jax.lax.broadcasted_iota(jnp.int32, (1, Atot), 1).astype(dt)  # 2-D int iota
         resp_row = jnp.maximum(age_cap - ages, 0.0)  # (1, Atot)
-        # accumulator columns [t, t + Atot) — always in range (len >= Tc + Atot)
-        t = jnp.asarray(t)
-        z = jnp.zeros((), t.dtype)
-        seg = jax.lax.dynamic_slice(resp_mass, (z, t), (C, Atot))
-        resp_mass = jax.lax.dynamic_update_slice(resp_mass, seg + cmass, (z, t))
-        seg_t = jax.lax.dynamic_slice(resp_time, (z, t), (C, Atot))
-        resp_time = jax.lax.dynamic_update_slice(
-            resp_time, seg_t + cmass * resp_row, (z, t))
+        # accumulator columns [t, t + Atot) — always in range (len >= Tc + Atot);
+        # Pallas TPU cannot slice a value at a traced offset, so the bucket
+        # rows land through an (Atot, L) one-hot placement (one exact product
+        # per column, the same sums as the scatter below)
+        L = resp_mass.shape[-1]
+        col = jax.lax.broadcasted_iota(jnp.int32, (Atot, L), 1)
+        b = jax.lax.broadcasted_iota(jnp.int32, (Atot, L), 0)
+        place = (col == b + jnp.asarray(t, jnp.int32)).astype(dt)
+        dims = (((1,), (0,)), ((), ()))
+        resp_mass = resp_mass + jax.lax.dot_general(
+            cmass, place, dims, precision=_HI, preferred_element_type=dt)
+        resp_time = resp_time + jax.lax.dot_general(
+            cmass * resp_row, place, dims, precision=_HI, preferred_element_type=dt)
     else:
         resp_per_b = jnp.maximum(age_cap - jnp.arange(Atot, dtype=dt), 0.0)
         idx = t + jnp.arange(Atot)
         resp_mass = resp_mass.at[:, idx].add(cmass, mode="drop")
         resp_time = resp_time.at[:, idx].add(cmass * resp_per_b[None, :], mode="drop")
-    capped_served = cmass[:, 0].sum()
+    capped_served = _vsum(cmass[:, 0], kernel_safe)
     term_served = cmass.sum()
     q_out_tag = q_out_tag + served_b[:, None, :] * c.sel_cmp[:, :, None] * bolt_f[:, None, None]
 

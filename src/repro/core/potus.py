@@ -300,7 +300,8 @@ def _mandatory_dispatch(
     any shortfall vs the greedy shipment is split evenly across the successor
     component's instances."""
     comp_onehot = jax.nn.one_hot(inst_comp, n_components, dtype=x.dtype)  # (I, C)
-    shipped = x @ comp_onehot  # (R, C)
+    # full f32: the TPU's default precision would round the mass through bf16
+    shipped = jnp.dot(x, comp_onehot, precision=jax.lax.Precision.HIGHEST)  # (R, C)
     shortfall = jnp.maximum(must_send - shipped, 0.0)  # (R, C)
     extra = jnp.where(
         edge_mask,

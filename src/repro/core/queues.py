@@ -95,7 +95,8 @@ def slot_update_rows(
     cohort engines; DESIGN.md §9). An all-alive slot has ``hold_mask == 0``
     everywhere, which is numerically a no-op.
     """
-    shipped = X @ comp_onehot  # (R, C) tuples leaving i toward component c
+    # full f32: the TPU's default precision would round the mass through bf16
+    shipped = jnp.dot(X, comp_onehot, precision=jax.lax.Precision.HIGHEST)  # (R, C) leaving i toward c
 
     # --- spouts: drain Q_rem in ascending w (actual first), shift window ----
     cum_before = jnp.cumsum(state.q_rem, axis=-1) - state.q_rem

@@ -1,22 +1,15 @@
 """Ambient mesh context for modules that need explicit collectives
 (shard_map paths) deep inside a traced model function, plus the
-version-compat ``shard_map`` entry point they share."""
+``shard_map`` entry point they share."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with per-shard replication checking off, across the API
-    move: ``jax.shard_map(check_vma=...)`` (jax >= 0.6) vs
-    ``jax.experimental.shard_map.shard_map(check_rep=...)`` (0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with per-shard replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 _MESH = None

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_interpret
+
 __all__ = ["cohort_drain_kernel", "cohort_drain_call"]
 
 
@@ -68,7 +70,7 @@ def cohort_drain_kernel(src_ref, ship_ref, ratio_ref, oh_ref, land_ref, *,
 @functools.partial(jax.jit, static_argnames=("age_bucket", "block_i", "block_j", "interpret"))
 def cohort_drain_call(src_ext, shipped, ratio, inst_comp, age_bucket: int,
                       block_i: int = 8, block_j: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """Landing buckets ``land`` (I, Atot) for one cohort slot.
 
     ``src_ext``: (I, C, Atot + 1) extended drain buffer; ``shipped``: (I, C)
@@ -101,6 +103,6 @@ def cohort_drain_call(src_ext, shipped, ratio, inst_comp, age_bucket: int,
         ],
         out_specs=pl.BlockSpec((block_j, n_age), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((Jp, n_age), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(src_p, ship_p, ratio_p, oh_p)
     return land[:I]

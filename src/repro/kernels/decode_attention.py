@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import pallas_interpret
+
 __all__ = ["decode_attention_kernel", "decode_attention_call"]
 
 NEG_INF = -1e30
@@ -51,7 +53,7 @@ def decode_attention_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, block_s: int
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention_call(q, k_cache, v_cache, pos, block_s: int = 256,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """q: (B, Hq, D); caches: (B, S, Hkv, D); pos: (B,) -> (B, Hq, D)."""
     B, Hq, D = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
@@ -74,6 +76,6 @@ def decode_attention_call(q, k_cache, v_cache, pos, block_s: int = 256,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(pos2d, qg, k_cache, v_cache)
     return out.reshape(B, Hq, D)

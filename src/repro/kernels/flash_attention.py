@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import pallas_interpret
+
 __all__ = ["flash_attention_kernel", "flash_attention_call"]
 
 NEG_INF = -1e30
@@ -62,7 +64,7 @@ def flash_attention_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: 
     jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
 )
 def flash_attention_call(q, k, v, causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True):
+                         block_k: int = 128, interpret: bool | None = None):
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D) -> (B, Hq, S, D)."""
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]
@@ -88,5 +90,5 @@ def flash_attention_call(q, k, v, causal: bool = True, block_q: int = 128,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(q, k, v)

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_interpret
+
 __all__ = ["potus_price_kernel", "potus_price_call"]
 
 
@@ -49,7 +51,7 @@ def potus_price_kernel(vb_ref, kc_i_ref, kc_j_ref, comp_j_ref, qin_j_ref, qout_i
 @functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
 def potus_price_call(U, q_in, q_out, inst_container, inst_comp, edge_mask,
                      V: float, beta: float, block_i: int = 128, block_j: int = 128,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Returns the (I, I) price matrix l (eq. 16), +inf off the DAG edges."""
     I = q_in.shape[0]
     K = U.shape[0]
@@ -86,6 +88,6 @@ def potus_price_call(U, q_in, q_out, inst_container, inst_comp, edge_mask,
         ],
         out_specs=pl.BlockSpec((block_i, block_j), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Ip, Jp), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(vb, kc_i, kc_j, cp_j, qin_j, qout_i, U.astype(jnp.float32), mask)
     return l[:I, :I]

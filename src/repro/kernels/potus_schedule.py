@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_interpret
+
 __all__ = ["potus_schedule_kernel", "potus_schedule_call"]
 
 
@@ -103,7 +105,7 @@ def potus_schedule_kernel(vb_ref, kc_i_ref, gamma_ref, qout_i_ref, kc_j_ref,
 @functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
 def potus_schedule_call(U, q_in, q_out, inst_container, inst_comp, edge_mask,
                         gamma, V: float, beta: float, block_i: int = 8,
-                        block_j: int = 128, interpret: bool = True):
+                        block_j: int = 128, interpret: bool | None = None):
     """Greedy allocation X (I, I) of Algorithm 1 lines 9-14 (no mandatory
     dispatch), computed by the fused Pallas kernel."""
     I = q_in.shape[0]
@@ -143,6 +145,6 @@ def potus_schedule_call(U, q_in, q_out, inst_container, inst_comp, edge_mask,
         ],
         out_specs=pl.BlockSpec((block_i, Jp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Ip, Jp), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(vb, kc_i, gamma_i, qout_i, kc_j, cp_j, qin_j, U.astype(jnp.float32), mask)
     return x[:I, :I]

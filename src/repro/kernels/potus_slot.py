@@ -26,12 +26,14 @@ Memory layout (DESIGN.md §12):
 The body *is* :func:`repro.core.compact.compact_slot_step` with
 ``kernel_safe=True`` — the same function the XLA path scans — so parity
 between the kernel and the unfused composition is by construction up to the
-documented kernel-safe substitutions (one-hot contractions for gathers, the
-O(C²) precedence-rank water-fill for ``lax.sort``), which are bitwise on the
-dyadic tier. The engine launches this kernel only for compact schedulers
-without a disruption trace; per-slot caps fall back to the compact XLA step
-(DESIGN.md §12 lists the fallback conditions). Off-TPU it runs in interpret
-mode; parity is tested in ``tests/test_potus_slot.py``.
+documented kernel-safe substitutions (DESIGN.md §12.2), which are bitwise
+on the dyadic tier. The engine launches this kernel only for compact
+schedulers without a disruption trace; per-slot caps fall back to the
+compact XLA step (DESIGN.md §12 lists the fallback conditions). The kernel
+is grid-less, so a whole slot must fit VMEM: on a v5e that holds up to
+I=256 of the fleet topology (``tests/test_tpu_compile.py``). It compiles on
+the TPU and interprets on the CPU; parity is tested in
+``tests/test_potus_slot.py``.
 
 Under the instance-sharded scan (``EngineSpec(engine="cohort-fused",
 sharded=True)``, DESIGN.md §13) the kernel runs per shard **only on a
@@ -50,6 +52,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.compact import StepConsts, compact_slot_step
+
+from . import pallas_interpret
 
 __all__ = ["potus_slot_kernel", "potus_slot_call"]
 
@@ -123,7 +127,7 @@ def potus_slot_call(
     scheduler: str = "potus",
     age_cap: int = 64,
     n_slots: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Run ``n_slots`` slots in one launch; returns ``(state, metrics)`` with
     ``metrics = (backlog, cost, capped, served)``, each ``(n_slots,)``."""
@@ -156,7 +160,7 @@ def potus_slot_call(
             pltpu.VMEM((2, I, S, Atot), dt),
             pltpu.VMEM((2, I, Atot), dt),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(
         consts.U.astype(dt), col(consts.mu), col(consts.inv_service),
         consts.sel_cmp.astype(dt), consts.stream_cmp.astype(dt),

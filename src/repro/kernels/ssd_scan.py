@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_interpret
+
 __all__ = ["ssd_intra_chunk_kernel", "ssd_intra_chunk_call"]
 
 
@@ -44,7 +46,7 @@ def ssd_intra_chunk_kernel(x_ref, dt_ref, dA_ref, b_ref, c_ref, y_ref, s_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk_call(xc, dtc, dA_cum, Bc, Cc, interpret: bool = True):
+def ssd_intra_chunk_call(xc, dtc, dA_cum, Bc, Cc, interpret: bool | None = None):
     """xc: (b, nc, Q, H, P); dtc/dA_cum: (b, nc, Q, H); Bc/Cc: (b, nc, Q, S).
     Returns y_diag (b, nc, Q, H, P), states (b, nc, H, P, S)."""
     b, nc, Q, H, P = xc.shape
@@ -67,6 +69,6 @@ def ssd_intra_chunk_call(xc, dtc, dA_cum, Bc, Cc, interpret: bool = True):
             jax.ShapeDtypeStruct(xc.shape, xc.dtype),
             jax.ShapeDtypeStruct((b, nc, H, P, S), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(xc, dtc, dA_cum, Bc, Cc)
     return y, states

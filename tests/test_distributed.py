@@ -174,8 +174,12 @@ def test_sharded_cohort_multidevice_differential():
         arr = (2.0 ** rng.integers(-1, 2, size=(T + 1, *unit.shape))).astype(np.float32)
         arr *= rng.random((T + 1, *unit.shape)) < 0.8
         arr = (arr * (unit > 0)).astype(np.float32)
+        # each restarted instance belongs to a 2-instance component, so the
+        # alive counts stay powers of two and every even split stays dyadic
+        # (a 4 -> 3 count makes x/3 masses whose cross-shard psum
+        # re-associates by 1 ulp, DESIGN.md §13.2)
         trace = rolling_restart(topo, start=8, down_slots=2,
-                                instances=[1, 5, 9]).compile(topo, T, placement)
+                                instances=[1, 7, 9]).compile(topo, T, placement)
 
         def eq(a, b):
             return bool(np.array_equal(np.asarray(a), np.asarray(b),

@@ -176,7 +176,7 @@ class TestSlotKernelParity:
 
     @pytest.mark.parametrize("n_slots", [1, 4])
     def test_f64_kernel_vs_unfused_composition(self, system, n_slots):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             fin_d, out_d = _run_dense(system, jnp.float64)
             fin_k, out_k = _run_kernel(system, jnp.float64, n_slots)
             assert fin_k[0].dtype == jnp.float64  # no silent f32 truncation
