@@ -93,16 +93,23 @@ def _degradation(transient, sched: str, W: int) -> float:
 def _dump_obs(sys, T, t0, dur, scen, W) -> None:
     """Metrics-on POTUS run through the same transient (DESIGN.md §14).
 
-    Re-runs the kfail scenario with every cohort-fused stream enabled and
-    span tracing on, then dumps ``OBS_disruption.json`` (``repro-obs/v1``)
-    and ``TRACE_disruption.json`` (Chrome-trace / Perfetto).  The recovery
+    Re-runs the kfail scenario with every cohort-fused stream enabled, under
+    ``jax.profiler`` with the program's spans on, then dumps
+    ``OBS_disruption.json`` (``repro-obs/v1``) and ``TRACE_disruption.json``
+    (the profiler's Perfetto trace, decompressed: Chrome-trace JSON).  The recovery
     story in BENCH_disruption — peak-backlog slot, recovery slot — is
     re-derivable from the streams alone via
     ``python tools/obs_report.py OBS_disruption.json --recovery``.
     """
+    import glob
+    import gzip
     import os
+    import shutil
+    import tempfile
 
-    from repro.obs.trace import disable_tracing, enable_tracing, export_chrome_trace
+    import jax
+
+    from repro.obs.trace import disable_tracing, enable_tracing, tracing_enabled
 
     obs_path = os.environ.get("REPRO_OBS_DISRUPTION_JSON", "OBS_disruption.json")
     trace_path = os.environ.get("REPRO_OBS_TRACE_JSON", "TRACE_disruption.json")
@@ -113,8 +120,19 @@ def _dump_obs(sys, T, t0, dur, scen, W) -> None:
     margin = T - min(t0 + dur + 10, T - 1)
     streams = ("backlog", "queue_depth", "price", "dispatch", "transit",
                "backlog_comp", "held", "window", "saturation", "payload")
-    tracer = enable_tracing()
-    tracer.clear()
+    log_dir = tempfile.mkdtemp(prefix="trace-disruption-")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # spans and ops, not every Python call
+    try:
+        jax.profiler.start_trace(log_dir, create_perfetto_trace=True,
+                                 profiler_options=options)
+        profiling = True
+    except RuntimeError:
+        # one session at a time: under ``benchmarks.run --profile`` that
+        # session's trace holds these spans, and no TRACE file is written
+        profiling = False
+    was_tracing = tracing_enabled()
+    enable_tracing()
     try:
         swept = run_sweep(sys.topo, sys.net, sys.placement, arr, T, spec,
                           engine="cohort-fused", events={"kfail": scen},
@@ -122,9 +140,17 @@ def _dump_obs(sys, T, t0, dur, scen, W) -> None:
                                        "drain_margin": margin,
                                        "metrics": streams})
     finally:
-        disable_tracing()
+        if not was_tracing:
+            disable_tracing()
+        if profiling:
+            jax.profiler.stop_trace()
     swept.result(scheduler="potus", window=W, events="kfail").metrics.save(obs_path)
-    export_chrome_trace(trace_path)
+    if profiling:
+        perfetto, = glob.glob(os.path.join(log_dir, "**", "perfetto_trace.json.gz"),
+                              recursive=True)
+        with gzip.open(perfetto, "rb") as src, open(trace_path, "wb") as dst:
+            shutil.copyfileobj(src, dst)
+    shutil.rmtree(log_dir, ignore_errors=True)
 
 
 def disruption_bench() -> list[Row]:

@@ -7,9 +7,10 @@ machine-readable JSON under one shared schema (``benchmarks/common.py``) to
 / REPRO_BENCH_SERVING_JSON) so the perf trajectory is tracked across PRs.
 
 Set REPRO_BENCH_FULL=1 for the full (paper-scale) sweeps. ``--profile DIR``
-wraps the run in span tracing (``repro.obs.trace``) plus ``jax.profiler``,
-writing a Perfetto-loadable ``chrome_trace.json`` (and the XLA profile) to
-DIR (DESIGN.md §14). JAX's persistent compile cache is on
+runs the sections under ``jax.profiler`` with the program's spans on
+(``repro.obs.trace``), writing the XLA profile and a Perfetto-loadable
+``perfetto_trace.json.gz`` under ``DIR/plugins/profile/<run>/`` (DESIGN.md
+§14.2). JAX's persistent compile cache is on
 (``benchmarks.common.enable_compile_cache``). A section that raises prints
 an ``ERROR`` row and the driver goes on, then exits 1.
 """
@@ -45,7 +46,8 @@ def main() -> None:
     ap.add_argument("only", nargs="?", default=None,
                     help="substring filter on section names")
     ap.add_argument("--profile", metavar="DIR", default=None,
-                    help="write span + jax.profiler traces to DIR (DESIGN.md §14)")
+                    help="write a jax.profiler trace with the program's spans to DIR "
+                         "(DESIGN.md §14)")
     args = ap.parse_args()
     only = args.only
     print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
@@ -56,11 +58,11 @@ def main() -> None:
 
         import jax
 
-        from repro.obs.trace import enable_tracing, export_chrome_trace
+        from repro.obs.trace import enable_tracing
 
         os.makedirs(args.profile, exist_ok=True)
         enable_tracing()
-        profile_ctx = jax.profiler.trace(args.profile)
+        profile_ctx = jax.profiler.trace(args.profile, create_perfetto_trace=True)
         profile_ctx.__enter__()
 
     print("name,us_per_call,derived")
@@ -86,12 +88,8 @@ def main() -> None:
                      workload.WORKLOAD_BENCH)
 
     if profile_ctx is not None:
-        import os
-
         profile_ctx.__exit__(None, None, None)
-        out = os.path.join(args.profile, "chrome_trace.json")
-        export_chrome_trace(out)
-        print(f"# profile: spans -> {out}; XLA profile -> {args.profile}",
+        print(f"# profile: XLA profile and perfetto_trace.json.gz -> {args.profile}",
               file=sys.stderr)
     print(f"# total {time.time() - t0:.1f}s", file=sys.stderr)
     if failed:

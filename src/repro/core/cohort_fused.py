@@ -68,7 +68,9 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.context import shard_map_compat
 from repro.distributed.sharding import named
 from repro.obs.metrics import build_frame, compute_scan_streams, scan_stream_names
+from repro.obs.trace import count as obs_count
 from repro.obs.trace import span as obs_span
+from repro.obs.trace import tracing_enabled
 
 from .cohort import CohortResult
 from .compact import COMPACT_SCHEDULERS, StepConsts, compact_slot_step
@@ -90,7 +92,7 @@ from .simulator import (
 )
 from .topology import Topology
 
-__all__ = ["run_fused_sweep", "drain_ages", "AgeCapSaturationWarning"]
+__all__ = ["run_fused_sweep", "drain_ages", "AgeCapSaturationWarning", "scan_hlo_text"]
 
 _EPS = 1e-12  # same negligible-mass threshold as the Python engine's FIFOs
 
@@ -153,12 +155,28 @@ class _CompactProb(NamedTuple):
 
 def _compact_prob(topo: Topology, inst_container) -> _CompactProb:
     return _CompactProb(
-        inst_comp=jnp.asarray(topo.inst_comp),
-        inst_container=jnp.asarray(inst_container, dtype=jnp.int32),
-        gamma=jnp.asarray(topo.inst_gamma),
-        comp_count=jnp.asarray(topo.comp_parallelism, dtype=jnp.float32),
-        is_spout=jnp.asarray(topo.comp_is_spout[topo.inst_comp]),
+        inst_comp=_to_device(topo.inst_comp),
+        inst_container=_to_device(inst_container, np.int32),
+        gamma=_to_device(topo.inst_gamma),
+        comp_count=_to_device(topo.comp_parallelism, np.float32),
+        is_spout=_to_device(topo.comp_is_spout[topo.inst_comp]),
     )
+
+
+def _to_device(x, dtype=None) -> jax.Array:
+    """``jnp.asarray`` of a host array; its bytes count as ``h2d_bytes``."""
+    a = np.asarray(x, dtype)
+    a = a.astype(jax.dtypes.canonicalize_dtype(a.dtype), copy=False)
+    obs_count("h2d_bytes", a.nbytes)
+    return jnp.asarray(a)
+
+
+def _fetch(x) -> np.ndarray:
+    """``np.asarray`` of a device array (blocks on it); its bytes count as
+    ``d2h_bytes``."""
+    a = np.asarray(x)
+    obs_count("d2h_bytes", a.nbytes)
+    return a
 
 
 # ---------------------------------------------------------------------------
@@ -274,119 +292,125 @@ def _fused_step(
         return jnp.take_along_axis(x, gather_idx, axis=1) * valid_cmp
 
     # -- 1. reconcile window pos-0 with actual arrivals of slot t ------------
-    pred_m = to_cmp(pred_t) * stream_cmp
-    act_m = to_cmp(act_t) * stream_cmp
-    tp = jnp.minimum(pred_m, act_m)
-    tn = act_m - tp
-    r = jnp.where(pred_m > 0, q_rem[:, :, 0] / jnp.where(pred_m > 0, pred_m, 1.0), 0.0)
-    q_rem = q_rem.at[:, :, 0].set(r * tp + tn)  # drop unserved phantoms
+    with jax.named_scope("reconcile"):
+        pred_m = to_cmp(pred_t) * stream_cmp
+        act_m = to_cmp(act_t) * stream_cmp
+        tp = jnp.minimum(pred_m, act_m)
+        tn = act_m - tp
+        r = jnp.where(pred_m > 0, q_rem[:, :, 0] / jnp.where(pred_m > 0, pred_m, 1.0), 0.0)
+        q_rem = q_rem.at[:, :, 0].set(r * tp + tn)  # drop unserved phantoms
 
     # -- 2. observe queue state, schedule ------------------------------------
-    q_in_arr = q_in_tag.sum(-1)
-    q_out_cmp = jnp.where(is_spout[:, None], q_rem.sum(-1), q_out_tag.sum(-1))
-    q_out_arr = to_dense(q_out_cmp)
-    must_send = to_dense((q_rem[:, :, 0] + admit) * spout_f[:, None])
-    X = sched(prob, U, q_in_arr, q_out_arr, must_send, V, beta, caps=caps)
-    backlog = q_in_arr.sum() + beta * q_out_arr.sum()
-    cost = (X * u_pair).sum()
+    with jax.named_scope("decide"):
+        q_in_arr = q_in_tag.sum(-1)
+        q_out_cmp = jnp.where(is_spout[:, None], q_rem.sum(-1), q_out_tag.sum(-1))
+        q_out_arr = to_dense(q_out_cmp)
+        must_send = to_dense((q_rem[:, :, 0] + admit) * spout_f[:, None])
+        X = sched(prob, U, q_in_arr, q_out_arr, must_send, V, beta, caps=caps)
+        backlog = q_in_arr.sum() + beta * q_out_arr.sum()
+        cost = (X * u_pair).sum()
 
     # -- 3. drain sources oldest-first, split over targets -------------------
-    # requested mass per successor slot: blocked column sums over DAG edges
-    shipped = jnp.zeros((I, S), q_rem.dtype)
-    for (rs, re, s, cs, ce) in edges:
-        shipped = shipped.at[rs:re, s].set(X[rs:re, cs:ce].sum(axis=1))
-    # unified drain buffer: bolts ship from q_out buckets; spouts ship the
-    # window in ascending lookahead (buckets age_cap..age_cap+W), then the
-    # admission backlog (a trailing slot, re-tagged to age 0 when it lands)
-    src_spout = jnp.concatenate(
-        [jnp.zeros((I, S, age_cap), q_rem.dtype), q_rem, admit[:, :, None]], axis=-1
-    )
-    src_bolt = jnp.concatenate([q_out_tag, jnp.zeros((I, S, 1), q_rem.dtype)], axis=-1)
-    src_ext = jnp.where(is_spout[:, None, None], src_spout, src_bolt)  # (I, S, Atot+1)
-    drained = drain_ages(src_ext, shipped)
-    q_rem = q_rem - drained[:, :, age_cap:Atot] * spout_f[:, None, None]
-    admit = admit - drained[:, :, -1] * spout_f[:, None]
-    q_out_tag = q_out_tag - drained[:, :, :Atot] * bolt_f[:, None, None]
-
-    if use_pallas:
-        from repro.kernels import ops as kops
-
-        # the kernel's split is component-dense: expand the compact buffers
-        src_dense = jnp.zeros((I, C + 1, Atot + 1), q_rem.dtype)
-        src_dense = src_dense.at[rows, succ_map, :].add(src_ext)[:, :C]
-        ship_dense = to_dense(shipped)
-        ship_cols = ship_dense[:, prob.inst_comp]  # (I, I)
-        ratio = jnp.where(ship_cols > _EPS, X / jnp.where(ship_cols > 0, ship_cols, 1.0), 0.0)
-        land = kops.cohort_drain_split(src_dense, ship_dense, ratio, prob.inst_comp, age_cap)
-    else:
-        # proportional split, one skinny matmul per DAG edge
-        land = jnp.zeros((I, Atot), q_rem.dtype)
+    with jax.named_scope("drain"):
+        # requested mass per successor slot: blocked column sums over DAG edges
+        shipped = jnp.zeros((I, S), q_rem.dtype)
         for (rs, re, s, cs, ce) in edges:
-            d_land = drained[rs:re, s, :Atot].at[:, age_cap].add(drained[rs:re, s, -1])
-            sh = shipped[rs:re, s]
-            ratio_b = jnp.where(
-                (sh > _EPS)[:, None], X[rs:re, cs:ce] / jnp.where(sh > 0, sh, 1.0)[:, None], 0.0
-            )
-            land = land.at[cs:ce].add(jax.lax.dot_general(
-                ratio_b, d_land, (((0,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST, preferred_element_type=q_rem.dtype,
-            ))
+            shipped = shipped.at[rs:re, s].set(X[rs:re, cs:ce].sum(axis=1))
+        # unified drain buffer: bolts ship from q_out buckets; spouts ship the
+        # window in ascending lookahead (buckets age_cap..age_cap+W), then the
+        # admission backlog (a trailing slot, re-tagged to age 0 when it lands)
+        src_spout = jnp.concatenate(
+            [jnp.zeros((I, S, age_cap), q_rem.dtype), q_rem, admit[:, :, None]], axis=-1
+        )
+        src_bolt = jnp.concatenate([q_out_tag, jnp.zeros((I, S, 1), q_rem.dtype)], axis=-1)
+        src_ext = jnp.where(is_spout[:, None, None], src_spout, src_bolt)  # (I, S, Atot+1)
+        drained = drain_ages(src_ext, shipped)
+        q_rem = q_rem - drained[:, :, age_cap:Atot] * spout_f[:, None, None]
+        admit = admit - drained[:, :, -1] * spout_f[:, None]
+        q_out_tag = q_out_tag - drained[:, :, :Atot] * bolt_f[:, None, None]
+
+        if use_pallas:
+            from repro.kernels import ops as kops
+
+            # the kernel's split is component-dense: expand the compact buffers
+            src_dense = jnp.zeros((I, C + 1, Atot + 1), q_rem.dtype)
+            src_dense = src_dense.at[rows, succ_map, :].add(src_ext)[:, :C]
+            ship_dense = to_dense(shipped)
+            ship_cols = ship_dense[:, prob.inst_comp]  # (I, I)
+            ratio = jnp.where(ship_cols > _EPS, X / jnp.where(ship_cols > 0, ship_cols, 1.0), 0.0)
+            land = kops.cohort_drain_split(src_dense, ship_dense, ratio, prob.inst_comp, age_cap)
+        else:
+            # proportional split, one skinny matmul per DAG edge
+            land = jnp.zeros((I, Atot), q_rem.dtype)
+            for (rs, re, s, cs, ce) in edges:
+                d_land = drained[rs:re, s, :Atot].at[:, age_cap].add(drained[rs:re, s, -1])
+                sh = shipped[rs:re, s]
+                ratio_b = jnp.where(
+                    (sh > _EPS)[:, None], X[rs:re, cs:ce] / jnp.where(sh > 0, sh, 1.0)[:, None], 0.0
+                )
+                land = land.at[cs:ce].add(jax.lax.dot_general(
+                    ratio_b, d_land, (((0,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST, preferred_element_type=q_rem.dtype,
+                ))
 
     # -- 4. land last slot's transit, serve bolts ----------------------------
-    avail = q_in_tag + transit
-    served_amt = jnp.minimum(avail.sum(-1), mu) * bolt_f
-    served_b = drain_ages(avail, served_amt)
-    q_in_tag = (avail - served_b) * bolt_f[:, None]
-    # terminal completions -> response accumulators, indexed by *chunk-local*
-    # source slot: ``t`` counts slots within this scan segment, and bucket
-    # ``b`` of slot ``t`` holds source slot ``t0 + t - age_cap + b``, which
-    # is accumulator column ``t + b`` (the accumulator spans the chunk's
-    # global source-slot range [t0 - age_cap, t0 + Tc + W]; the host driver
-    # adds each chunk's slab at offset t0 - age_cap, DESIGN.md §11.2)
-    # full f32 (mass-carrying): the TPU's default precision rounds through bf16
-    cmass = jnp.dot(comp_onehot.T, served_b * term_f[:, None],
-                    precision=jax.lax.Precision.HIGHEST)  # (C, Atot)
-    resp_per_b = jnp.maximum(
-        age_cap - jnp.arange(Atot, dtype=q_rem.dtype), 0.0
-    )  # clip(t - s, 0); saturated mass reports age_cap
-    idx = t + jnp.arange(Atot)  # always in range: accumulator length Tc + Atot
-    resp_mass = resp_mass.at[:, idx].add(cmass, mode="drop")
-    resp_time = resp_time.at[:, idx].add(cmass * resp_per_b[None, :], mode="drop")
-    # completions reporting the capped response — nonzero means age_cap is
-    # (or is close to) too shallow and the response metric is biased low
-    capped_served = cmass[:, 0].sum()
-    term_served = cmass.sum()
-    # emissions: served * selectivity into own output queues (same buckets)
-    q_out_tag = q_out_tag + served_b[:, None, :] * sel_cmp[:, :, None] * bolt_f[:, None, None]
+    with jax.named_scope("land"):
+        avail = q_in_tag + transit
+        served_amt = jnp.minimum(avail.sum(-1), mu) * bolt_f
+        served_b = drain_ages(avail, served_amt)
+        q_in_tag = (avail - served_b) * bolt_f[:, None]
+        # terminal completions -> response accumulators, indexed by *chunk-local*
+        # source slot: ``t`` counts slots within this scan segment, and bucket
+        # ``b`` of slot ``t`` holds source slot ``t0 + t - age_cap + b``, which
+        # is accumulator column ``t + b`` (the accumulator spans the chunk's
+        # global source-slot range [t0 - age_cap, t0 + Tc + W]; the host loop
+        # adds each chunk's slab at offset t0 - age_cap, DESIGN.md §11.2)
+        # full f32 (mass-carrying): the TPU's default precision rounds through bf16
+        cmass = jnp.dot(comp_onehot.T, served_b * term_f[:, None],
+                        precision=jax.lax.Precision.HIGHEST)  # (C, Atot)
+        resp_per_b = jnp.maximum(
+            age_cap - jnp.arange(Atot, dtype=q_rem.dtype), 0.0
+        )  # clip(t - s, 0); saturated mass reports age_cap
+        idx = t + jnp.arange(Atot)  # always in range: accumulator length Tc + Atot
+        resp_mass = resp_mass.at[:, idx].add(cmass, mode="drop")
+        resp_time = resp_time.at[:, idx].add(cmass * resp_per_b[None, :], mode="drop")
+        # completions reporting the capped response — nonzero means age_cap is
+        # (or is close to) too shallow and the response metric is biased low
+        capped_served = cmass[:, 0].sum()
+        term_served = cmass.sum()
+        # emissions: served * selectivity into own output queues (same buckets)
+        q_out_tag = q_out_tag + served_b[:, None, :] * sel_cmp[:, :, None] * bolt_f[:, None, None]
 
     # -- 5. admit leftover actuals, shift windows and age axes ---------------
-    admit = admit + q_rem[:, :, 0] * spout_f[:, None]
-    q_rem = jnp.concatenate(
-        [q_rem[:, :, 1:], (to_cmp(new_pred) * stream_cmp)[:, :, None]], axis=-1
-    )
+    with jax.named_scope("admit"):
+        admit = admit + q_rem[:, :, 0] * spout_f[:, None]
+        q_rem = jnp.concatenate(
+            [q_rem[:, :, 1:], (to_cmp(new_pred) * stream_cmp)[:, :, None]], axis=-1
+        )
 
-    def shift(x):  # age b+1 -> b; the oldest bucket saturates (A-cap rule)
-        head = x[..., 0:1] + x[..., 1:2]
-        return jnp.concatenate([head, x[..., 2:], jnp.zeros_like(x[..., 0:1])], axis=-1)
+        def shift(x):  # age b+1 -> b; the oldest bucket saturates (A-cap rule)
+            head = x[..., 0:1] + x[..., 1:2]
+            return jnp.concatenate([head, x[..., 2:], jnp.zeros_like(x[..., 0:1])], axis=-1)
 
-    state = (q_rem, admit, shift(q_in_tag), shift(q_out_tag), shift(land), resp_mass, resp_time)
+        state = (q_rem, admit, shift(q_in_tag), shift(q_out_tag), shift(land), resp_mass, resp_time)
     out = (backlog, cost, capped_served, term_served)
     if metrics_spec is not None:
         # §14 metric streams as extra scan outputs (dense reference path)
-        landed = land.sum(-1)
-        ctx = {
-            "h": backlog,
-            "q_in": q_in_arr,
-            "price": V * U.mean(axis=0)[prob.inst_container] + q_in_arr,
-            "landed": landed,
-            "transit_total": landed.sum(),
-            "comp_backlog": comp_onehot.T @ q_in_arr,
-            "held": admit.sum(),
-            "dropped": (r * (pred_m - tp)).sum(),
-            "tp": tp.sum(), "fp": (pred_m - tp).sum(), "tn": tn.sum(),
-            "capped": capped_served, "served": term_served,
-        }
-        out = out + compute_scan_streams(scan_stream_names(metrics_spec), ctx)
+        with jax.named_scope("metrics"):
+            landed = land.sum(-1)
+            ctx = {
+                "h": backlog,
+                "q_in": q_in_arr,
+                "price": V * U.mean(axis=0)[prob.inst_container] + q_in_arr,
+                "landed": landed,
+                "transit_total": landed.sum(),
+                "comp_backlog": comp_onehot.T @ q_in_arr,
+                "held": admit.sum(),
+                "dropped": (r * (pred_m - tp)).sum(),
+                "tp": tp.sum(), "fp": (pred_m - tp).sum(), "tn": tn.sum(),
+                "capped": capped_served, "served": term_served,
+            }
+            out = out + compute_scan_streams(scan_stream_names(metrics_spec), ctx)
     return state, out
 
 
@@ -777,17 +801,17 @@ def _device_inputs(topo: Topology, net: NetworkCosts, cpt: _Compact, service=Non
         svc = np.broadcast_to(np.asarray(service, np.float32), (topo.n_instances,))
         if (svc <= 0).any():
             raise ValueError("service times must be positive")
-        inv_service = jnp.asarray(1.0 / svc)
+        inv_service = _to_device(1.0 / svc)
     return dict(
-        U=jnp.asarray(net.U),
-        mu=jnp.asarray(topo.inst_mu, jnp.float32),
+        U=_to_device(net.U),
+        mu=_to_device(topo.inst_mu, np.float32),
         inv_service=inv_service,
-        sel_cmp=jnp.asarray(cpt.sel_cmp),
-        stream_cmp=jnp.asarray(cpt.stream_cmp),
-        valid_cmp=jnp.asarray(cpt.valid),
-        succ_map=jnp.asarray(cpt.succ_map),
-        term_f=jnp.asarray(_terminal_mask(topo)),
-        adj_rows=jnp.asarray(cpt.adj_rows),
+        sel_cmp=_to_device(cpt.sel_cmp),
+        stream_cmp=_to_device(cpt.stream_cmp),
+        valid_cmp=_to_device(cpt.valid),
+        succ_map=_to_device(cpt.succ_map),
+        term_f=_to_device(_terminal_mask(topo)),
+        adj_rows=_to_device(cpt.adj_rows),
     )
 
 
@@ -839,20 +863,21 @@ def _run_chunked_cohort(
     I, Sc, W1 = q0_b.shape[1:]
     Atot = age_cap + W1
     f32 = np.float32
-    carry = (
-        jnp.asarray(q0_b, jnp.float32),
-        jnp.zeros((Sn, I, Sc), jnp.float32),
-        jnp.zeros((Sn, I, Atot), jnp.float32),
-        jnp.zeros((Sn, I, Sc, Atot), jnp.float32),
-        jnp.zeros((Sn, I, Atot), jnp.float32),
-    )
-    if mesh is not None:
-        # place the carry on the mesh up front; chunk inputs get resharded by
-        # the jitted scan per its shard_map in_specs
-        carry = tuple(
-            jax.device_put(cr, named(mesh, sp))
-            for cr, sp in zip(carry, cohort_state_specs()[:5])
+    with obs_span("potus/cohort-fused/upload"):
+        carry = (
+            _to_device(q0_b, f32),
+            jnp.zeros((Sn, I, Sc), jnp.float32),
+            jnp.zeros((Sn, I, Atot), jnp.float32),
+            jnp.zeros((Sn, I, Sc, Atot), jnp.float32),
+            jnp.zeros((Sn, I, Atot), jnp.float32),
         )
+        if mesh is not None:
+            # place the carry on the mesh up front; chunk inputs get resharded
+            # by the jitted scan per its shard_map in_specs
+            carry = tuple(
+                jax.device_put(cr, named(mesh, sp))
+                for cr, sp in zip(carry, cohort_state_specs()[:5])
+            )
     resp_mass = np.zeros((Sn, n_components, T + W1), f32)
     resp_time = np.zeros((Sn, n_components, T + W1), f32)
     backlogs: list[np.ndarray] = []
@@ -866,30 +891,33 @@ def _run_chunked_cohort(
     for t0 in range(0, T, tc) or [0]:
         t1 = min(t0 + tc, T)
         n = t1 - t0
-        acc = jnp.zeros((Sn, n_components, n + Atot), jnp.float32)
-        states = carry + (acc, jnp.zeros_like(acc))
         sl = (slice(t0, t1),) if shared else (slice(None), slice(t0, t1))
-        ev_c = None
-        if ev_host is not None:
-            esl = (slice(t0, t1),) if ev_shared else (slice(None), slice(t0, t1))
-            ev_c = tuple(jnp.asarray(e[esl]) for e in ev_host)
-        kwargs = dict(
-            actual_s=jnp.asarray(act[sl]),
-            pred_s=jnp.asarray(pred[sl]),
-            nxt_s=jnp.asarray(nxt[sl]),
-            Vs=jnp.asarray(Vs, jnp.float32),
-            betas=jnp.asarray(betas, jnp.float32),
-            events_s=ev_c,
-            events_shared=ev_shared,
-            scheduler=scheduler,
-            use_pallas=use_pallas,
-            age_cap=age_cap,
-            n_components=n_components,
-            shared_inputs=shared,
-            slots_per_launch=slots_per_launch,
-            metrics_spec=metrics_spec,
-            **dev,
-        )
+        with obs_span("potus/cohort-fused/upload"):
+            acc = jnp.zeros((Sn, n_components, n + Atot), jnp.float32)
+            states = carry + (acc, jnp.zeros_like(acc))
+            ev_c = None
+            if ev_host is not None:
+                esl = (slice(t0, t1),) if ev_shared else (slice(None), slice(t0, t1))
+                ev_c = tuple(_to_device(e[esl]) for e in ev_host)
+            kwargs = dict(
+                actual_s=_to_device(act[sl]),
+                pred_s=_to_device(pred[sl]),
+                nxt_s=_to_device(nxt[sl]),
+                Vs=_to_device(Vs, f32),
+                betas=_to_device(betas, f32),
+                events_s=ev_c,
+                events_shared=ev_shared,
+                scheduler=scheduler,
+                use_pallas=use_pallas,
+                age_cap=age_cap,
+                n_components=n_components,
+                shared_inputs=shared,
+                slots_per_launch=slots_per_launch,
+                metrics_spec=metrics_spec,
+                **dev,
+            )
+        if mesh is None and tracing_enabled():
+            _remember_traced_scan(prob, states, dict(kwargs, edges=cpt.edges))
         with obs_span("potus/cohort-fused/chunk", t0=t0, t1=t1,
                       sharded=mesh is not None):
             if mesh is None:
@@ -897,19 +925,21 @@ def _run_chunked_cohort(
                     prob, states, edges=cpt.edges, **kwargs)
             else:
                 states, ys = _scan_cohort_sharded(mesh, prob, states, **kwargs)
-        h, cost, capped, served = ys[:4]
-        for k, slab in enumerate(ys[4:]):
-            stream_chunks[k].append(np.asarray(slab))
         carry = states[:5]
-        rm, rt = np.asarray(states[5]), np.asarray(states[6])
+        with obs_span("potus/cohort-fused/fetch"):
+            h, cost, capped, served = (_fetch(y) for y in ys[:4])
+            slabs = [_fetch(slab) for slab in ys[4:]]
+            rm, rt = _fetch(states[5]), _fetch(states[6])
+        for k, slab in enumerate(slabs):
+            stream_chunks[k].append(slab)
         g0 = t0 - age_cap  # global source slot of the slab's first column
         lo = max(0, -g0)
         resp_mass[:, :, g0 + lo : t1 + W1] += rm[:, :, lo:]
         resp_time[:, :, g0 + lo : t1 + W1] += rt[:, :, lo:]
-        backlogs.append(np.asarray(h))
-        costs.append(np.asarray(cost))
-        capped_tot += np.asarray(capped, np.float64)
-        served_tot += np.asarray(served, np.float64)
+        backlogs.append(h)
+        costs.append(cost)
+        capped_tot += capped
+        served_tot += served
     return (
         resp_mass,
         resp_time,
@@ -919,6 +949,46 @@ def _run_chunked_cohort(
         served_tot,
         [np.concatenate(chunks, axis=1) for chunks in stream_chunks],
     )
+
+
+#: (problem, state, keyword arguments) of the last scan dispatched while
+#: tracing was on, their arrays as ``jax.ShapeDtypeStruct`` (the state is
+#: donated, the inputs need not stay alive); see :func:`scan_hlo_text`
+_traced_scan = None
+
+
+def _remember_traced_scan(prob, states, kwargs) -> None:
+    global _traced_scan
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype) if isinstance(x, jax.Array) else x
+
+    _traced_scan = (prob, tuple(map(spec, states)),
+                    {k: spec(v) for k, v in kwargs.items()})
+
+
+def scan_hlo_text() -> str | None:
+    """Compiled HLO text of the scan last dispatched while tracing was on
+    (``None`` if none was): the instruction names a device profile shows,
+    with ``op_name`` metadata naming the slot step's stages (DESIGN.md
+    §14.2).
+
+    The persistent compile cache keys a program without its metadata, so the
+    executable that ran may carry the names of whichever program first
+    compiled the same computation. This compiles the scan once more with the
+    metadata in the key, after clearing JAX's in-memory caches: call it
+    after the work it describes."""
+    if _traced_scan is None:
+        return None
+    prob, states, kwargs = _traced_scan
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, flag)
+    jax.clear_caches()
+    jax.config.update(flag, True)
+    try:
+        return _scan_cohort_fused.lower(prob, states, **kwargs).compile().as_text()
+    finally:
+        jax.config.update(flag, was)
 
 
 def _run_cohort_fused_impl(
@@ -975,37 +1045,41 @@ def _run_cohort_fused_impl(
                 f"I={topo.n_instances}"
             )
     W = cfg.window
-    actual = materialize_arrivals(actual, topo, T + W + 1)
-    # compact schedulers never need the (I, I) edge mask — build the O(I)
-    # problem so fleet-scale (and sharded) runs stay linear in I
-    prob = (_compact_prob(topo, inst_container)
-            if cfg.scheduler in COMPACT_SCHEDULERS
-            else make_problem(topo, net, inst_container))
-    cpt = _compact(topo)
-    mask = _stream_mask(topo)
-    act, pred, nxt, q_rem0 = _prep_streams(actual, predicted, T, W, cpt, mask)
+    with obs_span("potus/cohort-fused/prep"):
+        actual = materialize_arrivals(actual, topo, T + W + 1)
+        # compact schedulers never need the (I, I) edge mask — build the O(I)
+        # problem so fleet-scale (and sharded) runs stay linear in I
+        prob = (_compact_prob(topo, inst_container)
+                if cfg.scheduler in COMPACT_SCHEDULERS
+                else make_problem(topo, net, inst_container))
+        cpt = _compact(topo)
+        mask = _stream_mask(topo)
+        act, pred, nxt, q_rem0 = _prep_streams(actual, predicted, T, W, cpt, mask)
+        ev_host = host_trace(events, T)
+    with obs_span("potus/cohort-fused/upload"):
+        dev = _device_inputs(topo, net, cpt, service)
     resp_mass, resp_time, backlog, cost, capped, served, streams = _run_chunked_cohort(
-        prob, _device_inputs(topo, net, cpt, service), cpt,
-        cfg.scheduler, cfg.use_pallas, age_cap, topo.n_components,
+        prob, dev, cpt, cfg.scheduler, cfg.use_pallas, age_cap, topo.n_components,
         True, act, pred, nxt, q_rem0, [cfg.V], [cfg.beta],
-        host_trace(events, T), True, T, W, chunk, slots_per_launch, mesh=mesh,
+        ev_host, True, T, W, chunk, slots_per_launch, mesh=mesh,
         metrics_spec=metrics,
     )
-    weights = np.einsum("sic,ic->cs", act, mask)
-    sat = float(capped[0]) / max(float(served[0]), 1e-9)
-    _maybe_warn_saturation(sat, age_cap,
-                           label=f"scheduler={cfg.scheduler} V={cfg.V} W={W}")
-    result = _aggregate(
-        resp_mass[0], resp_time[0], weights, _reachability(topo),
-        backlog[0], cost[0], sat, float(served[0]),
-        T, W, warmup, drain_margin,
-    )
-    if metrics is not None:
-        frame = build_frame(
-            metrics, [s[0] for s in streams], n_slots=T,
-            payload_floats=_fused_payload_floats(topo, net, age_cap, W, mesh),
+    with obs_span("potus/cohort-fused/reduce"):
+        weights = np.einsum("sic,ic->cs", act, mask)
+        sat = float(capped[0]) / max(float(served[0]), 1e-9)
+        _maybe_warn_saturation(sat, age_cap,
+                               label=f"scheduler={cfg.scheduler} V={cfg.V} W={W}")
+        result = _aggregate(
+            resp_mass[0], resp_time[0], weights, _reachability(topo),
+            backlog[0], cost[0], sat, float(served[0]),
+            T, W, warmup, drain_margin,
         )
-        result = dataclasses.replace(result, metrics=frame)
+        if metrics is not None:
+            frame = build_frame(
+                metrics, [s[0] for s in streams], n_slots=T,
+                payload_floats=_fused_payload_floats(topo, net, age_cap, W, mesh),
+            )
+            result = dataclasses.replace(result, metrics=frame)
     return result
 
 
@@ -1084,10 +1158,12 @@ def run_fused_sweep(
                               else make_problem(topo, net, inst_container))
         return probs[compact]
 
-    cpt = _compact(topo)
-    mask = _stream_mask(topo)
-    reach = _reachability(topo)
-    dev = _device_inputs(topo, net, cpt, service)
+    with obs_span("potus/cohort-fused/prep"):
+        cpt = _compact(topo)
+        mask = _stream_mask(topo)
+    with obs_span("potus/cohort-fused/upload"):
+        dev = _device_inputs(topo, net, cpt, service)
+    reach = None
 
     def trace_of(scn):
         return events_map[getattr(scn, "events", "none")]
@@ -1100,46 +1176,52 @@ def run_fused_sweep(
     results: list[CohortResult | None] = [None] * len(scenarios)
     for (scheduler, W, use_pallas, has_events), group in groups.items():
         shared = len({scn.arrival for scn in group}) == 1
-        if shared:  # one prep + one weights matrix for the whole partition
-            prepped = [_prep_streams(*arr_map[group[0].arrival], T, W, cpt, mask)]
-            act_s, pred_s, nxt_s, q0_s = prepped[0]
-        else:
-            prepped = [_prep_streams(*arr_map[scn.arrival], T, W, cpt, mask)
-                       for scn in group]
-            act_s, pred_s, nxt_s, q0_s = (
-                np.stack([p[k] for p in prepped]) for k in range(4)
-            )
-        weights_s = [np.einsum("sic,ic->cs", p[0], mask) for p in prepped]
-        ev_host, ev_shared = None, True
-        if has_events:
-            ev_host, ev_shared = stacked_host_traces(
-                [getattr(scn, "events", "none") for scn in group],
-                [trace_of(scn) for scn in group], T,
-            )
+        with obs_span("potus/cohort-fused/prep"):
+            prob = prob_for(scheduler)
+            if shared:  # one prep + one weights matrix for the whole partition
+                prepped = [_prep_streams(*arr_map[group[0].arrival], T, W, cpt, mask)]
+                act_s, pred_s, nxt_s, q0_s = prepped[0]
+            else:
+                prepped = [_prep_streams(*arr_map[scn.arrival], T, W, cpt, mask)
+                           for scn in group]
+                act_s, pred_s, nxt_s, q0_s = (
+                    np.stack([p[k] for p in prepped]) for k in range(4)
+                )
+            ev_host, ev_shared = None, True
+            if has_events:
+                ev_host, ev_shared = stacked_host_traces(
+                    [getattr(scn, "events", "none") for scn in group],
+                    [trace_of(scn) for scn in group], T,
+                )
         resp_mass, resp_time, backlog, cost, capped, served, streams = _run_chunked_cohort(
-            prob_for(scheduler), dev, cpt, scheduler, use_pallas, age_cap,
+            prob, dev, cpt, scheduler, use_pallas, age_cap,
             topo.n_components, shared, act_s, pred_s, nxt_s, q0_s,
             [scn.V for scn in group], [scn.beta for scn in group],
             ev_host, ev_shared, T, W, chunk, slots_per_launch, mesh=mesh,
             metrics_spec=metrics,
         )
-        for s, scn in enumerate(group):
-            sat = float(capped[s]) / max(float(served[s]), 1e-9)
-            _maybe_warn_saturation(
-                sat, age_cap,
-                label=(f"scheduler={scheduler} V={scn.V} W={W} "
-                       f"arrival={scn.arrival} "
-                       f"events={getattr(scn, 'events', 'none')}"),
-            )
-            result = _aggregate(
-                resp_mass[s], resp_time[s], weights_s[0 if shared else s], reach,
-                backlog[s], cost[s], sat, float(served[s]), T, W, warmup, drain_margin,
-            )
-            if metrics is not None:
-                frame = build_frame(
-                    metrics, [slab[s] for slab in streams], n_slots=T,
-                    payload_floats=_fused_payload_floats(topo, net, age_cap, W, mesh),
+        with obs_span("potus/cohort-fused/reduce"):
+            if reach is None:
+                reach = _reachability(topo)
+            weights_s = [np.einsum("sic,ic->cs", p[0], mask) for p in prepped]
+            for s, scn in enumerate(group):
+                sat = float(capped[s]) / max(float(served[s]), 1e-9)
+                _maybe_warn_saturation(
+                    sat, age_cap,
+                    label=(f"scheduler={scheduler} V={scn.V} W={W} "
+                           f"arrival={scn.arrival} "
+                           f"events={getattr(scn, 'events', 'none')}"),
                 )
-                result = dataclasses.replace(result, metrics=frame)
-            results[scn.index] = result
+                result = _aggregate(
+                    resp_mass[s], resp_time[s], weights_s[0 if shared else s], reach,
+                    backlog[s], cost[s], sat, float(served[s]), T, W, warmup,
+                    drain_margin,
+                )
+                if metrics is not None:
+                    frame = build_frame(
+                        metrics, [slab[s] for slab in streams], n_slots=T,
+                        payload_floats=_fused_payload_floats(topo, net, age_cap, W, mesh),
+                    )
+                    result = dataclasses.replace(result, metrics=frame)
+                results[scn.index] = result
     return results, len(groups)

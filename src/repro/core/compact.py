@@ -567,142 +567,147 @@ def compact_slot_step(
     dt = q_rem.dtype
 
     # -- 1. reconcile window pos-0 with actual arrivals of slot t ------------
-    pred_m = _to_cmp(c, pred_t, kernel_safe) * c.stream_cmp
-    act_m = _to_cmp(c, act_t, kernel_safe) * c.stream_cmp
-    tp = jnp.minimum(pred_m, act_m)
-    tn = act_m - tp
-    r = jnp.where(pred_m > 0, q_rem[:, :, 0] / jnp.where(pred_m > 0, pred_m, 1.0), 0.0)
-    q_rem = jnp.concatenate([(r * tp + tn)[:, :, None], q_rem[:, :, 1:]], axis=-1)
+    with jax.named_scope("reconcile"):
+        pred_m = _to_cmp(c, pred_t, kernel_safe) * c.stream_cmp
+        act_m = _to_cmp(c, act_t, kernel_safe) * c.stream_cmp
+        tp = jnp.minimum(pred_m, act_m)
+        tn = act_m - tp
+        r = jnp.where(pred_m > 0, q_rem[:, :, 0] / jnp.where(pred_m > 0, pred_m, 1.0), 0.0)
+        q_rem = jnp.concatenate([(r * tp + tn)[:, :, None], q_rem[:, :, 1:]], axis=-1)
 
     # -- 2. observe queue state, schedule (compact decision) -----------------
-    q_in_arr = q_in_tag.sum(-1)
-    q_out_cmp = jnp.where(spout_f[:, None] > 0, q_rem.sum(-1), q_out_tag.sum(-1))
-    q_out_arr = _to_dense(c, q_out_cmp, kernel_safe)
-    must_send = _to_dense(c, (q_rem[:, :, 0] + admit) * spout_f[:, None], kernel_safe)
-    if ev:
-        mu_row, gamma_row, alive_row = ev[0]
-        mu_eff = mu_row * c.inv_service
-        if kernel_safe:
-            comp_count = jax.lax.dot_general(
-                alive_row[None, :], c.comp_onehot, (((1,), (0,)), ((), ())),
-                precision=_HI, preferred_element_type=dt)[0]
+    with jax.named_scope("decide"):
+        q_in_arr = q_in_tag.sum(-1)
+        q_out_cmp = jnp.where(spout_f[:, None] > 0, q_rem.sum(-1), q_out_tag.sum(-1))
+        q_out_arr = _to_dense(c, q_out_cmp, kernel_safe)
+        must_send = _to_dense(c, (q_rem[:, :, 0] + admit) * spout_f[:, None], kernel_safe)
+        if ev:
+            mu_row, gamma_row, alive_row = ev[0]
+            mu_eff = mu_row * c.inv_service
+            if kernel_safe:
+                comp_count = jax.lax.dot_general(
+                    alive_row[None, :], c.comp_onehot, (((1,), (0,)), ((), ())),
+                    precision=_HI, preferred_element_type=dt)[0]
+            else:
+                comp_count = jnp.zeros((C,), dt).at[c.inst_comp].add(alive_row)
+            if axis is not None:
+                comp_count = jax.lax.psum(comp_count, axis)
+            cp = CompactProblem(c.inst_comp, c.inst_cont, gamma_row, comp_count,
+                                c.adj_rows, alive_row)
+            must_send = must_send * alive_row[:, None]
         else:
-            comp_count = jnp.zeros((C,), dt).at[c.inst_comp].add(alive_row)
+            mu_eff = c.mu * c.inv_service
+            cp = CompactProblem(c.inst_comp, c.inst_cont, c.gamma, c.comp_count,
+                                c.adj_rows, jnp.ones((I,), dt))
+        dec = compact_decide(scheduler, cp, c.U, q_in_arr, q_out_arr, must_send,
+                             c.V, c.beta, kernel_safe, axis, n_shards)
+        backlog = _vsum(q_in_arr, kernel_safe) + c.beta * q_out_arr.sum()
+        cost = dec.cost
         if axis is not None:
-            comp_count = jax.lax.psum(comp_count, axis)
-        cp = CompactProblem(c.inst_comp, c.inst_cont, gamma_row, comp_count,
-                            c.adj_rows, alive_row)
-        must_send = must_send * alive_row[:, None]
-    else:
-        mu_eff = c.mu * c.inv_service
-        cp = CompactProblem(c.inst_comp, c.inst_cont, c.gamma, c.comp_count,
-                            c.adj_rows, jnp.ones((I,), dt))
-    dec = compact_decide(scheduler, cp, c.U, q_in_arr, q_out_arr, must_send,
-                         c.V, c.beta, kernel_safe, axis, n_shards)
-    backlog = _vsum(q_in_arr, kernel_safe) + c.beta * q_out_arr.sum()
-    cost = dec.cost
-    if axis is not None:
-        backlog = jax.lax.psum(backlog, axis)
-        cost = jax.lax.psum(cost, axis)
+            backlog = jax.lax.psum(backlog, axis)
+            cost = jax.lax.psum(cost, axis)
 
     # -- 3. drain sources oldest-first, split over targets -------------------
-    shipped_cmp = _to_cmp(c, dec.shipped, kernel_safe)
-    src_spout = jnp.concatenate(
-        [jnp.zeros((I, S, age_cap), dt), q_rem, admit[:, :, None]], axis=-1
-    )
-    src_bolt = jnp.concatenate([q_out_tag, jnp.zeros((I, S, 1), dt)], axis=-1)
-    src_ext = jnp.where(spout_f[:, None, None] > 0, src_spout, src_bolt)  # (I, S, Atot+1)
-    drained = _drain_ages(src_ext, shipped_cmp, kernel_safe)
-    q_rem = q_rem - drained[:, :, age_cap:Atot] * spout_f[:, None, None]
-    admit = admit - drained[:, :, Atot] * spout_f[:, None]
-    q_out_tag = q_out_tag - drained[:, :, :Atot] * bolt_f[:, None, None]
+    with jax.named_scope("drain"):
+        shipped_cmp = _to_cmp(c, dec.shipped, kernel_safe)
+        src_spout = jnp.concatenate(
+            [jnp.zeros((I, S, age_cap), dt), q_rem, admit[:, :, None]], axis=-1
+        )
+        src_bolt = jnp.concatenate([q_out_tag, jnp.zeros((I, S, 1), dt)], axis=-1)
+        src_ext = jnp.where(spout_f[:, None, None] > 0, src_spout, src_bolt)  # (I, S, Atot+1)
+        drained = _drain_ages(src_ext, shipped_cmp, kernel_safe)
+        q_rem = q_rem - drained[:, :, age_cap:Atot] * spout_f[:, None, None]
+        admit = admit - drained[:, :, Atot] * spout_f[:, None]
+        q_out_tag = q_out_tag - drained[:, :, :Atot] * bolt_f[:, None, None]
 
-    # landing: the admission slot re-tags to age 0 (bucket age_cap) on landing
-    d_land = jnp.concatenate(
-        [drained[:, :, :age_cap],
-         drained[:, :, age_cap:age_cap + 1] + drained[:, :, Atot:],
-         drained[:, :, age_cap + 1:Atot]], axis=-1,
-    )  # (I, S, Atot)
-    sh_safe = jnp.where(dec.shipped > 0, dec.shipped, 1.0)
-    live = dec.shipped > _EPS
-    w_pt = jnp.where(live, dec.point / sh_safe, 0.0)
-    w_ev = jnp.where(live, dec.even_per / sh_safe, 0.0)
-    if kernel_safe:
-        land, ev_cb = _land_kernel(c, dec.j_point, w_pt, w_ev, d_land)
-        ev_rows = jax.lax.dot_general(c.comp_onehot, ev_cb, (((1,), (0,)), ((), ())),
-                                      precision=_HI, preferred_element_type=dt)  # (I, Atot)
-    else:
-        d_dense = _to_dense3(c, d_land)  # (I, C, Atot)
-        wd = (w_pt[:, :, None] * d_dense).reshape(I * C, Atot)
-        if axis is not None:
-            # point targets are global ids: scatter the local sources' mass
-            # into the global landing buffer, fold it (the one O(I)-sized
-            # collective — the physical tuple transfer), keep our row block
-            I_all = I * n_shards
-            land_g = jnp.zeros((I_all + 1, Atot), dt).at[
-                dec.j_point.reshape(I * C)].add(wd)[:I_all]
-            land_g = jax.lax.psum(land_g, axis)
-            land = jax.lax.dynamic_slice_in_dim(land_g, jax.lax.axis_index(axis) * I, I)
+        # landing: the admission slot re-tags to age 0 (bucket age_cap) on landing
+        d_land = jnp.concatenate(
+            [drained[:, :, :age_cap],
+             drained[:, :, age_cap:age_cap + 1] + drained[:, :, Atot:],
+             drained[:, :, age_cap + 1:Atot]], axis=-1,
+        )  # (I, S, Atot)
+        sh_safe = jnp.where(dec.shipped > 0, dec.shipped, 1.0)
+        live = dec.shipped > _EPS
+        w_pt = jnp.where(live, dec.point / sh_safe, 0.0)
+        w_ev = jnp.where(live, dec.even_per / sh_safe, 0.0)
+        if kernel_safe:
+            land, ev_cb = _land_kernel(c, dec.j_point, w_pt, w_ev, d_land)
+            ev_rows = jax.lax.dot_general(c.comp_onehot, ev_cb, (((1,), (0,)), ((), ())),
+                                          precision=_HI, preferred_element_type=dt)  # (I, Atot)
         else:
-            land = jnp.zeros((I + 1, Atot), dt).at[dec.j_point.reshape(I * C)].add(wd)[:I]
-        # even spread: per-component contraction, broadcast to alive instances
-        ev_cb = jnp.einsum("ic,icb->cb", w_ev, d_dense, precision=_HI)  # (C, Atot)
-        if axis is not None:
-            ev_cb = jax.lax.psum(ev_cb, axis)
-        ev_rows = ev_cb[c.inst_comp]
-    land = land + cp.alive[:, None] * ev_rows
+            d_dense = _to_dense3(c, d_land)  # (I, C, Atot)
+            wd = (w_pt[:, :, None] * d_dense).reshape(I * C, Atot)
+            if axis is not None:
+                # point targets are global ids: scatter the local sources' mass
+                # into the global landing buffer, fold it (the one O(I)-sized
+                # collective — the physical tuple transfer), keep our row block
+                I_all = I * n_shards
+                land_g = jnp.zeros((I_all + 1, Atot), dt).at[
+                    dec.j_point.reshape(I * C)].add(wd)[:I_all]
+                land_g = jax.lax.psum(land_g, axis)
+                land = jax.lax.dynamic_slice_in_dim(land_g, jax.lax.axis_index(axis) * I, I)
+            else:
+                land = jnp.zeros((I + 1, Atot), dt).at[dec.j_point.reshape(I * C)].add(wd)[:I]
+            # even spread: per-component contraction, broadcast to alive instances
+            ev_cb = jnp.einsum("ic,icb->cb", w_ev, d_dense, precision=_HI)  # (C, Atot)
+            if axis is not None:
+                ev_cb = jax.lax.psum(ev_cb, axis)
+            ev_rows = ev_cb[c.inst_comp]
+        land = land + cp.alive[:, None] * ev_rows
 
     # -- 4. land last slot's transit, serve bolts ----------------------------
-    avail = q_in_tag + transit
-    served_amt = jnp.minimum(avail.sum(-1), mu_eff) * bolt_f
-    served_b = _drain_ages(avail, served_amt, kernel_safe)
-    q_in_tag = (avail - served_b) * bolt_f[:, None]
-    cmass = jax.lax.dot_general(
-        c.comp_onehot, served_b * c.term_f[:, None], (((0,), (0,)), ((), ())),
-        precision=_HI, preferred_element_type=dt,
-    )  # (C, Atot)
-    if axis is not None:
-        # fold served mass so the replicated response accumulators see the
-        # global per-component completions on every shard
-        cmass = jax.lax.psum(cmass, axis)
-    if kernel_safe:
-        ages = jax.lax.broadcasted_iota(jnp.int32, (1, Atot), 1).astype(dt)  # 2-D int iota
-        resp_row = jnp.maximum(age_cap - ages, 0.0)  # (1, Atot)
-        # accumulator columns [t, t + Atot) — always in range (len >= Tc + Atot);
-        # Pallas TPU cannot slice a value at a traced offset, so the bucket
-        # rows land through an (Atot, L) one-hot placement (one exact product
-        # per column, the same sums as the scatter below)
-        L = resp_mass.shape[-1]
-        col = jax.lax.broadcasted_iota(jnp.int32, (Atot, L), 1)
-        b = jax.lax.broadcasted_iota(jnp.int32, (Atot, L), 0)
-        place = (col == b + jnp.asarray(t, jnp.int32)).astype(dt)
-        dims = (((1,), (0,)), ((), ()))
-        resp_mass = resp_mass + jax.lax.dot_general(
-            cmass, place, dims, precision=_HI, preferred_element_type=dt)
-        resp_time = resp_time + jax.lax.dot_general(
-            cmass * resp_row, place, dims, precision=_HI, preferred_element_type=dt)
-    else:
-        resp_per_b = jnp.maximum(age_cap - jnp.arange(Atot, dtype=dt), 0.0)
-        idx = t + jnp.arange(Atot)
-        resp_mass = resp_mass.at[:, idx].add(cmass, mode="drop")
-        resp_time = resp_time.at[:, idx].add(cmass * resp_per_b[None, :], mode="drop")
-    capped_served = _vsum(cmass[:, 0], kernel_safe)
-    term_served = cmass.sum()
-    q_out_tag = q_out_tag + served_b[:, None, :] * c.sel_cmp[:, :, None] * bolt_f[:, None, None]
+    with jax.named_scope("land"):
+        avail = q_in_tag + transit
+        served_amt = jnp.minimum(avail.sum(-1), mu_eff) * bolt_f
+        served_b = _drain_ages(avail, served_amt, kernel_safe)
+        q_in_tag = (avail - served_b) * bolt_f[:, None]
+        cmass = jax.lax.dot_general(
+            c.comp_onehot, served_b * c.term_f[:, None], (((0,), (0,)), ((), ())),
+            precision=_HI, preferred_element_type=dt,
+        )  # (C, Atot)
+        if axis is not None:
+            # fold served mass so the replicated response accumulators see the
+            # global per-component completions on every shard
+            cmass = jax.lax.psum(cmass, axis)
+        if kernel_safe:
+            ages = jax.lax.broadcasted_iota(jnp.int32, (1, Atot), 1).astype(dt)  # 2-D int iota
+            resp_row = jnp.maximum(age_cap - ages, 0.0)  # (1, Atot)
+            # accumulator columns [t, t + Atot) — always in range (len >= Tc + Atot);
+            # Pallas TPU cannot slice a value at a traced offset, so the bucket
+            # rows land through an (Atot, L) one-hot placement (one exact product
+            # per column, the same sums as the scatter below)
+            L = resp_mass.shape[-1]
+            col = jax.lax.broadcasted_iota(jnp.int32, (Atot, L), 1)
+            b = jax.lax.broadcasted_iota(jnp.int32, (Atot, L), 0)
+            place = (col == b + jnp.asarray(t, jnp.int32)).astype(dt)
+            dims = (((1,), (0,)), ((), ()))
+            resp_mass = resp_mass + jax.lax.dot_general(
+                cmass, place, dims, precision=_HI, preferred_element_type=dt)
+            resp_time = resp_time + jax.lax.dot_general(
+                cmass * resp_row, place, dims, precision=_HI, preferred_element_type=dt)
+        else:
+            resp_per_b = jnp.maximum(age_cap - jnp.arange(Atot, dtype=dt), 0.0)
+            idx = t + jnp.arange(Atot)
+            resp_mass = resp_mass.at[:, idx].add(cmass, mode="drop")
+            resp_time = resp_time.at[:, idx].add(cmass * resp_per_b[None, :], mode="drop")
+        capped_served = _vsum(cmass[:, 0], kernel_safe)
+        term_served = cmass.sum()
+        q_out_tag = q_out_tag + served_b[:, None, :] * c.sel_cmp[:, :, None] * bolt_f[:, None, None]
 
     # -- 5. admit leftover actuals, shift windows and age axes ---------------
-    admit = admit + q_rem[:, :, 0] * spout_f[:, None]
-    q_rem = jnp.concatenate(
-        [q_rem[:, :, 1:], (_to_cmp(c, new_pred, kernel_safe) * c.stream_cmp)[:, :, None]],
-        axis=-1,
-    )
+    with jax.named_scope("admit"):
+        admit = admit + q_rem[:, :, 0] * spout_f[:, None]
+        q_rem = jnp.concatenate(
+            [q_rem[:, :, 1:], (_to_cmp(c, new_pred, kernel_safe) * c.stream_cmp)[:, :, None]],
+            axis=-1,
+        )
 
-    def shift(x):  # age b+1 -> b; the oldest bucket saturates (A-cap rule)
-        head = x[..., 0:1] + x[..., 1:2]
-        return jnp.concatenate([head, x[..., 2:], jnp.zeros_like(x[..., 0:1])], axis=-1)
+        def shift(x):  # age b+1 -> b; the oldest bucket saturates (A-cap rule)
+            head = x[..., 0:1] + x[..., 1:2]
+            return jnp.concatenate([head, x[..., 2:], jnp.zeros_like(x[..., 0:1])], axis=-1)
 
-    state = (q_rem, admit, shift(q_in_tag), shift(q_out_tag), shift(land),
-             resp_mass, resp_time)
+        state = (q_rem, admit, shift(q_in_tag), shift(q_out_tag), shift(land),
+                 resp_mass, resp_time)
     out = (backlog, cost, capped_served, term_served)
     if metrics_spec is not None:
         # §14 metric streams ride as extra scan outputs. Under sharding the
@@ -710,29 +715,30 @@ def compact_slot_step(
         # replicated global row (the quantile/sort reductions need the full
         # vector); scalars fold with psum. Never on the kernel path — the
         # engine gates metrics off it (collectives cannot lower into Pallas).
-        landed = land.sum(-1)
-        price = c.V * c.U.mean(axis=0)[c.inst_cont] + q_in_arr
-        comp_backlog = jnp.einsum("i,ic->c", q_in_arr, c.comp_onehot)
-        held = admit.sum()
-        dropped = (r * (pred_m - tp)).sum()
-        tp_s, fp_s, tn_s = tp.sum(), (pred_m - tp).sum(), tn.sum()
-        if axis is not None:
-            q_in_g = jax.lax.all_gather(q_in_arr, axis, tiled=True)
-            price_g = jax.lax.all_gather(price, axis, tiled=True)
-            landed_g = jax.lax.all_gather(landed, axis, tiled=True)
-            comp_backlog = jax.lax.psum(comp_backlog, axis)
-            held = jax.lax.psum(held, axis)
-            dropped = jax.lax.psum(dropped, axis)
-            tp_s = jax.lax.psum(tp_s, axis)
-            fp_s = jax.lax.psum(fp_s, axis)
-            tn_s = jax.lax.psum(tn_s, axis)
-        else:
-            q_in_g, price_g, landed_g = q_in_arr, price, landed
-        ctx = {
-            "h": backlog, "q_in": q_in_g, "price": price_g, "landed": landed_g,
-            "transit_total": landed_g.sum(), "comp_backlog": comp_backlog,
-            "held": held, "dropped": dropped, "tp": tp_s, "fp": fp_s, "tn": tn_s,
-            "capped": capped_served, "served": term_served,
-        }
-        out = out + compute_scan_streams(scan_stream_names(metrics_spec), ctx)
+        with jax.named_scope("metrics"):
+            landed = land.sum(-1)
+            price = c.V * c.U.mean(axis=0)[c.inst_cont] + q_in_arr
+            comp_backlog = jnp.einsum("i,ic->c", q_in_arr, c.comp_onehot)
+            held = admit.sum()
+            dropped = (r * (pred_m - tp)).sum()
+            tp_s, fp_s, tn_s = tp.sum(), (pred_m - tp).sum(), tn.sum()
+            if axis is not None:
+                q_in_g = jax.lax.all_gather(q_in_arr, axis, tiled=True)
+                price_g = jax.lax.all_gather(price, axis, tiled=True)
+                landed_g = jax.lax.all_gather(landed, axis, tiled=True)
+                comp_backlog = jax.lax.psum(comp_backlog, axis)
+                held = jax.lax.psum(held, axis)
+                dropped = jax.lax.psum(dropped, axis)
+                tp_s = jax.lax.psum(tp_s, axis)
+                fp_s = jax.lax.psum(fp_s, axis)
+                tn_s = jax.lax.psum(tn_s, axis)
+            else:
+                q_in_g, price_g, landed_g = q_in_arr, price, landed
+            ctx = {
+                "h": backlog, "q_in": q_in_g, "price": price_g, "landed": landed_g,
+                "transit_total": landed_g.sum(), "comp_backlog": comp_backlog,
+                "held": held, "dropped": dropped, "tp": tp_s, "fp": fp_s, "tn": tn_s,
+                "capped": capped_served, "served": term_served,
+            }
+            out = out + compute_scan_streams(scan_stream_names(metrics_spec), ctx)
     return state, out

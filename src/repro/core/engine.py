@@ -32,7 +32,10 @@ the compact one-dispatch cohort engine — full response-time semantics, no
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any
+
+from repro.obs.trace import span as obs_span
 
 __all__ = ["EngineSpec", "UnsupportedEngineOption", "simulate", "ENGINES",
            "OPTION_SUPPORT", "check_metrics_spec"]
@@ -183,6 +186,10 @@ class EngineSpec:
             check_engine_option(self.engine, option)
 
 
+#: per-process index of :func:`simulate` calls, the request span's identifier
+_CALLS = itertools.count()
+
+
 def simulate(spec: EngineSpec):
     """Run one fully-specified simulation; the unified entry point.
 
@@ -193,6 +200,11 @@ def simulate(spec: EngineSpec):
     for the scan engines, :class:`~repro.core.cohort.CohortResult` for the
     cohort engines.
     """
+    with obs_span("potus/engine/simulate", call=next(_CALLS), engine=spec.engine):
+        return _simulate(spec)
+
+
+def _simulate(spec: EngineSpec):
     spec.validate()
     cfg = spec.config()
     metrics = check_metrics_spec(spec.engine, spec.metrics)

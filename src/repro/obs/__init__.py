@@ -19,13 +19,12 @@ from repro.obs.metrics import (
 )
 from repro.obs.recorder import FlightRecorder
 from repro.obs.trace import (
-    SpanTracer,
-    chrome_trace,
+    count,
     disable_tracing,
     enable_tracing,
-    export_chrome_trace,
-    get_tracer,
     span,
+    take_counters,
+    tracing_enabled,
 )
 
 __all__ = [
@@ -41,11 +40,10 @@ __all__ = [
     "stream_engines",
     "unsupported_streams",
     "FlightRecorder",
-    "SpanTracer",
-    "chrome_trace",
+    "count",
     "disable_tracing",
     "enable_tracing",
-    "export_chrome_trace",
-    "get_tracer",
     "span",
+    "take_counters",
+    "tracing_enabled",
 ]

@@ -1,136 +1,75 @@
-"""Host-side span tracing with a Chrome-trace (Perfetto-loadable) exporter.
+"""Program spans and counters, written into the ``jax.profiler`` trace.
 
-Spans wrap the engine's host-visible phases — problem build, scheduler call,
-chunk, drain, kernel launch, dispatcher route — under the naming convention
-``potus/<engine-or-layer>/<stage>`` (DESIGN.md §14.3).  When tracing is
-enabled each span also opens a ``jax.profiler.TraceAnnotation`` of the same
-name, so a device profile captured with ``benchmarks/run.py --profile DIR``
-lines up with the engine phases in the profiler UI.
+Spans wrap the engines' host-visible phases under the naming convention
+``potus/<layer>/<stage>`` (DESIGN.md §14.2). When tracing is on, each span
+is a ``jax.profiler.TraceAnnotation`` of that name — its keyword arguments
+become the event's stats — so a profile captured with ``jax.profiler``
+holds the program's phases on the same clock as the device's operations.
+The profiler's trace is the one record: with no profiler session running,
+an annotation costs a few hundred nanoseconds and records nothing.
 
-Tracing is **off by default**: :func:`span` is a no-op context manager until
-:func:`enable_tracing` runs, so the engines can leave the ``with`` statements
-in place at zero steady-state cost.  Events live in a bounded ring (oldest
-dropped) and export via :func:`export_chrome_trace` as the standard
-``{"traceEvents": [...]}`` JSON that chrome://tracing and Perfetto load
-directly.
+Counters (:func:`count`) add up quantities the trace cannot show, such as
+the bytes handed to the device; :func:`take_counters` reads them and
+starts them again from zero.
+
+Tracing is **off by default**: :func:`span` is a no-op context manager
+and :func:`count` returns at once until :func:`enable_tracing` runs, so the
+engines leave the statements in place at no steady-state cost.
 """
 from __future__ import annotations
 
 import contextlib
-import json
-import os
 import threading
-import time
-from collections import deque
-from typing import Iterator
 
 __all__ = [
-    "SpanTracer",
     "span",
-    "get_tracer",
+    "count",
+    "take_counters",
     "enable_tracing",
     "disable_tracing",
-    "chrome_trace",
-    "export_chrome_trace",
+    "tracing_enabled",
 ]
 
-
-class SpanTracer:
-    """Bounded in-memory span collector (thread-safe, nesting-aware)."""
-
-    def __init__(self, capacity: int = 8192):
-        self.capacity = int(capacity)
-        self._events: deque[dict] = deque(maxlen=self.capacity)
-        self._lock = threading.Lock()
-        self._local = threading.local()
-        self.enabled = False
-        self._t0 = time.perf_counter()
-
-    def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
-            self._t0 = time.perf_counter()
-
-    def _depth(self) -> int:
-        return getattr(self._local, "depth", 0)
-
-    @contextlib.contextmanager
-    def span(self, name: str, **meta) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        annotation = None
-        try:  # line device profiles up with host phases when jax is around
-            import jax.profiler
-
-            annotation = jax.profiler.TraceAnnotation(name)
-        except Exception:
-            annotation = None
-        begin = time.perf_counter()
-        self._local.depth = self._depth() + 1
-        try:
-            if annotation is not None:
-                with annotation:
-                    yield
-            else:
-                yield
-        finally:
-            end = time.perf_counter()
-            self._local.depth = self._depth() - 1
-            event = {
-                "name": name,
-                "ph": "X",
-                "ts": (begin - self._t0) * 1e6,  # chrome trace wants µs
-                "dur": (end - begin) * 1e6,
-                "pid": os.getpid(),
-                "tid": threading.get_ident() % 2**31,
-            }
-            if meta:
-                event["args"] = {k: str(v) for k, v in meta.items()}
-            with self._lock:
-                self._events.append(event)
-
-    def chrome_trace(self) -> dict:
-        with self._lock:
-            events = list(self._events)
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def export_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-            f.write("\n")
-
-    def __len__(self) -> int:
-        return len(self._events)
+_enabled = False
+_counters: dict[str, int] = {}
+_lock = threading.Lock()
+_NOOP = contextlib.nullcontext()
 
 
-_TRACER = SpanTracer()
-
-
-def get_tracer() -> SpanTracer:
-    return _TRACER
-
-
-def enable_tracing(capacity: int | None = None) -> SpanTracer:
-    if capacity is not None:
-        _TRACER._events = deque(_TRACER._events, maxlen=int(capacity))
-        _TRACER.capacity = int(capacity)
-    _TRACER.enabled = True
-    return _TRACER
+def enable_tracing() -> None:
+    global _enabled
+    _enabled = True
 
 
 def disable_tracing() -> None:
-    _TRACER.enabled = False
+    global _enabled
+    _enabled = False
 
 
-def span(name: str, **meta):
-    """Module-level convenience over the global tracer (no-op when disabled)."""
-    return _TRACER.span(name, **meta)
+def tracing_enabled() -> bool:
+    return _enabled
 
 
-def chrome_trace() -> dict:
-    return _TRACER.chrome_trace()
+def span(name: str, **stats):
+    """``TraceAnnotation(name, **stats)`` when tracing is on, else a no-op."""
+    if not _enabled:
+        return _NOOP
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
-def export_chrome_trace(path: str) -> None:
-    _TRACER.export_chrome_trace(path)
+def count(name: str, n: int) -> None:
+    """Add ``n`` to counter ``name``; nothing happens while tracing is off."""
+    if not _enabled:
+        return
+    with _lock:
+        _counters[name] = _counters.get(name, 0) + int(n)
+
+
+def take_counters() -> dict[str, int]:
+    """The counters' values since the last call; every counter restarts at 0."""
+    with _lock:
+        out = dict(_counters)
+        _counters.clear()
+    return out

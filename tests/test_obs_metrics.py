@@ -36,7 +36,6 @@ from repro.obs import (
     FlightRecorder,
     MetricsFrame,
     MetricsSpec,
-    SpanTracer,
     stream_engines,
     unsupported_streams,
 )
@@ -229,42 +228,121 @@ class TestFrameAndRecorder:
         assert rec.rows()[1]["backlog_tokens"] > 0  # request landed at t=1
 
 
-class TestSpanTracing:
-    def test_span_noop_when_disabled(self):
-        tracer = SpanTracer()
-        with tracer.span("potus/test/stage"):
-            pass
-        assert len(tracer) == 0
+def _profiled(fn):
+    """Run ``fn`` under a ``jax.profiler`` trace; (its result, the host
+    events of the trace whose name starts with ``potus/``, as (name, start,
+    end, stats) tuples)."""
+    import glob
+    import tempfile
 
-    def test_span_capture_and_chrome_export(self, tmp_path):
-        tracer = SpanTracer(capacity=4)
-        tracer.enabled = True
-        for t in range(6):  # overflow the ring: oldest spans evicted
-            with tracer.span("potus/test/stage", t=t):
-                pass
-        assert len(tracer) == 4
-        trace = tracer.chrome_trace()
-        ev = trace["traceEvents"][-1]
-        assert ev["name"] == "potus/test/stage" and ev["ph"] == "X"
-        assert ev["args"]["t"] == "5" and ev["dur"] >= 0
-        path = tmp_path / "trace.json"
-        tracer.export_chrome_trace(str(path))
-        assert json.loads(path.read_text())["traceEvents"][0]["name"] == "potus/test/stage"
+    import jax
+    from jax.profiler import ProfileData
 
-    def test_global_tracer_toggles(self):
-        from repro.obs import disable_tracing, enable_tracing, get_tracer, span
-
-        tracer = enable_tracing()
-        tracer.clear()
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
         try:
-            with span("potus/test/global"):
-                pass
-            assert len(get_tracer()) == 1
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(f"{d}/**/*.xplane.pb", recursive=True)[0]
+        data = ProfileData.from_file(path)
+        spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                 for plane in data.planes if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith("potus/")]
+    return out, spans
+
+
+class TestSpanTracing:
+    """Program spans live in the profiler's trace, counters beside them."""
+
+    PHASES = ("prep", "upload", "chunk", "fetch", "reduce")
+
+    def test_span_noop_when_disabled(self, system):
+        from repro.obs import take_counters, tracing_enabled
+
+        assert not tracing_enabled()
+        take_counters()
+        _, spans = _profiled(lambda: simulate(_spec(system, engine="cohort-fused",
+                                                    warmup=5)))
+        assert spans == []
+        assert take_counters() == {}
+
+    def test_phases_nest_in_the_request_span(self, system):
+        from repro.obs import disable_tracing, enable_tracing
+
+        enable_tracing()
+        try:
+            _, spans = _profiled(lambda: [
+                simulate(_spec(system, engine="cohort-fused", warmup=5))
+                for _ in range(2)])
         finally:
             disable_tracing()
-        with span("potus/test/after"):
-            pass
-        assert len(get_tracer()) == 1  # disabled again: no new events
+        requests = [s for s in spans if s[0] == "potus/engine/simulate"]
+        assert len(requests) == 2
+        calls = [r[3]["call"] for r in requests]
+        assert calls[1] == calls[0] + 1  # the per-process call index
+        assert all(r[3]["engine"] == "cohort-fused" for r in requests)
+        for _, r0, r1, _ in requests:
+            inside = [n for n, s0, s1, _ in spans if r0 <= s0 and s1 <= r1]
+            for phase in self.PHASES:
+                assert f"potus/cohort-fused/{phase}" in inside, phase
+        starts = {n.rsplit("/", 1)[1]: s0 for n, s0, _, _ in spans
+                  if n.startswith("potus/cohort-fused/") and s0 < requests[0][2]}
+        assert sorted(starts, key=starts.get) == list(self.PHASES)
+
+    def test_counters_reckon_the_bytes_from_shapes(self, system):
+        from repro.obs import disable_tracing, enable_tracing, take_counters
+
+        topo, net, _, _ = system
+        I, C, K = topo.n_instances, topo.n_components, net.U.shape[0]
+        S = max(len(topo.successors_of_comp(c)) for c in range(C))
+        age_cap = 64
+        take_counters()
+        enable_tracing()
+        try:
+            simulate(_spec(system, engine="cohort-fused", warmup=5, age_cap=age_cap))
+        finally:
+            disable_tracing()
+        got = take_counters()
+        f32 = 4
+        h2d = (3 * T * I * C * f32  # act, pred, nxt: (T, I, C) float32
+               + I * S * (W + 1) * f32  # the window's initial contents
+               + 2 * f32  # V, beta
+               + K * K * f32 + I * f32 + 4 * I * S * f32 + I * f32 + I * C * f32  # constants
+               + 3 * I * f32 + C * f32 + I)  # the problem; is_spout is bool
+        d2h = (2 * T * f32 + 2 * f32  # backlog, cost; capped, served
+               + 2 * C * (T + age_cap + W + 1) * f32)  # response accumulators
+        assert got == {"h2d_bytes": h2d, "d2h_bytes": d2h}
+        assert take_counters() == {}  # read-and-reset
+
+    def test_tracing_compiles_nothing_new(self, system):
+        from repro.core.cohort_fused import _scan_cohort_fused
+        from repro.obs import disable_tracing, enable_tracing
+
+        simulate(_spec(system, engine="cohort-fused", warmup=5))
+        n = _scan_cohort_fused._cache_size()
+        enable_tracing()
+        try:
+            simulate(_spec(system, engine="cohort-fused", warmup=5))
+        finally:
+            disable_tracing()
+        assert _scan_cohort_fused._cache_size() == n
+
+    @pytest.mark.parametrize("scheduler", ["potus", "potus-loop"])  # compact, dense
+    def test_slot_step_stages_are_named(self, system, scheduler):
+        from repro.core.cohort_fused import scan_hlo_text
+        from repro.obs import disable_tracing, enable_tracing
+
+        enable_tracing()
+        try:
+            simulate(_spec(system, engine="cohort-fused", warmup=5, scheduler=scheduler,
+                           metrics=True))
+        finally:
+            disable_tracing()
+        hlo = scan_hlo_text()
+        for stage in ("reconcile", "decide", "drain", "land", "admit", "metrics"):
+            assert f"/{stage}/" in hlo, stage
 
 
 class TestCLITools:
