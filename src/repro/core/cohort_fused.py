@@ -54,7 +54,10 @@ cohort engines).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
+import threading
 import warnings
+from collections import OrderedDict
 from functools import partial
 
 import jax
@@ -154,21 +157,64 @@ class _CompactProb(NamedTuple):
 
 
 def _compact_prob(topo: Topology, inst_container) -> _CompactProb:
-    return _CompactProb(
-        inst_comp=_to_device(topo.inst_comp),
-        inst_container=_to_device(inst_container, np.int32),
-        gamma=_to_device(topo.inst_gamma),
-        comp_count=_to_device(topo.comp_parallelism, np.float32),
-        is_spout=_to_device(topo.comp_is_spout[topo.inst_comp]),
-    )
+    return _CompactProb(**_resident(dict(
+        inst_comp=topo.inst_comp,
+        inst_container=np.asarray(inst_container, np.int32),
+        gamma=topo.inst_gamma,
+        comp_count=np.asarray(topo.comp_parallelism, np.float32),
+        is_spout=topo.comp_is_spout[topo.inst_comp],
+    )))
+
+
+def _host(x, dtype=None) -> np.ndarray:
+    """``x`` as the host array JAX would place: canonical dtype."""
+    a = np.asarray(x, dtype)
+    return a.astype(jax.dtypes.canonicalize_dtype(a.dtype), copy=False)
 
 
 def _to_device(x, dtype=None) -> jax.Array:
     """``jnp.asarray`` of a host array; its bytes count as ``h2d_bytes``."""
-    a = np.asarray(x, dtype)
-    a = a.astype(jax.dtypes.canonicalize_dtype(a.dtype), copy=False)
+    a = _host(x, dtype)
     obs_count("h2d_bytes", a.nbytes)
     return jnp.asarray(a)
+
+
+#: device copies of slot-invariant inputs, by content (see :func:`_resident`)
+_RESIDENT: OrderedDict = OrderedDict()
+_RESIDENT_MAX = 4
+_RESIDENT_LOCK = threading.Lock()
+
+
+def _resident(arrays: dict) -> dict:
+    """Device copies of ``arrays``, uploaded once per content: calls on one
+    deployment reuse them. On a TPU v5e host every transfer costs ~0.25 ms
+    of host time whatever its size, so re-sending a topology's constants
+    each call cost more than the arrival streams did (PERF.md §6). The key
+    hashes every array's name, dtype, shape and bytes; the cache keeps the
+    last ``_RESIDENT_MAX`` contents."""
+    host = {k: _host(v) for k, v in arrays.items()}
+    h = hashlib.blake2b(digest_size=20)
+    for k, a in host.items():
+        h.update(f"{k}:{a.dtype.str}:{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).data)
+    key = h.digest()
+    with _RESIDENT_LOCK:
+        dev = _RESIDENT.get(key)
+        if dev is None:
+            dev = _RESIDENT[key] = {k: _to_device(a) for k, a in host.items()}
+            if len(_RESIDENT) > _RESIDENT_MAX:
+                _RESIDENT.popitem(last=False)
+        else:
+            _RESIDENT.move_to_end(key)
+        return dict(dev)
+
+
+def _to_device_stream(x) -> jax.Array:
+    """``_to_device`` of packed arrival lanes; their bytes also count as
+    ``packed_stream_bytes``."""
+    a = _to_device(x, np.float32)
+    obs_count("packed_stream_bytes", a.nbytes)
+    return a
 
 
 def _fetch(x) -> np.ndarray:
@@ -201,6 +247,8 @@ class _Compact:
     sel_cmp: np.ndarray  # (I, S) f32 — selectivity toward each successor
     stream_cmp: np.ndarray  # (I, S) f32 — valid & spout row (window streams)
     adj_rows: np.ndarray  # (I, C) f32 — 1 where comp(i) -> c is a DAG edge
+    lanes: np.ndarray  # (2, L) int32 — (instance, successor comp) of each stream lane
+    lane_slot: np.ndarray  # (L,) — each stream lane's successor slot
 
 
 def _compact(topo: Topology) -> _Compact:
@@ -231,7 +279,12 @@ def _compact(topo: Topology) -> _Compact:
             sel_cmp[rs:re, s] = topo.selectivity[c, c2]
             adj_rows[rs:re, c2] = 1.0
     stream_cmp = valid * is_spout[:, None].astype(np.float32)
-    return _Compact(S, tuple(edges), succ_map, valid, sel_cmp, stream_cmp, adj_rows)
+    # the stream lanes, in row-major (instance, successor component) order:
+    # successors ascend, so row-major (instance, slot) order is the same
+    lane_i, lane_slot = np.nonzero(stream_cmp)
+    lanes = np.stack([lane_i, succ_map[lane_i, lane_slot]]).astype(np.int32)
+    return _Compact(S, tuple(edges), succ_map, valid, sel_cmp, stream_cmp,
+                    adj_rows, lanes, lane_slot)
 
 
 def _fused_step(
@@ -470,13 +523,50 @@ def _step_consts(prob, comp_onehot, U, mu, inv_service, sel_cmp, stream_cmp,
     )
 
 
+def _dense_streams(lanes, pred_s, actual_s, W1: int, I: int, C: int):
+    """The slot step's dense ``(…, Tc, I, C)`` actual, predicted and entering
+    (slot t+W+1) arrivals, scattered from the packed stream lanes into zeros:
+    every other entry is masked away by the step's ``stream_cmp``. ``pred``
+    and ``nxt`` are windows of the one prediction stream of Tc + W + 1 slots."""
+
+    def dense(x):
+        return jnp.zeros(x.shape[:-1] + (I, C), x.dtype).at[..., lanes[0], lanes[1]].set(x)
+
+    full = dense(pred_s)
+    n = pred_s.shape[-2] - W1
+    pred, nxt = full[..., :n, :, :], full[..., W1:, :, :]
+    return (pred if actual_s is None else dense(actual_s)), pred, nxt
+
+
+def _with_accumulators(carry, n_slots: int, age_cap: int, C: int):
+    """The scan's full state: the carried queues plus this chunk's response
+    accumulators, zeros of (S, C, n_slots + age_cap + W + 1)."""
+    Sn, W1 = carry[0].shape[0], carry[0].shape[-1]
+    acc = jnp.zeros((Sn, C, n_slots + age_cap + W1), carry[0].dtype)
+    return tuple(carry) + (acc, acc)
+
+
+@partial(jax.jit, static_argnames=("age_cap",))
+def _initial_carry(q_rem0: jax.Array, age_cap: int):
+    """The queue state at slot 0, made on the device: the spouts' initial
+    windows (S, I, Sc, W+1), every other queue empty."""
+    Sn, I, Sc, W1 = q_rem0.shape
+    Atot = age_cap + W1
+
+    def zeros(*shape):
+        return jnp.zeros(shape, q_rem0.dtype)
+
+    return (q_rem0, zeros(Sn, I, Sc), zeros(Sn, I, Atot), zeros(Sn, I, Sc, Atot),
+            zeros(Sn, I, Atot))
+
+
 @partial(jax.jit, static_argnames=("edges", "scheduler", "use_pallas", "age_cap",
                                    "n_components", "shared_inputs", "events_shared",
                                    "slots_per_launch", "metrics_spec"),
          donate_argnames=("states",))
 def _scan_cohort_fused(
     prob,
-    states,  # 7-tuple state pytree, leading scenario axis (always batched)
+    states,  # 5-tuple queue state (the carry), leading scenario axis (always batched)
     U: jax.Array,  # (K, K)
     mu: jax.Array,  # (I,)
     inv_service: jax.Array,  # (I,)
@@ -486,9 +576,9 @@ def _scan_cohort_fused(
     succ_map: jax.Array,  # (I, S) int32
     term_f: jax.Array,  # (I,)
     adj_rows: jax.Array,  # (I, C)
-    actual_s: jax.Array,  # (S?, Tc, I, C) actual arrivals (unbatched if shared)
-    pred_s: jax.Array,  # (S?, Tc, I, C) predictions for the chunk's slots
-    nxt_s: jax.Array,  # (S?, Tc, I, C) predictions entering the window (t+W+1)
+    lanes: jax.Array,  # (2, L) int32 stream lanes (``_Compact.lanes``)
+    pred_s: jax.Array,  # (S?, Tc+W+1, L) packed predictions (unbatched if shared)
+    actual_s: jax.Array | None,  # (S?, Tc, L) packed actuals; None: pred_s[:Tc]
     Vs: jax.Array,  # (S,)
     betas: jax.Array,  # (S,)
     events_s=None,  # (S?, Tc, I) (mu_t, gamma_t, alive_t) triple, or None
@@ -504,10 +594,13 @@ def _scan_cohort_fused(
 ):
     """Scan one chunk of slots for every scenario in the batch.
 
-    The full state (queues + this chunk's response accumulators) is an
-    explicit input/output so a chunked run can thread it through repeated
-    calls at fixed device memory — the input buffers are donated to the next
-    chunk. The monolithic run is the single-chunk case of the same function.
+    The queue state is an explicit input/output so a chunked run can thread
+    it through repeated calls at fixed device memory — the input buffers are
+    donated to the next chunk; the output adds this chunk's response
+    accumulators, which start as zeros made here. The monolithic run is the
+    single-chunk case of the same function. The chunk's arrivals come as
+    packed stream lanes and are expanded into the step's dense inputs once,
+    before the scan (DESIGN.md §11.2).
 
     Scheduler routing (DESIGN.md §12): every scheduler in
     :data:`~repro.core.compact.COMPACT_SCHEDULERS` runs the one-dispatch
@@ -520,6 +613,9 @@ def _scan_cohort_fused(
     ``potus-loop`` keeps the dense reference path (and, under ``use_pallas``,
     the ``cohort_drain`` kernel).
     """
+    actual_s, pred_s, nxt_s = _dense_streams(lanes, pred_s, actual_s, states[0].shape[-1],
+                                             mu.shape[0], n_components)
+    states = _with_accumulators(states, nxt_s.shape[-3], age_cap, n_components)
     comp_onehot = jax.nn.one_hot(prob.inst_comp, n_components, dtype=mu.dtype)
     compact = scheduler in COMPACT_SCHEDULERS
     # metrics never ride the kernel path: stream reductions (sorts) cannot
@@ -571,7 +667,7 @@ def _scan_cohort_fused(
 def _scan_cohort_sharded(
     mesh,
     prob: _CompactProb,
-    states,  # 7-tuple state pytree, leading scenario axis (always batched)
+    states,  # 5-tuple queue state (the carry), leading scenario axis (always batched)
     U: jax.Array,  # (K, K)
     mu: jax.Array,  # (I,)
     inv_service: jax.Array,  # (I,)
@@ -581,9 +677,9 @@ def _scan_cohort_sharded(
     succ_map: jax.Array,  # (I, S) int32
     term_f: jax.Array,  # (I,)
     adj_rows: jax.Array,  # (I, C)
-    actual_s: jax.Array,  # (S?, Tc, I, C) actual arrivals (unbatched if shared)
-    pred_s: jax.Array,  # (S?, Tc, I, C)
-    nxt_s: jax.Array,  # (S?, Tc, I, C)
+    lanes: jax.Array,  # (2, L) int32 stream lanes (``_Compact.lanes``)
+    pred_s: jax.Array,  # (S?, Tc+W+1, L) packed predictions (unbatched if shared)
+    actual_s: jax.Array | None,  # (S?, Tc, L) packed actuals; None: pred_s[:Tc]
     Vs: jax.Array,  # (S,)
     betas: jax.Array,  # (S,)
     events_s=None,  # (S?, Tc, I) (mu_t, gamma_t, alive_t) triple, or None
@@ -623,6 +719,10 @@ def _scan_cohort_sharded(
             f"{COMPACT_SCHEDULERS}, got {scheduler!r}"
         )
     n_shards = mesh.shape[COHORT_AXIS]
+    # expanded before the shard_map, which row-shards the dense streams
+    actual_s, pred_s, nxt_s = _dense_streams(lanes, pred_s, actual_s, states[0].shape[-1],
+                                             mu.shape[0], n_components)
+    states = _with_accumulators(states, nxt_s.shape[-3], age_cap, n_components)
     kernel_path = (use_pallas and scheduler == "potus" and events_s is None
                    and n_shards == 1 and metrics_spec is None)
 
@@ -692,13 +792,6 @@ def _scan_cohort_sharded(
 # host-side preparation and aggregation
 # ---------------------------------------------------------------------------
 
-def _stream_mask(topo: Topology) -> np.ndarray:
-    """(I, C) — 1.0 on the (spout instance, successor component) streams the
-    Python engine enumerates as ``spout_streams``."""
-    is_spout = topo.comp_is_spout[topo.inst_comp]
-    return (topo.adj[topo.inst_comp] & is_spout[:, None]).astype(np.float32)
-
-
 def _terminal_mask(topo: Topology) -> np.ndarray:
     term = np.zeros(topo.n_components, bool)
     term[topo.terminal_components] = True
@@ -718,16 +811,37 @@ def _reachability(topo: Topology) -> np.ndarray:
     return reach
 
 
-def _prep_streams(actual, predicted, T: int, W: int, cpt: _Compact, mask: np.ndarray):
-    """Pad/slice one scenario's arrival tensors into scan inputs."""
-    act = pad_arrivals(np.asarray(actual, np.float32), T)[:T]
-    pred = pad_arrivals(np.asarray(predicted if predicted is not None else actual,
-                                   np.float32), T + W + 1)
-    q_rem0 = np.moveaxis(pred[: W + 1], 0, -1) * mask[:, :, None]  # (I, C, W+1)
-    C = mask.shape[1]
-    idx = np.minimum(cpt.succ_map, C - 1)[:, :, None]
-    q_rem0_cmp = np.take_along_axis(q_rem0, idx, axis=1) * cpt.valid[:, :, None]
-    return act, pred[:T], pred[W + 1: T + W + 1], q_rem0_cmp.astype(np.float32)
+def _prep_streams(actual, predicted, T: int, W: int, cpt: _Compact):
+    """Pack one scenario's arrivals into the stream lanes the scan reads
+    (``cpt.lanes``; the slot step masks every other entry away).
+
+    Returns ``(pred, act, q_rem0)``: the prediction stream of slots
+    0..T+W, (T+W+1, L); the actual stream of slots 0..T-1, (T, L), or None
+    under perfect prediction, where it is ``pred[:T]``; and the spouts'
+    initial lookahead windows, (I, S, W+1)."""
+    def pack(x, n):
+        lanes = np.asarray(x)[:n, cpt.lanes[0], cpt.lanes[1]]
+        return pad_arrivals(lanes.astype(np.float32, copy=False), n)
+
+    pred = pack(actual if predicted is None else predicted, T + W + 1)
+    act = None if predicted is None else pack(actual, T)
+    q_rem0 = np.zeros(cpt.valid.shape + (W + 1,), np.float32)
+    q_rem0[cpt.lanes[0], cpt.lane_slot] = pred[: W + 1].T
+    return pred, act, q_rem0
+
+
+def _actual_stream(prepped, T: int) -> np.ndarray:
+    """The (T, L) actual stream of a :func:`_prep_streams` result."""
+    pred, act, _ = prepped
+    return pred[:T] if act is None else act
+
+
+def _entry_weights(act: np.ndarray, cpt: _Compact, C: int) -> np.ndarray:
+    """(C, T) actual arrivals per (entry component, slot): the (T, L) stream
+    lanes summed onto their successor components."""
+    onehot = np.zeros((act.shape[1], C), np.float32)
+    onehot[np.arange(act.shape[1]), cpt.lanes[1]] = 1.0
+    return (act @ onehot).T
 
 
 def _aggregate(
@@ -795,24 +909,23 @@ def _aggregate(
 
 
 def _device_inputs(topo: Topology, net: NetworkCosts, cpt: _Compact, service=None):
-    if service is None:
-        inv_service = jnp.ones(topo.n_instances, jnp.float32)
-    else:
-        svc = np.broadcast_to(np.asarray(service, np.float32), (topo.n_instances,))
-        if (svc <= 0).any():
-            raise ValueError("service times must be positive")
-        inv_service = _to_device(1.0 / svc)
-    return dict(
-        U=_to_device(net.U),
-        mu=_to_device(topo.inst_mu, np.float32),
-        inv_service=inv_service,
-        sel_cmp=_to_device(cpt.sel_cmp),
-        stream_cmp=_to_device(cpt.stream_cmp),
-        valid_cmp=_to_device(cpt.valid),
-        succ_map=_to_device(cpt.succ_map),
-        term_f=_to_device(_terminal_mask(topo)),
-        adj_rows=_to_device(cpt.adj_rows),
-    )
+    """The scan's slot-invariant inputs, resident on the device per content."""
+    svc = np.broadcast_to(np.asarray(1.0 if service is None else service, np.float32),
+                          (topo.n_instances,))
+    if (svc <= 0).any():
+        raise ValueError("service times must be positive")
+    return _resident(dict(
+        U=net.U,
+        mu=np.asarray(topo.inst_mu, np.float32),
+        inv_service=1.0 / svc,
+        sel_cmp=cpt.sel_cmp,
+        stream_cmp=cpt.stream_cmp,
+        valid_cmp=cpt.valid,
+        succ_map=cpt.succ_map,
+        term_f=_terminal_mask(topo),
+        adj_rows=cpt.adj_rows,
+        lanes=cpt.lanes,
+    ))
 
 
 def _run_chunked_cohort(
@@ -824,9 +937,8 @@ def _run_chunked_cohort(
     age_cap: int,
     n_components: int,
     shared: bool,
-    act: np.ndarray,  # (T, I, C) if shared else (S, T, I, C) — host-resident
-    pred: np.ndarray,
-    nxt: np.ndarray,
+    pred: np.ndarray,  # (T+W+1, L) if shared else (S, T+W+1, L) — host-resident
+    act: np.ndarray | None,  # (T, L) / (S, T, L); None: perfect prediction
     q0: np.ndarray,  # (I, Sc, W+1) if shared else (S, I, Sc, W+1)
     Vs: list,
     betas: list,
@@ -844,8 +956,10 @@ def _run_chunked_cohort(
     Arrival streams and event traces stay host-resident; each call to
     :func:`_scan_cohort_fused` sees one chunk of slots plus the carried
     queue state (donated buffers), so device memory is bounded by the chunk
-    size, not T. Per-chunk response-accumulator slabs — indexed by
-    chunk-local source slot — are added into full-horizon host arrays at
+    size, not T. A chunk uploads only its rows of the packed stream lanes:
+    slots t0..t1+W of the prediction stream and, where one was given,
+    t0..t1-1 of the actual stream. Per-chunk response-accumulator slabs —
+    indexed by chunk-local source slot — are added into full-horizon host arrays at
     offset ``t0 - age_cap``; columns before source slot 0 are provably zero
     (no mass can predate the run) and are sliced off. Per-slot backlog/cost
     concatenate bitwise across chunk boundaries (the scan body compiles
@@ -860,17 +974,10 @@ def _run_chunked_cohort(
     """
     Sn = len(Vs)
     q0_b = np.broadcast_to(q0, (Sn,) + q0.shape) if shared else q0
-    I, Sc, W1 = q0_b.shape[1:]
-    Atot = age_cap + W1
+    W1 = q0_b.shape[-1]
     f32 = np.float32
     with obs_span("potus/cohort-fused/upload"):
-        carry = (
-            _to_device(q0_b, f32),
-            jnp.zeros((Sn, I, Sc), jnp.float32),
-            jnp.zeros((Sn, I, Atot), jnp.float32),
-            jnp.zeros((Sn, I, Sc, Atot), jnp.float32),
-            jnp.zeros((Sn, I, Atot), jnp.float32),
-        )
+        carry = _initial_carry(_to_device(q0_b, f32), age_cap=age_cap)
         if mesh is not None:
             # place the carry on the mesh up front; chunk inputs get resharded
             # by the jitted scan per its shard_map in_specs
@@ -890,19 +997,16 @@ def _run_chunked_cohort(
     tc = T if chunk is None else int(chunk)
     for t0 in range(0, T, tc) or [0]:
         t1 = min(t0 + tc, T)
-        n = t1 - t0
-        sl = (slice(t0, t1),) if shared else (slice(None), slice(t0, t1))
+        lead = () if shared else (slice(None),)
         with obs_span("potus/cohort-fused/upload"):
-            acc = jnp.zeros((Sn, n_components, n + Atot), jnp.float32)
-            states = carry + (acc, jnp.zeros_like(acc))
             ev_c = None
             if ev_host is not None:
                 esl = (slice(t0, t1),) if ev_shared else (slice(None), slice(t0, t1))
                 ev_c = tuple(_to_device(e[esl]) for e in ev_host)
             kwargs = dict(
-                actual_s=_to_device(act[sl]),
-                pred_s=_to_device(pred[sl]),
-                nxt_s=_to_device(nxt[sl]),
+                pred_s=_to_device_stream(pred[lead + (slice(t0, t1 + W1),)]),
+                actual_s=(None if act is None
+                          else _to_device_stream(act[lead + (slice(t0, t1),)])),
                 Vs=_to_device(Vs, f32),
                 betas=_to_device(betas, f32),
                 events_s=ev_c,
@@ -917,14 +1021,14 @@ def _run_chunked_cohort(
                 **dev,
             )
         if mesh is None and tracing_enabled():
-            _remember_traced_scan(prob, states, dict(kwargs, edges=cpt.edges))
+            _remember_traced_scan(prob, carry, dict(kwargs, edges=cpt.edges))
         with obs_span("potus/cohort-fused/chunk", t0=t0, t1=t1,
                       sharded=mesh is not None):
             if mesh is None:
                 states, ys = _scan_cohort_fused(
-                    prob, states, edges=cpt.edges, **kwargs)
+                    prob, carry, edges=cpt.edges, **kwargs)
             else:
-                states, ys = _scan_cohort_sharded(mesh, prob, states, **kwargs)
+                states, ys = _scan_cohort_sharded(mesh, prob, carry, **kwargs)
         carry = states[:5]
         with obs_span("potus/cohort-fused/fetch"):
             h, cost, capped, served = (_fetch(y) for y in ys[:4])
@@ -1053,19 +1157,18 @@ def _run_cohort_fused_impl(
                 if cfg.scheduler in COMPACT_SCHEDULERS
                 else make_problem(topo, net, inst_container))
         cpt = _compact(topo)
-        mask = _stream_mask(topo)
-        act, pred, nxt, q_rem0 = _prep_streams(actual, predicted, T, W, cpt, mask)
+        packed = _prep_streams(actual, predicted, T, W, cpt)
         ev_host = host_trace(events, T)
     with obs_span("potus/cohort-fused/upload"):
         dev = _device_inputs(topo, net, cpt, service)
     resp_mass, resp_time, backlog, cost, capped, served, streams = _run_chunked_cohort(
         prob, dev, cpt, cfg.scheduler, cfg.use_pallas, age_cap, topo.n_components,
-        True, act, pred, nxt, q_rem0, [cfg.V], [cfg.beta],
+        True, *packed, [cfg.V], [cfg.beta],
         ev_host, True, T, W, chunk, slots_per_launch, mesh=mesh,
         metrics_spec=metrics,
     )
     with obs_span("potus/cohort-fused/reduce"):
-        weights = np.einsum("sic,ic->cs", act, mask)
+        weights = _entry_weights(_actual_stream(packed, T), cpt, topo.n_components)
         sat = float(capped[0]) / max(float(served[0]), 1e-9)
         _maybe_warn_saturation(sat, age_cap,
                                label=f"scheduler={cfg.scheduler} V={cfg.V} W={W}")
@@ -1160,7 +1263,6 @@ def run_fused_sweep(
 
     with obs_span("potus/cohort-fused/prep"):
         cpt = _compact(topo)
-        mask = _stream_mask(topo)
     with obs_span("potus/cohort-fused/upload"):
         dev = _device_inputs(topo, net, cpt, service)
     reach = None
@@ -1179,14 +1281,15 @@ def run_fused_sweep(
         with obs_span("potus/cohort-fused/prep"):
             prob = prob_for(scheduler)
             if shared:  # one prep + one weights matrix for the whole partition
-                prepped = [_prep_streams(*arr_map[group[0].arrival], T, W, cpt, mask)]
-                act_s, pred_s, nxt_s, q0_s = prepped[0]
+                prepped = [_prep_streams(*arr_map[group[0].arrival], T, W, cpt)]
+                pred_s, act_s, q0_s = prepped[0]
             else:
-                prepped = [_prep_streams(*arr_map[scn.arrival], T, W, cpt, mask)
+                prepped = [_prep_streams(*arr_map[scn.arrival], T, W, cpt)
                            for scn in group]
-                act_s, pred_s, nxt_s, q0_s = (
-                    np.stack([p[k] for p in prepped]) for k in range(4)
-                )
+                pred_s = np.stack([p[0] for p in prepped])
+                act_s = (None if all(p[1] is None for p in prepped)
+                         else np.stack([_actual_stream(p, T) for p in prepped]))
+                q0_s = np.stack([p[2] for p in prepped])
             ev_host, ev_shared = None, True
             if has_events:
                 ev_host, ev_shared = stacked_host_traces(
@@ -1195,7 +1298,7 @@ def run_fused_sweep(
                 )
         resp_mass, resp_time, backlog, cost, capped, served, streams = _run_chunked_cohort(
             prob, dev, cpt, scheduler, use_pallas, age_cap,
-            topo.n_components, shared, act_s, pred_s, nxt_s, q0_s,
+            topo.n_components, shared, pred_s, act_s, q0_s,
             [scn.V for scn in group], [scn.beta for scn in group],
             ev_host, ev_shared, T, W, chunk, slots_per_launch, mesh=mesh,
             metrics_spec=metrics,
@@ -1203,7 +1306,8 @@ def run_fused_sweep(
         with obs_span("potus/cohort-fused/reduce"):
             if reach is None:
                 reach = _reachability(topo)
-            weights_s = [np.einsum("sic,ic->cs", p[0], mask) for p in prepped]
+            weights_s = [_entry_weights(_actual_stream(p, T), cpt, topo.n_components)
+                         for p in prepped]
             for s, scn in enumerate(group):
                 sat = float(capped[s]) / max(float(served[s]), 1e-9)
                 _maybe_warn_saturation(

@@ -292,28 +292,40 @@ class TestSpanTracing:
         assert sorted(starts, key=starts.get) == list(self.PHASES)
 
     def test_counters_reckon_the_bytes_from_shapes(self, system):
+        from repro.core import cohort_fused as cf
         from repro.obs import disable_tracing, enable_tracing, take_counters
 
         topo, net, _, _ = system
         I, C, K = topo.n_instances, topo.n_components, net.U.shape[0]
         S = max(len(topo.successors_of_comp(c)) for c in range(C))
+        is_spout = topo.comp_is_spout[topo.inst_comp]
+        L = int((topo.adj[topo.inst_comp] & is_spout[:, None]).sum())  # stream lanes
         age_cap = 64
+        cf._RESIDENT.clear()  # the deployment's constants go up on its first call only
         take_counters()
         enable_tracing()
+        got = []
         try:
-            simulate(_spec(system, engine="cohort-fused", warmup=5, age_cap=age_cap))
+            for _ in range(2):
+                simulate(_spec(system, engine="cohort-fused", warmup=5, age_cap=age_cap))
+                got.append(take_counters())
         finally:
             disable_tracing()
-        got = take_counters()
         f32 = 4
-        h2d = (3 * T * I * C * f32  # act, pred, nxt: (T, I, C) float32
-               + I * S * (W + 1) * f32  # the window's initial contents
-               + 2 * f32  # V, beta
-               + K * K * f32 + I * f32 + 4 * I * S * f32 + I * f32 + I * C * f32  # constants
-               + 3 * I * f32 + C * f32 + I)  # the problem; is_spout is bool
+        packed = (T + W + 1) * L * f32  # one prediction stream: (T+W+1, L) float32
+        per_call = (packed
+                    + I * S * (W + 1) * f32  # the window's initial contents
+                    + 2 * f32)  # V, beta
+        consts = (K * K * f32 + 3 * I * f32  # U; mu, inv_service, term_f
+                  + 4 * I * S * f32 + I * C * f32  # (I, S) step constants; adj_rows
+                  + 2 * L * f32  # the stream lanes, (2, L) int32
+                  + 3 * I * f32 + C * f32 + I)  # the problem; is_spout is bool
         d2h = (2 * T * f32 + 2 * f32  # backlog, cost; capped, served
                + 2 * C * (T + age_cap + W + 1) * f32)  # response accumulators
-        assert got == {"h2d_bytes": h2d, "d2h_bytes": d2h}
+        assert got == [
+            {"h2d_bytes": per_call + consts, "d2h_bytes": d2h, "packed_stream_bytes": packed},
+            {"h2d_bytes": per_call, "d2h_bytes": d2h, "packed_stream_bytes": packed},
+        ]
         assert take_counters() == {}  # read-and-reset
 
     def test_tracing_compiles_nothing_new(self, system):

@@ -75,8 +75,7 @@ def _setup(system, dtype):
     actual = materialize_arrivals(arr, topo, T + W + 1)
     prob = make_problem(topo, net, placement)
     cpt = cf._compact(topo)
-    mask = cf._stream_mask(topo)
-    act, pred, nxt, q_rem0 = cf._prep_streams(actual, None, T, W, cpt, mask)
+    pred_p, _, q_rem0 = cf._prep_streams(actual, None, T, W, cpt)
     dev = cf._device_inputs(topo, net, cpt)
     I, C = topo.n_instances, topo.n_components
     Sc, W1 = q_rem0.shape[1:]
@@ -90,8 +89,9 @@ def _setup(system, dtype):
         jnp.zeros((C, T + Atot), dtype),
         jnp.zeros((C, T + Atot), dtype),
     )
-    xs = (jnp.asarray(act, dtype), jnp.asarray(pred, dtype),
-          jnp.asarray(nxt, dtype), jnp.arange(T))
+    act, pred, nxt = cf._dense_streams(dev["lanes"], jnp.asarray(pred_p, dtype), None,
+                                       W + 1, I, C)
+    xs = (act, pred, nxt, jnp.arange(T))
     V, beta = jnp.asarray(cfg.V, dtype), jnp.asarray(cfg.beta, dtype)
     comp_onehot = jax.nn.one_hot(prob.inst_comp, C, dtype=dtype)
     dev = {k: (v if v.dtype == jnp.int32 else v.astype(dtype))

@@ -87,12 +87,14 @@ def test_fleet_scan_compiles(one_chip):
     topo, net, placement, cpt, dev = _fleet_inputs(I_FLEET)
     I, C = topo.n_instances, topo.n_components
     prob = cf._compact_prob(topo, placement)
-    arr = jax.ShapeDtypeStruct((T, I, C), jnp.float32, sharding=one_chip)
-    states = _shapes(_state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1), one_chip)
+    L = cpt.lanes.shape[1]
+    arr = jax.ShapeDtypeStruct((T + W + 1, L), jnp.float32, sharding=one_chip)
+    carry = _state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1)[:5]  # the queues
+    states = _shapes(carry, one_chip)
     f = partial(cf._scan_cohort_fused, edges=cpt.edges, scheduler="potus",
                 age_cap=AGE_CAP, n_components=C, shared_inputs=True)
     compiled = jax.jit(f).lower(
-        _shapes(prob, one_chip), states, actual_s=arr, pred_s=arr, nxt_s=arr,
+        _shapes(prob, one_chip), states, pred_s=arr, actual_s=None,
         Vs=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip),
         betas=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip),
         **_shapes(dev, one_chip)).compile()
