@@ -1,16 +1,38 @@
 """A deployment, built from its configuration file.
 
 A configuration (``chipbench/configs/<name>.json``) states the whole
-deployment as data: the application DAGs (components, parallelism, per-instance
-capacity ``mu``, successors and selectivities), the transmission budget
-``gamma``, the fabric, the placement (a data file beside the configuration)
-and the utilization the spout rates are set at. Nothing here depends on the
-traffic seed, so a seed can change what arrives but never what is deployed.
+deployment as data. Nothing a deployment holds depends on the traffic seed,
+so a seed can change what arrives but never what is deployed.
 
-``Deployment`` holds the plain arrays the traffic generator and the reference
-read; ``program_inputs`` converts it into the objects the system under test
-takes. The fabric and the rate arithmetic are the benchmark's own copies, so
-the deployment a cell measures cannot move with the program.
+A configuration that names no ``kind`` is the POTUS paper's: layered
+application DAGs (components, parallelism, per-instance capacity ``mu``,
+successors and selectivities) on a fat-tree, the transmission budget
+``gamma``, the placement (a data file beside the configuration) and the
+utilization the spout rates are set at. ``Deployment`` holds the plain
+arrays the traffic generator and the reference read; ``program_inputs``
+converts it into the objects the system under test takes. The fabric and
+the rate arithmetic are the benchmark's own copies, so the deployment a cell
+measures cannot move with the program.
+
+A configuration that names ``"kind": "<kind>"`` brings its own code in
+``chipbench/deployments/<kind>.py``, a new file that needs no edit here:
+
+* ``build(cfg, read_placement)`` returns the deployment. ``cfg`` is the
+  configuration file's object, its ``name`` set to the configuration's name.
+  What it returns carries what the harness reads: ``cfg`` (whose ``kind``
+  leads :func:`program_inputs` and :func:`reference_of` back to the module),
+  ``rates`` ((I, C) mean arrivals per spout stream, which ``traffic.draw``
+  offers) and, for ``chipbench/reference.py`` and ``chipbench/placement.py``,
+  the other fields and properties of :class:`Deployment`. A ``Deployment``
+  carries all of them.
+* ``program_inputs(dep)`` returns ``(topology, network costs, placement)``
+  for the system under test.
+* ``reference``, where the module has one, is the plain reference that
+  ``run.compare`` judges the kind by: an object with ``Model`` and ``run`` of
+  the signatures of ``chipbench/reference.py``, which it takes otherwise. It
+  imports nothing of the program.
+
+A kind whose file is missing fails the run and names the file.
 """
 from __future__ import annotations
 
@@ -20,8 +42,13 @@ import os
 
 import numpy as np
 
+from chipbench import lookup
+from chipbench import reference as plain_reference
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+CONFIGS = os.path.join(HERE, "configs")
+KINDS = os.path.join(HERE, "deployments")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +80,15 @@ class Deployment:
 
 
 def load_config(name: str) -> dict:
-    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
+    with open(os.path.join(CONFIGS, f"{name}.json")) as f:
         return json.load(f)
+
+
+def kind_module(cfg: dict):
+    """The module of the configuration's deployment kind, ``None`` where it
+    names none."""
+    kind = cfg.get("kind")
+    return None if kind is None else lookup.module(KINDS, kind, "deployment kind")
 
 
 def flatten_apps(apps: list) -> dict:
@@ -166,8 +200,17 @@ def utilization_rates(comps: dict, inst_comp: np.ndarray, gamma: float,
 def build_deployment(name: str, read_placement: bool = True) -> Deployment:
     """The deployment of configuration ``name``, its placement read from the
     data file the configuration names (left empty for the placement tool,
-    which computes it)."""
+    which computes it); a kind's ``build`` where the configuration names one."""
     cfg = load_config(name)
+    kind = kind_module(cfg)
+    if kind is not None:
+        return kind.build(dict(cfg, name=name), read_placement)
+    return layered_fat_tree(name, cfg, read_placement)
+
+
+def layered_fat_tree(name: str, cfg: dict, read_placement: bool) -> Deployment:
+    """The paper's kind: the layered DAGs of ``cfg["apps"]`` on the
+    configuration's fat-tree, at its utilization."""
     comps = flatten_apps(cfg["apps"])
     inst_comp = np.repeat(np.arange(len(comps["comp_parallelism"])),
                           comps["comp_parallelism"]).astype(np.int32)
@@ -189,7 +232,18 @@ def build_deployment(name: str, read_placement: bool = True) -> Deployment:
 
 
 def program_inputs(dep: Deployment):
-    """(Topology, NetworkCosts, placement) of the system under test."""
+    """(Topology, NetworkCosts, placement) of the system under test; a
+    kind's ``program_inputs`` where the deployment's configuration names one."""
+    kind = kind_module(dep.cfg)
+    if kind is not None:
+        return kind.program_inputs(dep)
+    return layered_fat_tree_inputs(dep, dep.cfg["apps"])
+
+
+def layered_fat_tree_inputs(dep: Deployment, app_list: list):
+    """The program's inputs for the layered DAGs ``app_list`` (per-app
+    component lists, as a configuration states them) on ``dep``'s fat-tree,
+    at ``dep``'s placement."""
     from repro.core import Component, NetworkCosts, build_topology
 
     apps = [[Component(name=comp["name"], app=a, is_spout=bool(comp["is_spout"]),
@@ -198,7 +252,7 @@ def program_inputs(dep: Deployment):
                        successors=tuple(int(s) for s in comp["successors"]),
                        selectivity=tuple(float(f) for f in comp["selectivity"]))
              for comp in comps]
-            for a, comps in enumerate(dep.cfg["apps"])]
+            for a, comps in enumerate(app_list)]
     topo = build_topology(apps, gamma=dep.gamma)
     if not np.array_equal(topo.inst_comp, dep.inst_comp):
         raise ValueError("the program orders instances differently from the deployment")
@@ -212,3 +266,9 @@ def program_inputs(dep: Deployment):
         U=dep.U,
     )
     return topo, net, dep.placement
+
+
+def reference_of(dep):
+    """The plain reference the deployment is judged by: its kind's
+    ``reference`` where it has one, else ``chipbench/reference.py``."""
+    return getattr(kind_module(dep.cfg), "reference", None) or plain_reference

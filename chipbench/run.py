@@ -3,8 +3,9 @@
     python3 chipbench/run.py --workload NAME --seed N --seconds S --trace 0|1
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
-configuration (``chipbench/configs/<config>.json``: the deployment) and a
-traffic mix (``chipbench/traffic/<traffic>.json``: the ``simulate`` call, its
+configuration (``chipbench/configs/<config>.json``: the deployment, built by
+``chipbench/deploy.py`` or by the code of the kind it names) and a traffic
+mix (``chipbench/traffic/<traffic>.json``: the ``simulate`` call, its
 arrivals, and the limits of the output check). The run
 
 1. refuses to run (exit 2, no result) unless JAX finds a TPU with as many
@@ -17,9 +18,10 @@ arrivals, and the limits of the output check). The run
    ``--seconds`` have passed, and counts the slots of the calls that
    completed (``--trace 1`` runs the window under the profiler and reports
    the per-layer metrics instead of the end-to-end ones);
-4. checks one call, drawn from the seed, against ``chipbench/reference.py``
-   and prints each compared number beside its limit, on standard error and
-   as the ``checks`` key of the result line;
+4. checks one call, drawn from the seed, against the deployment's plain
+   reference (``chipbench/reference.py`` unless its kind brings one) and
+   prints each compared number beside its limit, on standard error and as
+   the ``checks`` key of the result line;
 5. prints one JSON line: ``correct``, ``attempted``, ``failed``,
    ``metrics``, ``device`` and, with ``--trace 1``, ``breakdown``.
 """
@@ -30,7 +32,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -46,7 +47,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 import numpy as np  # noqa: E402
 
-from chipbench import deploy, reference, traffic  # noqa: E402
+from chipbench import deploy, lookup, stages, traffic  # noqa: E402
 from chipbench import trace as trace_reducer  # noqa: E402
 
 SCAN_PROGRAM = "_scan_cohort_fused"  # the jitted scan's stable name
@@ -172,8 +173,8 @@ def ledger_gap(g: dict, offered: float) -> float:
 
 def compare(mix: dict, dep, inputs: dict, got: dict | None = None) -> dict:
     """The numbers the mix's ``limits`` name, between the program's call and
-    the reference (``got=None``: the control, the reference in bfloat16, in
-    the program's place).
+    the deployment's plain reference (``got=None``: the control, the
+    reference in bfloat16, in the program's place).
 
     ``ledger_gap``: mass conservation over all slots (:func:`ledger_gap`).
     ``traj_gap``: the largest relative gap of the per-slot backlog and cost;
@@ -182,6 +183,7 @@ def compare(mix: dict, dep, inputs: dict, got: dict | None = None) -> dict:
     without discrete choices: two sound float32 implementations of POTUS
     break near-ties of prices differently and part from there."""
     names = mix["limits"]
+    reference = deploy.reference_of(dep)
     m = reference.Model(dep)
     kw = dict(T=int(mix["T"]), scheduler=mix["scheduler"], W=int(mix["window"]),
               age_cap=int(mix["age_cap"]), warmup=int(mix["warmup"]), V=float(mix["V"]))
@@ -214,11 +216,7 @@ def judge(numbers: dict, limits: dict) -> tuple[bool, dict]:
 # ---------------------------------------------------------------------------
 
 def _load_reader(name: str):
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"chipbench.metrics.{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return lookup.module(os.path.join(HERE, "metrics"), name, "per-layer metric").read
 
 
 def run(workload: str, seed: int, seconds: float, traced: bool,
@@ -262,6 +260,7 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
     traced_calls = 0
     if traced:
         log_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
+        stages.program_counters()  # reset: the counters cover the traced slice alone
         program_trace.enable_tracing()
         jax.profiler.start_trace(log_dir)
         with jax.profiler.TraceAnnotation(trace_reducer.WINDOW_SPAN):
@@ -289,7 +288,7 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
     if traced:
         reduced = trace_reducer.reduce(trace_reducer.load(log_dir), SCAN_PROGRAM)
         ctx = {"trace": reduced, "slots": traced_calls * slots_per_call,
-               "compiles_in_window": compiles_in_window}
+               "compiles_in_window": compiles_in_window, "log_dir": log_dir}
         metrics = {}
         for pm in bench["per_layer"]:
             if workload in pm.get("workloads", [workload]):

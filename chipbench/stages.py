@@ -20,10 +20,8 @@ each stage's device time within the scan's launches, both inside the
 window span. :func:`of` does all of that once per run for the metric
 readers and adds the program's counters (``repro.obs.trace.take_counters``).
 A program without the spans, scopes or counters reads ``None`` there, not 0.
-
-Where the readers find the trace: ``ctx["log_dir"]`` if the harness gives
-it, else the newest ``chipbench-trace-*`` directory under the temporary
-directory, which is where ``chipbench/run.py`` has the profiler write.
+The readers find the trace in ``ctx["log_dir"]``, where ``chipbench/run.py``
+has the profiler write.
 """
 from __future__ import annotations
 
@@ -35,7 +33,6 @@ import math
 import os
 import re
 import sys
-import tempfile
 from collections import Counter
 
 from chipbench import trace as trace_reducer
@@ -209,11 +206,6 @@ def stage_s(rec: Recording, stages_by_op: dict, w0: float, w1: float,
     return per, module, module - sum(per.values())
 
 
-def newest_trace_dir() -> str | None:
-    dirs = glob.glob(os.path.join(tempfile.gettempdir(), "chipbench-trace-*"))
-    return max(dirs, key=os.path.getmtime) if dirs else None
-
-
 def program_scan_hlo() -> str | None:
     """The program's compiled scan HLO text; ``None`` where the program
     gives none or fails to (a reader reports nothing rather than fail the run)."""
@@ -244,24 +236,22 @@ _memo: dict = {}
 def of(ctx: dict) -> dict | None:
     """Everything the readers need from one traced run, computed once per
     ``ctx``: ``span_self_s``, ``stage_s``, ``scan_s``, ``unclaimed_s`` and
-    ``counters``. ``None`` where no trace is found."""
+    ``counters``. ``None`` where no trace is found in ``ctx["log_dir"]``."""
     key = id(ctx)
     if key in _memo and _memo[key][0] is ctx:
         return _memo[key][1]
-    log_dir = ctx.get("log_dir") or newest_trace_dir()
+    try:
+        rec = load(ctx["log_dir"])
+        w0, w1 = window(rec)
+    except (FileNotFoundError, ValueError):
+        rec = None
     out = None
-    if log_dir:
-        try:
-            rec = load(log_dir)
-            w0, w1 = window(rec)
-        except (FileNotFoundError, ValueError):
-            rec = None
-        if rec is not None:
-            text = program_scan_hlo()
-            per, scan, unclaimed = stage_s(rec, hlo_stages(text) if text else {}, w0, w1)
-            out = {"span_self_s": span_self_s(rec, w0, w1), "stage_s": per,
-                   "scan_s": scan, "unclaimed_s": unclaimed,
-                   "counters": program_counters()}
+    if rec is not None:
+        text = program_scan_hlo()
+        per, scan, unclaimed = stage_s(rec, hlo_stages(text) if text else {}, w0, w1)
+        out = {"span_self_s": span_self_s(rec, w0, w1), "stage_s": per,
+               "scan_s": scan, "unclaimed_s": unclaimed,
+               "counters": program_counters()}
     _memo.clear()
     _memo[key] = (ctx, out)
     return out

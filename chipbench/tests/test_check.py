@@ -46,42 +46,16 @@ def test_program_passes_and_control_fails(config, mix_name, seed):
     assert not ok, checks
 
 
-@pytest.fixture
-def fresh_jit():
-    """A fault patched under a jitted program needs that program traced anew."""
-    import jax
-
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
-
-
 def _run_broken(monkeypatch, cell):
     _short(monkeypatch, cell)
     line, _ = bench.run(cell, SEEDS[0], 0.01, False, require_tpu=False)
     return line
 
 
-def _break_step(monkeypatch, fault):
-    from repro.core import cohort_fused, compact
-
-    step = compact.compact_slot_step
-
-    def broken(c, state, xs, **kw):
-        new, out = step(c, state, xs, **kw)
-        if fault == "unchanged":
-            return state, out
-        if fault == "altered":  # the backlog sample, as the step reports it
-            return new, (out[0] * 1.001,) + tuple(out[1:])
-        raise ValueError(fault)
-
-    monkeypatch.setattr(cohort_fused, "compact_slot_step", broken)
-
-
 @pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", ["unchanged", "altered"])
-def test_a_broken_step_is_not_correct(monkeypatch, fresh_jit, cell, fault):
-    _break_step(monkeypatch, fault)
+def test_a_broken_step_is_not_correct(monkeypatch, break_step, cell, fault):
+    break_step(fault)
     line = _run_broken(monkeypatch, cell)
     assert line["correct"] is False, line["checks"]
 

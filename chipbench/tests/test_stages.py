@@ -3,6 +3,7 @@ synthetic recordings whose answers are known, and on a real profiler trace
 of one short call on the CPU."""
 import importlib
 
+import numpy as np
 import pytest
 
 from chipbench import stages
@@ -194,6 +195,12 @@ def test_a_real_trace_of_one_call(tmp_path):
         assert _reader(name)(ctx) > 0, name
     stage_of_op = stages.hlo_stages(stages.program_scan_hlo())
     assert set(stage_of_op.values()) == {None, *stages.STAGES}
+    # a call uploads the arrivals packed on the spout streams' lanes: T+W+1
+    # rows of one float32 per lane (under perfect prediction the actuals are
+    # those rows), not dense (I, C) arrivals, predictions and next windows
+    counters = stages.of(ctx)["counters"]
+    lanes = np.count_nonzero(dep.rates)
+    assert counters["packed_stream_bytes"] == traffic.n_slots(mix) * lanes * 4
     I, C = dep.rates.shape
-    assert _reader("h2d_bytes_per_slot")(ctx) > 3 * I * C * 4  # act, pred, nxt
-    assert stages.of(ctx)["counters"]["d2h_bytes"] > 0
+    assert _reader("h2d_bytes_per_slot")(ctx) < 3 * I * C * 4
+    assert counters["d2h_bytes"] > 0
