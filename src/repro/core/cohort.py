@@ -70,9 +70,10 @@ class CohortResult:
     # always 0.0 here (the event loop tracks ages exactly); the fused engine
     # (DESIGN.md §8) sets it so callers can tell when age_cap is too shallow
     saturated_frac: float = 0.0
-    # total tuple mass served at terminal bolts over the whole run (warmup
-    # included, phantoms included) — the conservation ledger the disruption
-    # property tests check against injected mass (DESIGN.md §9)
+    # total tuple mass completed over the whole run (warmup included,
+    # phantoms included): served at terminal bolts, plus what a filter acks
+    # without emitting (Topology.comp_done_share) — the conservation ledger
+    # the disruption property tests check against injected mass (DESIGN.md §9)
     completed_mass: float = 0.0
     # selected per-slot obs streams, or None when metrics were off (DESIGN.md §14)
     metrics: MetricsFrame | None = None
@@ -149,6 +150,7 @@ def _run_cohort_sim_impl(
     terminal = set(int(c) for c in topo.terminal_components)
     succ_of = {c: topo.successors_of_comp(c) for c in range(C)}
     sel = topo.selectivity
+    done_share = topo.comp_done_share  # a filter completes what it acks unemitted
     mu = topo.inst_mu
     U = net.U
     u_pair = U[np.ix_(inst_container, inst_container)]
@@ -306,6 +308,13 @@ def _run_cohort_sim_impl(
                     c2 = int(c2)
                     f = sel[ci, c2]
                     q_out[(i, c2)].push({k: m * f for k, m in served.items()})
+                if done_share[ci] > 0.0:  # acked without emitting: completes here
+                    for key, mass in served.items():
+                        part = mass * float(done_share[ci])
+                        completed_mass += part
+                        acc = resp_acc[key][ci]
+                        acc[0] += part
+                        acc[1] += part * max(t - key[1], 0.0)
 
         # -- 5. shift spout windows, load prediction for slot t + W + 1 --------
         # every lookahead position moves one slot closer to current; the

@@ -299,7 +299,7 @@ def _fused_step(
     stream_cmp: jax.Array,  # (I, S)
     valid_cmp: jax.Array,  # (I, S)
     succ_map: jax.Array,  # (I, S) int32
-    term_f: jax.Array,  # (I,) 1.0 on terminal-bolt instances
+    term_f: jax.Array,  # (I,) share of served mass completing (1.0 on terminal bolts)
     comp_onehot: jax.Array,  # (I, C)
     age_cap: int,
     use_pallas: bool,
@@ -510,7 +510,8 @@ def _kernel_launches(consts, state, actual, pred, nxt, scheduler, age_cap,
 
 
 def _step_consts(prob, comp_onehot, U, mu, inv_service, sel_cmp, stream_cmp,
-                 valid_cmp, succ_map, term_f, adj_rows, V, beta) -> StepConsts:
+                 valid_cmp, succ_map, term_f, adj_rows, V, beta,
+                 keyed=None, key_share=None) -> StepConsts:
     return StepConsts(
         U=U, mu=mu, inv_service=inv_service, sel_cmp=sel_cmp,
         stream_cmp=stream_cmp, valid_cmp=valid_cmp, succ_map=succ_map,
@@ -519,7 +520,7 @@ def _step_consts(prob, comp_onehot, U, mu, inv_service, sel_cmp, stream_cmp,
         gamma=prob.gamma,
         comp_count=prob.comp_count.astype(mu.dtype),
         spout_f=prob.is_spout.astype(mu.dtype),
-        adj_rows=adj_rows, V=V, beta=beta,
+        adj_rows=adj_rows, V=V, beta=beta, keyed=keyed, key_share=key_share,
     )
 
 
@@ -582,6 +583,8 @@ def _scan_cohort_fused(
     Vs: jax.Array,  # (S,)
     betas: jax.Array,  # (S,)
     events_s=None,  # (S?, Tc, I) (mu_t, gamma_t, alive_t) triple, or None
+    keyed=None,  # (C,) fields-grouped components (DESIGN.md §15), or None
+    key_share=None,  # (I,) each keyed instance's share, or None
     edges: tuple = (),
     scheduler: str = "potus",
     use_pallas: bool = False,
@@ -632,7 +635,7 @@ def _scan_cohort_fused(
         if compact:
             consts = _step_consts(prob, comp_onehot, U, mu, inv_service, sel_cmp,
                                   stream_cmp, valid_cmp, succ_map, term_f,
-                                  adj_rows, V, beta)
+                                  adj_rows, V, beta, keyed, key_share)
         if kernel_path and ev is None:
             return _kernel_launches(consts, state, actual, pred, nxt,
                                     scheduler, age_cap, slots_per_launch)
@@ -793,10 +796,10 @@ def _scan_cohort_sharded(
 # ---------------------------------------------------------------------------
 
 def _terminal_mask(topo: Topology) -> np.ndarray:
-    term = np.zeros(topo.n_components, bool)
-    term[topo.terminal_components] = True
-    is_spout = topo.comp_is_spout[topo.inst_comp]
-    return (term[topo.inst_comp] & ~is_spout).astype(np.float32)
+    """(I,) share of a bolt's served mass that completes there: 1.0 on
+    terminal bolts, the share a filter acks without emitting
+    (``Topology.comp_done_share``), 0 elsewhere."""
+    return topo.comp_done_share[topo.inst_comp].astype(np.float32)
 
 
 def _reachability(topo: Topology) -> np.ndarray:
@@ -909,11 +912,14 @@ def _aggregate(
 
 
 def _device_inputs(topo: Topology, net: NetworkCosts, cpt: _Compact, service=None):
-    """The scan's slot-invariant inputs, resident on the device per content."""
+    """The scan's slot-invariant inputs, resident on the device per content;
+    a topology with a fields grouping adds its keyed flags and key shares."""
     svc = np.broadcast_to(np.asarray(1.0 if service is None else service, np.float32),
                           (topo.n_instances,))
     if (svc <= 0).any():
         raise ValueError("service times must be positive")
+    keyed = {} if not topo.keyed else dict(keyed=topo.comp_keyed.astype(np.float32),
+                                           key_share=topo.inst_key_share)
     return _resident(dict(
         U=net.U,
         mu=np.asarray(topo.inst_mu, np.float32),
@@ -925,6 +931,7 @@ def _device_inputs(topo: Topology, net: NetworkCosts, cpt: _Compact, service=Non
         term_f=_terminal_mask(topo),
         adj_rows=cpt.adj_rows,
         lanes=cpt.lanes,
+        **keyed,
     ))
 
 
@@ -1247,6 +1254,11 @@ def run_fused_sweep(
     missing = [e for e in spec.events if e not in events_map]
     if missing:
         raise KeyError(f"spec names event scenarios {missing} not present in events_map")
+    from .engine import check_keyed  # lazy: engine imports us
+
+    for scn in scenarios:  # a fields grouping lands on one path only (DESIGN.md §15)
+        check_keyed("cohort-fused", topo, scn.scheduler, scn.use_pallas,
+                    getattr(spec, "sharded", False))
     mesh = None
     if getattr(spec, "sharded", False):
         for scn in scenarios:  # fail before any partition runs — no silent fallback

@@ -59,6 +59,14 @@ bitwise there and on the dyadic tier for any shard count (cross-shard
 ``axis`` and ``kernel_safe`` are mutually exclusive — collectives cannot
 lower into a Pallas body, which is why the megakernel runs per-shard only
 on single-shard meshes (DESIGN.md §13).
+
+**Fields groupings** (DESIGN.md §15): where the problem carries ``keyed``
+(the components whose in-edges are keyed) every scheduler decides on the
+shuffle edges only. A keyed edge ships by Heron's default rule
+(:func:`_ship_amounts_compact`), POTUS water-fills what of gamma the keyed
+edges left, and the keyed mass lands on the component's instances by their
+static ``key_share``. Only the XLA path of one shard implements it; a
+problem without ``keyed`` traces exactly the code it traced before.
 """
 from __future__ import annotations
 
@@ -69,6 +77,7 @@ import jax.numpy as jnp
 
 from repro.obs.metrics import compute_scan_streams, scan_stream_names
 
+from .engine import UnsupportedEngineOption
 from .potus import _fill_components
 
 __all__ = [
@@ -100,6 +109,9 @@ class CompactProblem(NamedTuple):
     comp_count: jax.Array  # (C,) alive instances per component
     adj_rows: jax.Array  # (I, C) 1.0 where comp(i) -> c is a DAG edge
     alive: jax.Array  # (I,) 1.0 on alive instances
+    # fields groupings (DESIGN.md §15); None where no component is keyed
+    keyed: jax.Array | None = None  # (C,) 1.0 where c's in-edges are keyed
+    key_share: jax.Array | None = None  # (I,) share of its keyed component's inflow
 
 
 class CompactDecision(NamedTuple):
@@ -322,6 +334,17 @@ def _ship_amounts_compact(cp, q_out, must_send):
     return jnp.maximum(q_out * scale, must_send)
 
 
+def _keyed_cost(cp, U, key_ship):
+    """Communication cost of the keyed shipments: each lands on instance j
+    of its component at share ``key_share[j]``, so a source in container k
+    pays ``sum_j key_share[j] U[k, k_j]`` per tuple — a (K, C) sum shared
+    by all rows, as :func:`_u_col_sums` is for the even spread."""
+    C = cp.keyed.shape[0]
+    u_key = jnp.zeros((U.shape[0], C), U.dtype).at[:, cp.inst_comp].add(
+        U[:, cp.inst_cont] * cp.key_share[None, :])
+    return (key_ship * u_key[cp.inst_cont]).sum()
+
+
 def _shuffle_decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
                     axis=None, n_shards=1):
     I = cp.inst_comp.shape[0]
@@ -407,13 +430,34 @@ def compact_decide(
     with ``I · n_shards`` as the "no target" sentinel, and ``cost`` is the
     shard-local partial (``compact_slot_step`` folds it). Incompatible with
     ``kernel_safe`` — collectives cannot lower into a Pallas body.
+
+    With ``cp.keyed`` set (DESIGN.md §15) the keyed edges ship by Heron's
+    rule and carry neither a point nor an even part; both ``kernel_safe``
+    and ``axis`` then raise :class:`UnsupportedEngineOption`.
     """
     if axis is not None and kernel_safe:
         raise ValueError("compact_decide: axis (sharded) and kernel_safe are "
                          "mutually exclusive — Pallas bodies cannot contain "
                          "collectives (DESIGN.md §13)")
-    return _DECIDERS[scheduler](cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
-                                axis, n_shards)
+    decide = _DECIDERS[scheduler]
+    if cp.keyed is None:
+        return decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe, axis, n_shards)
+    if kernel_safe or axis is not None:
+        path = "the Pallas slot kernel" if kernel_safe else "the instance-sharded step"
+        raise UnsupportedEngineOption(
+            "cohort-fused", "keyed", reason=f"{path} lands no fields grouping (DESIGN.md §15)")
+    # fields groupings (DESIGN.md §15): the scheduler sees the shuffle edges
+    # only; a keyed edge ships by Heron's rule, and POTUS water-fills what
+    # of gamma the keyed edges left (shuffle and JSQ throttle every edge
+    # together by that rule already)
+    keyed = (cp.adj_rows > 0.0) & (cp.keyed > 0.0)[None, :]
+    key_ship = jnp.where(keyed, _ship_amounts_compact(cp, q_out, must_send), 0.0)
+    steer = cp._replace(adj_rows=jnp.where(keyed, 0.0, cp.adj_rows))
+    if scheduler == "potus":
+        steer = steer._replace(gamma=jnp.maximum(cp.gamma - key_ship.sum(axis=1), 0.0))
+    dec = decide(steer, U, q_in, q_out, must_send, V, beta, kernel_safe, axis, n_shards)
+    return dec._replace(shipped=dec.shipped + key_ship,
+                        cost=dec.cost + _keyed_cost(cp, U, key_ship))
 
 
 # ---------------------------------------------------------------------------
@@ -432,7 +476,7 @@ class StepConsts(NamedTuple):
     stream_cmp: jax.Array  # (I, S)
     valid_cmp: jax.Array  # (I, S)
     succ_map: jax.Array  # (I, S) int32
-    term_f: jax.Array  # (I,)
+    term_f: jax.Array  # (I,) share of served mass completing there
     comp_onehot: jax.Array  # (I, C)
     inst_comp: jax.Array  # (I,) int32
     inst_cont: jax.Array  # (I,) int32
@@ -442,6 +486,8 @@ class StepConsts(NamedTuple):
     adj_rows: jax.Array  # (I, C)
     V: jax.Array  # ()
     beta: jax.Array  # ()
+    keyed: jax.Array | None = None  # (C,) fields-grouped components (DESIGN.md §15)
+    key_share: jax.Array | None = None  # (I,)
 
 
 def _to_dense(c: StepConsts, x_cmp: jax.Array, kernel_safe: bool) -> jax.Array:
@@ -593,12 +639,12 @@ def compact_slot_step(
             if axis is not None:
                 comp_count = jax.lax.psum(comp_count, axis)
             cp = CompactProblem(c.inst_comp, c.inst_cont, gamma_row, comp_count,
-                                c.adj_rows, alive_row)
+                                c.adj_rows, alive_row, c.keyed, c.key_share)
             must_send = must_send * alive_row[:, None]
         else:
             mu_eff = c.mu * c.inv_service
             cp = CompactProblem(c.inst_comp, c.inst_cont, c.gamma, c.comp_count,
-                                c.adj_rows, jnp.ones((I,), dt))
+                                c.adj_rows, jnp.ones((I,), dt), c.keyed, c.key_share)
         dec = compact_decide(scheduler, cp, c.U, q_in_arr, q_out_arr, must_send,
                              c.V, c.beta, kernel_safe, axis, n_shards)
         backlog = _vsum(q_in_arr, kernel_safe) + c.beta * q_out_arr.sum()
@@ -654,6 +700,15 @@ def compact_slot_step(
                 ev_cb = jax.lax.psum(ev_cb, axis)
             ev_rows = ev_cb[c.inst_comp]
         land = land + cp.alive[:, None] * ev_rows
+        if c.keyed is not None:
+            with jax.named_scope("keyed"):
+                # fields groupings: what a source drained toward a keyed
+                # component (its whole shipment there: no point or even
+                # part) lands on instance j at share key_share[j] — the even
+                # spread's per-component contraction, weighted by the share
+                w_key = jnp.where(live & (c.keyed > 0.0)[None, :], 1.0, 0.0).astype(dt)
+                key_cb = jnp.einsum("ic,icb->cb", w_key, d_dense, precision=_HI)
+                land = land + c.key_share[:, None] * key_cb[c.inst_comp]
 
     # -- 4. land last slot's transit, serve bolts ----------------------------
     with jax.named_scope("land"):

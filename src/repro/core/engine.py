@@ -62,6 +62,10 @@ OPTION_SUPPORT = {
     # every engine takes metrics=; *stream* availability is finer-grained
     # (obs.ENGINE_STREAMS) and checked by check_metrics_spec (DESIGN.md §14)
     "metrics": ("jax", "sharded", "cohort", "cohort-fused"),
+    # not a field: a topology with a fields grouping (DESIGN.md §15), which
+    # lands only on cohort-fused's compact XLA step, without Pallas, unsharded
+    # (check_keyed)
+    "keyed": ("cohort-fused",),
 }
 
 #: proximity order used to name the "nearest" supporting engine: the scan
@@ -105,6 +109,28 @@ def check_engine_option(engine: str, option: str) -> None:
     supported = OPTION_SUPPORT.get(option, ENGINES)
     if engine not in supported:
         raise UnsupportedEngineOption(engine, option, supported)
+
+
+def check_keyed(engine: str, topo, scheduler: str, use_pallas: bool,
+                sharded: bool) -> None:
+    """Refuse a topology with a fields grouping (``topo.keyed``, DESIGN.md
+    §15) everywhere but the path that lands one: ``cohort-fused``'s compact
+    XLA step on one shard. Nothing falls back to routing the keyed edges as
+    shuffle edges."""
+    if not getattr(topo, "keyed", False):
+        return
+    check_engine_option(engine, "keyed")
+    from .compact import COMPACT_SCHEDULERS  # lazy: compact imports us
+
+    if use_pallas:
+        why = "the Pallas slot kernel lands no fields grouping"
+    elif sharded:
+        why = "the instance-sharded scan lands no fields grouping"
+    elif scheduler not in COMPACT_SCHEDULERS:
+        why = f"scheduler {scheduler!r} keeps the dense (I, I) path, which has no grouping"
+    else:
+        return
+    raise UnsupportedEngineOption(engine, "keyed", reason=why)
 
 
 def check_metrics_spec(engine: str, metrics):
@@ -184,6 +210,8 @@ class EngineSpec:
                 f"unknown engine {self.engine!r}; expected one of {ENGINES}")
         for option in self._set_options():
             check_engine_option(self.engine, option)
+        check_keyed(self.engine, self.topo, self.scheduler, self.use_pallas,
+                    self.sharded)
 
 
 #: per-process index of :func:`simulate` calls, the request span's identifier

@@ -69,7 +69,7 @@ class EventSimResult:
     q_in_total: np.ndarray  # (T,)
     q_out_total: np.ndarray  # (T,)
     served_total: np.ndarray  # (T,) service completed during (t, t+1]
-    completed_mass: float  # terminal completions over the whole run
+    completed_mass: float  # completions over the whole run (terminals + filters' acks)
     n_events: int  # heap events processed (landings + completions)
 
     @property
@@ -141,6 +141,7 @@ def run_event_sim(
     succ_of = {c: [int(c2) for c2 in topo.successors_of_comp(c)] for c in range(C)}
     targets_of = {c: topo.instances_of(c) for c in range(C)}
     sel = topo.selectivity
+    done_share = topo.comp_done_share  # a filter completes what it acks unemitted
     mu = np.asarray(topo.inst_mu, np.float64)
     U = net.U
     u_pair = U[np.ix_(inst_container, inst_container)]
@@ -183,6 +184,7 @@ def run_event_sim(
         else:
             for c2 in succ_of[ci]:
                 q_out[(i, c2)] += amount * sel[ci, c2]
+            completed_mass += amount * float(done_share[ci])
 
     def integrate(i: int, tau: float) -> None:  # fluid service over (last, tau]
         dt = tau - last_int[i]

@@ -42,6 +42,7 @@ from .engine import (
     OPTION_SUPPORT,
     UnsupportedEngineOption,
     check_engine_option,
+    check_keyed,
     check_metrics_spec,
 )
 from .events import EventTrace, FleetScenario
@@ -296,6 +297,9 @@ def run_sweep(
     ``cohort-fused`` engines, so deep horizons run at fixed device memory.
     """
     scenarios = spec.scenarios()
+    for scn in scenarios:  # a fields grouping lands on one path only (DESIGN.md §15)
+        check_keyed("sharded" if engine == "jax" and scn.sharded else engine, topo,
+                    scn.scheduler, scn.use_pallas, scn.sharded)
     arr_map = _normalize_arrivals(arrivals, spec, topo, T + max(spec.window) + 1)
     ev_map = _normalize_events(events, spec, topo, T, inst_container)
     chunk = (engine_opts or {}).get("chunk")

@@ -11,6 +11,12 @@ Index conventions used across the whole package:
   i  : instance id          in [0, I)
   k  : container id         in [0, K)
   a  : application id       in [0, A)
+
+Groupings (DESIGN.md §15): an edge into a component is a *shuffle* edge —
+any instance of the component may take a tuple, so a scheduler chooses —
+unless the component states ``key_shares``. Then every edge into it is a
+*fields* (key-hash) grouping: a tuple's key names the instance, which takes
+share ``key_shares[j]`` of the component's inflow whatever the queues say.
 """
 from __future__ import annotations
 
@@ -40,6 +46,10 @@ class Component:
     proc_capacity: float = 4.0  # mu: tuples/slot each instance can process
     successors: tuple[int, ...] = ()  # component ids within the same app list
     selectivity: tuple[float, ...] = ()  # tuples emitted to each successor per processed tuple
+    # fields grouping on every edge into this component: instance j takes
+    # share key_shares[j] of the inflow (one share per instance, summing to
+    # 1); empty = shuffle grouping
+    key_shares: tuple[float, ...] = ()
 
 
 @dataclasses.dataclass
@@ -61,6 +71,21 @@ class Topology:
     inst_gamma: np.ndarray  # (I,) float32 — transmission capacity (eq. 1)
 
     comp_names: tuple[str, ...] = ()
+    # fields groupings (DESIGN.md §15): components whose in-edges are keyed,
+    # and each keyed instance's share of its component's inflow (0 elsewhere)
+    comp_keyed: np.ndarray | None = None  # (C,) bool; None = no keyed component
+    inst_key_share: np.ndarray | None = None  # (I,) float32
+
+    def __post_init__(self):
+        if self.comp_keyed is None:
+            self.comp_keyed = np.zeros(self.n_components, bool)
+        if self.inst_key_share is None:
+            self.inst_key_share = np.zeros(self.n_instances, np.float32)
+
+    @property
+    def keyed(self) -> bool:
+        """True where some component's in-edges are fields groupings."""
+        return bool(self.comp_keyed.any())
 
     # ---- derived helpers -------------------------------------------------
     def instances_of(self, c: int) -> np.ndarray:
@@ -84,6 +109,18 @@ class Topology:
     def terminal_components(self) -> np.ndarray:
         return np.nonzero(~self.adj.any(axis=1))[0]
 
+    @property
+    def comp_done_share(self) -> np.ndarray:
+        """(C,) share of each tuple a bolt processes that completes there:
+        1 at a terminal bolt; at a bolt whose selectivities sum below 1 (a
+        filter) the share it acks without emitting, since a tuple tree
+        completes where its last tuple is acked; 0 elsewhere. Sums within
+        1e-6 of 1 count as 1, so flow-conserving splits complete nothing."""
+        short = 1.0 - self.selectivity.astype(np.float64).sum(axis=1)
+        done = np.where(short > 1e-6, short, 0.0)
+        done[self.terminal_components] = 1.0
+        return np.where(self.comp_is_spout, 0.0, done)
+
     def edge_mask_instances(self) -> np.ndarray:
         """(I, I) bool — True where instance i may send tuples to i'."""
         return self.adj[np.ix_(self.inst_comp, self.inst_comp)]
@@ -96,14 +133,16 @@ class Topology:
             out = max(out, int(self.comp_parallelism[succ].sum()))
         return out
 
-    def expected_rates(self, stream_rates: np.ndarray) -> np.ndarray:
+    def expected_rates(self, stream_rates: np.ndarray,
+                       per_instance: bool = False) -> np.ndarray:
         """Propagate expected per-component *processed* tuple rates.
 
         ``stream_rates``: (I, C) — mean arrival rate per (spout instance,
         successor component) stream (λ in the paper). Spouts do not process;
         bolt inflow = direct spout streams + upstream processed × selectivity.
         Returns (C,) expected processed-tuple rate per component (0 for
-        spouts).
+        spouts); with ``per_instance`` the (I,) rate each instance processes:
+        its key share of a keyed component's rate, else an even share.
         """
         C = self.n_components
         rates = np.zeros(C, dtype=np.float64)
@@ -117,6 +156,11 @@ class Topology:
                 if not self.comp_is_spout[p]:
                     inflow += rates[p] * self.selectivity[p, c]
             rates[c] = inflow
+        if per_instance:
+            even = 1.0 / np.maximum(self.comp_parallelism[self.inst_comp], 1)
+            share = np.where(self.comp_keyed[self.inst_comp],
+                             self.inst_key_share.astype(np.float64), even)
+            return rates[self.inst_comp] * share
         return rates
 
 
@@ -143,7 +187,7 @@ def build_topology(apps: Sequence[Sequence[Component]], gamma: float = 8.0) -> T
     Each app is a list of Components whose ``successors`` refer to indices
     *within that app's list*; they are re-based onto global component ids.
     """
-    comp_app, comp_is_spout, comp_par, names = [], [], [], []
+    comp_app, comp_is_spout, comp_par, names, shares = [], [], [], [], []
     edges: list[tuple[int, int, float]] = []
     mu_per_comp: list[float] = []
     base = 0
@@ -154,6 +198,7 @@ def build_topology(apps: Sequence[Sequence[Component]], gamma: float = 8.0) -> T
             comp_par.append(comp.parallelism)
             mu_per_comp.append(comp.proc_capacity)
             names.append(f"app{a}/{comp.name}")
+            shares.append(_key_shares(comp))
             sel = comp.selectivity or tuple(1.0 for _ in comp.successors)
             if len(sel) != len(comp.successors):
                 raise ValueError("selectivity length must match successors")
@@ -169,11 +214,12 @@ def build_topology(apps: Sequence[Sequence[Component]], gamma: float = 8.0) -> T
         selectivity[c, c2] = f
     topo_order(adj)  # validates acyclicity
 
-    inst_comp, inst_mu = [], []
+    inst_comp, inst_mu, inst_share = [], [], []
     for c in range(C):
-        for _ in range(comp_par[c]):
+        for j in range(comp_par[c]):
             inst_comp.append(c)
             inst_mu.append(0.0 if comp_is_spout[c] else mu_per_comp[c])
+            inst_share.append(shares[c][j] if shares[c] else 0.0)
     I = len(inst_comp)
 
     return Topology(
@@ -189,7 +235,25 @@ def build_topology(apps: Sequence[Sequence[Component]], gamma: float = 8.0) -> T
         inst_mu=np.array(inst_mu, dtype=np.float32),
         inst_gamma=np.full((I,), gamma, dtype=np.float32),
         comp_names=tuple(names),
+        comp_keyed=np.array([bool(sh) for sh in shares], dtype=bool),
+        inst_key_share=np.array(inst_share, dtype=np.float32),
     )
+
+
+def _key_shares(comp: Component) -> tuple[float, ...]:
+    """``comp.key_shares``, validated: one non-negative share per instance,
+    summing to 1; a spout takes no in-edges, so it cannot be keyed."""
+    shares = tuple(float(x) for x in comp.key_shares)
+    if not shares:
+        return ()
+    if comp.is_spout:
+        raise ValueError(f"{comp.name}: a spout has no in-edges to key")
+    if len(shares) != comp.parallelism:
+        raise ValueError(f"{comp.name}: {len(shares)} key shares for "
+                         f"parallelism {comp.parallelism}; one per instance")
+    if min(shares) < 0.0 or abs(sum(shares) - 1.0) > 1e-6:
+        raise ValueError(f"{comp.name}: key shares must be >= 0 and sum to 1")
+    return shares
 
 
 # ---------------------------------------------------------------------------

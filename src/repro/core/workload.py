@@ -68,10 +68,14 @@ def spout_rate_matrix(topo: Topology, rate_per_stream: float) -> np.ndarray:
 def feasible_rates(topo: Topology, utilization: float = 0.7) -> np.ndarray:
     """Pick per-stream spout rates so the busiest resource runs at
     ~``utilization`` — both processing (parallelism × mu per component) and
-    transmission (gamma per instance) are respected."""
+    transmission (gamma per instance) are respected. A keyed component
+    (fields grouping) is as busy as its hottest instance: the largest key
+    share of its inflow against one instance's mu and gamma."""
     C = topo.n_components
     unit = spout_rate_matrix(topo, 1.0)  # (I, C) unit per-stream rates
     through = topo.expected_rates(unit)  # (C,) processed rate per comp
+    # (I,) processed rate per instance, key shares counted
+    per_inst = topo.expected_rates(unit, per_instance=True) if topo.keyed else None
 
     worst = 0.0
     for c in range(C):
@@ -80,6 +84,10 @@ def feasible_rates(topo: Topology, utilization: float = 0.7) -> np.ndarray:
             # transmission: per spout instance, total outgoing streams / gamma
             out = unit[inst].sum(axis=1)
             worst = max(worst, float(np.max(out / topo.inst_gamma[inst])))
+        elif topo.comp_keyed[c]:
+            hot = float(per_inst[inst].max())
+            worst = max(worst, hot / max(float(topo.inst_mu[inst[0]]), 1e-9))
+            worst = max(worst, float(hot * topo.selectivity[c].sum() / topo.inst_gamma[inst[0]]))
         else:
             cap = topo.comp_parallelism[c] * float(topo.inst_mu[inst[0]])
             worst = max(worst, through[c] / max(cap, 1e-9))

@@ -56,6 +56,12 @@ _SET_VALUES = {
 }
 
 
+def _keyed_topology():
+    """A spout feeding a component on a fields grouping (DESIGN.md §15)."""
+    return build_topology([[Component("src", 0, True, 2, successors=(1,)),
+                            Component("count", 0, False, 2, 4.0, key_shares=(0.75, 0.25))]])
+
+
 @pytest.fixture(scope="module")
 def system():
     """Dyadic-tier system: pow-2 parallelism, dyadic selectivity, pow-2
@@ -147,8 +153,11 @@ class TestOptionMatrix:
     @pytest.mark.parametrize("engine", ENGINES)
     @pytest.mark.parametrize("option", sorted(OPTION_SUPPORT))
     def test_engine_option_pair(self, engine, option):
-        spec = EngineSpec(topo=None, net=None, placement=None, arrivals=None,
-                          T=T, engine=engine, **{option: _SET_VALUES[option]})
+        # "keyed" is not a field: a topology with a fields grouping sets it
+        kw = ({"topo": _keyed_topology()} if option == "keyed"
+              else {"topo": None, option: _SET_VALUES[option]})
+        spec = EngineSpec(net=None, placement=None, arrivals=None,
+                          T=T, engine=engine, **kw)
         if engine in OPTION_SUPPORT[option]:
             spec.validate()  # supported: no error
         else:

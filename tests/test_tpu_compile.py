@@ -101,6 +101,36 @@ def test_fleet_scan_compiles(one_chip):
     assert _fits(compiled)
 
 
+@pytest.mark.parametrize("scheduler", ["potus", "shuffle"])
+def test_keyed_scan_compiles(one_chip, scheduler):
+    """The compact XLA scan with a fields grouping (DESIGN.md §15), at the
+    Yahoo Streaming Benchmark's shape: five stages of 22 instances on
+    shuffle edges, then 44 keyed tasks; the keyed landing keeps its scope."""
+    from repro.core import Component, build_topology
+
+    stages = [Component(f"s{d}", 0, d == 0, 22, 4.0, successors=(d + 1,)) for d in range(5)]
+    shares = np.bincount(np.arange(100) * 7 % 44, minlength=44) / 100
+    topo = build_topology([stages + [Component("count", 0, False, 44, 4.0,
+                                               key_shares=tuple(shares))]], gamma=24.0)
+    net = container_costs("ysb", fat_tree(4)[0])
+    placement = np.arange(topo.n_instances, dtype=np.int32) % net.n_containers
+    cpt = cf._compact(topo)
+    dev = cf._device_inputs(topo, net, cpt)
+    I, C = topo.n_instances, topo.n_components
+    arr = jax.ShapeDtypeStruct((T + W + 1, cpt.lanes.shape[1]), jnp.float32, sharding=one_chip)
+    f = partial(cf._scan_cohort_fused, edges=cpt.edges, scheduler=scheduler,
+                age_cap=AGE_CAP, n_components=C, shared_inputs=True)
+    compiled = jax.jit(f).lower(
+        _shapes(cf._compact_prob(topo, placement), one_chip),
+        _shapes(_state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1)[:5], one_chip),
+        pred_s=arr, actual_s=None,
+        Vs=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip),
+        betas=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip),
+        **_shapes(dev, one_chip)).compile()
+    assert "keyed" in dev and "/keyed/" in compiled.as_text()
+    assert _fits(compiled)
+
+
 def test_potus_schedule_kernel_compiles(one_chip):
     """The dense Algorithm-1 kernel behind ``use_pallas`` on the scan engines."""
     topo, net, placement, _, _ = _fleet_inputs(I_FLEET)
