@@ -32,7 +32,13 @@ the documented POTUS split caveat, DESIGN.md §12).
 
 Every function here is pure ``jnp`` on plain arrays so the identical code
 runs (a) under the engine's ``lax.scan`` (XLA path) and (b) inside the Pallas
-fused-slot/megakernel bodies (``kernels/potus_slot.py``). ``kernel_safe=True``
+fused-slot/megakernel bodies (``kernels/potus_slot.py``). The XLA path's
+decision moves no value through a per-element index op, which the TPU runs
+one element at a time: it reduces per component with windowed (K, ·)
+scatter-mins and -adds (one index per instance, a column of K each), lifts
+(K, C) tables to rows with row gathers, water-fills with two sorts (one to
+order the components, one to put them back), and spreads (I, S) streams over
+(I, C) with the one-hot sum the kernel path shares. ``kernel_safe=True``
 swaps the ops Mosaic cannot lower — gathers, scatters, ``cumsum``, slices at
 a traced offset and ``lax.sort`` — for masked per-component passes, one-hot
 contractions and placements, and the O(C²) precedence-rank water-fill (the
@@ -232,12 +238,14 @@ def _owner_gather(idx_g: jax.Array, values: jax.Array, off: jax.Array,
 
 
 def _fill_rows_sort(m, j_c, budget, gamma):
-    """(I, C) sort-based water-fill, in component order (XLA path)."""
-    C = m.shape[1]
+    """(I, C) sort-based water-fill, in component order (XLA path). The
+    sorted fill goes back into component order by a second sort keyed on
+    ``perm`` — a permutation, so values move and nothing rounds — rather
+    than a scatter, which the TPU runs one element at a time."""
 
     def one(m_r, j_r, b_r, g_r):
         fill, _, perm = _fill_components(m_r, j_r, b_r, g_r)
-        return jnp.zeros((C,), fill.dtype).at[perm].set(fill)
+        return jax.lax.sort((perm, fill), num_keys=1)[1]
 
     return jax.vmap(one)(m, j_c, budget, gamma)
 
@@ -258,6 +266,20 @@ def _fill_rows_rank(m, j_c, budget, gamma):
     after = before + budget
     g = gamma[:, None]
     return jnp.minimum(after, g) - jnp.minimum(before, g)
+
+
+def _point_price(U, cp, J):
+    """(I, C) = U[k_i, container of J[k_i, c]], the price of each row's
+    point target, read only where its fill is positive (there ``j_c`` is
+    ``J``'s target). The (K, C) table comes from the windowed min that found
+    ``J`` — exactly one column of each component matches — and is lifted to
+    rows by a row gather. Where ``J == I`` it holds ``_BIG``, which the cost
+    multiplies by a zero fill."""
+    I = cp.inst_comp.shape[0]
+    hit = jnp.where(jnp.arange(I, dtype=jnp.int32)[None, :] == J[:, cp.inst_comp],
+                    U[:, cp.inst_cont], _BIG)  # (K, I)
+    u_tgt = jnp.full(J.shape, _BIG, U.dtype).at[:, cp.inst_comp].min(hit)
+    return u_tgt[cp.inst_cont]
 
 
 def _potus_decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
@@ -292,7 +314,7 @@ def _potus_decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
     can_even = edge & (cp.comp_count > 0.0)[None, :]
     shortfall = jnp.where(can_even, jnp.maximum(must_send - fill, 0.0), 0.0)
     even_per = shortfall / jnp.maximum(cp.comp_count, 1.0)[None, :]
-    # cost: the point part gathers U at the target, the even part uses the
+    # cost: the point part reads U at the target, the even part uses the
     # per-component alive-column sum of U — both O(I·C)
     u_sum = _u_col_sums(U, cp, kernel_safe, axis)  # (K, C)
     if axis is not None:
@@ -313,11 +335,10 @@ def _potus_decide(cp, U, q_in, q_out, must_send, V, beta, kernel_safe,
                           axis=1, keepdims=True)  # (K, 1); 0 where J == I
             u_tgt.append(jnp.sum(jnp.where(k_t == iota_k, U, 0.0), axis=1, keepdims=True))
         # fill is 0 wherever j_c is not J's target, so the rows agree with
-        # the gather below on every entry the cost reads
+        # the point price of j_c on every entry the cost reads
         u_point = _rows_of(jnp.concatenate(u_tgt, axis=1), cp.inst_cont, True)
     else:
-        jc_safe = jnp.minimum(j_c, I - 1)
-        u_point = U[cp.inst_cont[:, None], cp.inst_cont[jc_safe]]
+        u_point = _point_price(U, cp, J)
     # under sharding the cost is this shard's partial (rows are local);
     # compact_slot_step psums it with the other slot scalars
     cost = (fill * u_point).sum() + (even_per * _rows_of(u_sum, cp.inst_cont,
@@ -490,17 +511,16 @@ class StepConsts(NamedTuple):
     key_share: jax.Array | None = None  # (I,)
 
 
-def _to_dense(c: StepConsts, x_cmp: jax.Array, kernel_safe: bool) -> jax.Array:
-    """(I, S) -> (I, C); the C sentinel slot contributes nowhere."""
+def _to_dense(c: StepConsts, x_cmp: jax.Array) -> jax.Array:
+    """(I, S) -> (I, C) as a one-hot sum over the static S: a source's
+    streams go to distinct components, so each entry receives one value plus
+    zeros (exact), and the C sentinel slot has no column to land in."""
     I, S = x_cmp.shape
     C = c.comp_onehot.shape[1]
-    if kernel_safe:
-        out = jnp.zeros((I, C), x_cmp.dtype)
-        for s in range(S):  # S is tiny and static
-            out = out + _onehot_cols(c.succ_map[:, s], C, x_cmp.dtype) * x_cmp[:, s:s + 1]
-        return out
-    rows = jnp.arange(I)[:, None]
-    return jnp.zeros((I, C + 1), x_cmp.dtype).at[rows, c.succ_map].add(x_cmp)[:, :C]
+    out = jnp.zeros((I, C), x_cmp.dtype)
+    for s in range(S):  # S is tiny and static
+        out = out + _onehot_cols(c.succ_map[:, s], C, x_cmp.dtype) * x_cmp[:, s:s + 1]
+    return out
 
 
 def _to_dense3(c: StepConsts, x_cmp: jax.Array) -> jax.Array:
@@ -625,8 +645,8 @@ def compact_slot_step(
     with jax.named_scope("decide"):
         q_in_arr = q_in_tag.sum(-1)
         q_out_cmp = jnp.where(spout_f[:, None] > 0, q_rem.sum(-1), q_out_tag.sum(-1))
-        q_out_arr = _to_dense(c, q_out_cmp, kernel_safe)
-        must_send = _to_dense(c, (q_rem[:, :, 0] + admit) * spout_f[:, None], kernel_safe)
+        q_out_arr = _to_dense(c, q_out_cmp)
+        must_send = _to_dense(c, (q_rem[:, :, 0] + admit) * spout_f[:, None])
         if ev:
             mu_row, gamma_row, alive_row = ev[0]
             mu_eff = mu_row * c.inv_service

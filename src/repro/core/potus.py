@@ -220,9 +220,10 @@ def _fill_components(
     """Water-fill ``gamma_i`` against per-component budgets in ascending
     ``(price, index)`` order. Returns ``(fill_sorted, j_sorted, perm)`` where
     ``perm`` maps sorted positions back to component slots, so callers can
-    scatter the fill either onto instance columns (dense X) or back into
-    component order (the compact one-dispatch path, ``core.compact``). Shared
-    by both so the two allocations are identical by construction."""
+    place the fill onto instance columns (dense X, a scatter) or back into
+    component order (the compact one-dispatch path, ``core.compact``, sorts
+    it by ``perm``). Shared by both so the two allocations are identical by
+    construction."""
     C = m.shape[0]
     _, j_sorted, b_sorted, perm = jax.lax.sort(
         (m, j_c, budget, jnp.arange(C, dtype=jnp.int32)), num_keys=2

@@ -8,7 +8,8 @@ topology is described inside a fixture (never while a module is imported),
 and the persistent compile cache is off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
-from functools import partial
+import re
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from benchmarks.systems_bench import _cohort_fleet
 from repro.core import container_costs, fat_tree, make_problem
 from repro.core import cohort_fused as cf
+from repro.core.topology import random_apps
 from repro.kernels.potus_schedule import potus_schedule_call
 from repro.kernels.potus_slot import potus_slot_call
 
@@ -101,11 +103,19 @@ def test_fleet_scan_compiles(one_chip):
     assert _fits(compiled)
 
 
-@pytest.mark.parametrize("scheduler", ["potus", "shuffle"])
-def test_keyed_scan_compiles(one_chip, scheduler):
-    """The compact XLA scan with a fields grouping (DESIGN.md §15), at the
-    Yahoo Streaming Benchmark's shape: five stages of 22 instances on
-    shuffle edges, then 44 keyed tasks; the keyed landing keeps its scope."""
+def _paper_deployment():
+    """The POTUS paper's shape: the seed-0 layered DAGs (I=83, C=27) on a
+    k=4 fat-tree with 2 containers per server (K=32)."""
+    from repro.core import build_topology
+
+    topo = build_topology(random_apps(np.random.default_rng(0)), gamma=24.0)
+    net = container_costs("k4", fat_tree(4)[0], containers_per_server=2)
+    return topo, net, np.arange(topo.n_instances, dtype=np.int32) % net.n_containers
+
+
+def _keyed_deployment():
+    """The Yahoo Streaming Benchmark's shape: five stages of 22 instances on
+    shuffle edges, then 44 tasks on a fields grouping."""
     from repro.core import Component, build_topology
 
     stages = [Component(f"s{d}", 0, d == 0, 22, 4.0, successors=(d + 1,)) for d in range(5)]
@@ -113,22 +123,77 @@ def test_keyed_scan_compiles(one_chip, scheduler):
     topo = build_topology([stages + [Component("count", 0, False, 44, 4.0,
                                                key_shares=tuple(shares))]], gamma=24.0)
     net = container_costs("ysb", fat_tree(4)[0])
-    placement = np.arange(topo.n_instances, dtype=np.int32) % net.n_containers
+    return topo, net, np.arange(topo.n_instances, dtype=np.int32) % net.n_containers
+
+
+_DEPLOYMENTS = {"paper": _paper_deployment, "keyed": _keyed_deployment}
+
+
+@cache
+def _deployment_scan(sharding, deployment, scheduler):
+    """One chunk of the compact XLA scan for ``deployment``, compiled once
+    per module: ``(topology, device inputs, compiled)``."""
+    topo, net, placement = _DEPLOYMENTS[deployment]()
     cpt = cf._compact(topo)
     dev = cf._device_inputs(topo, net, cpt)
     I, C = topo.n_instances, topo.n_components
-    arr = jax.ShapeDtypeStruct((T + W + 1, cpt.lanes.shape[1]), jnp.float32, sharding=one_chip)
+    arr = jax.ShapeDtypeStruct((T + W + 1, cpt.lanes.shape[1]), jnp.float32, sharding=sharding)
     f = partial(cf._scan_cohort_fused, edges=cpt.edges, scheduler=scheduler,
                 age_cap=AGE_CAP, n_components=C, shared_inputs=True)
     compiled = jax.jit(f).lower(
-        _shapes(cf._compact_prob(topo, placement), one_chip),
-        _shapes(_state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1)[:5], one_chip),
+        _shapes(cf._compact_prob(topo, placement), sharding),
+        _shapes(_state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1)[:5], sharding),
         pred_s=arr, actual_s=None,
-        Vs=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip),
-        betas=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip),
-        **_shapes(dev, one_chip)).compile()
+        Vs=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=sharding),
+        betas=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=sharding),
+        **_shapes(dev, sharding)).compile()
+    return topo, dev, compiled
+
+
+@pytest.mark.parametrize("scheduler", ["potus", "shuffle"])
+def test_keyed_scan_compiles(one_chip, scheduler):
+    """The compact XLA scan with a fields grouping (DESIGN.md §15), at the
+    Yahoo Streaming Benchmark's shape; the keyed landing keeps its scope."""
+    _, dev, compiled = _deployment_scan(one_chip, "keyed", scheduler)
     assert "keyed" in dev and "/keyed/" in compiled.as_text()
     assert _fits(compiled)
+
+
+_INDEX_OP = re.compile(r"= (\w+)\[([\d,]*)\]\S* (gather|scatter)\((.*)$")
+
+
+def _per_element_index_ops(hlo_text, scope, sizes):
+    """The gathers and scatters under ``scope`` that move one element per
+    index into or out of an array of one of ``sizes`` elements (flattened or
+    not): a gather whose slice is a single element, a scatter with no update
+    window. Row gathers and windowed per-component scatters move a row or a
+    column per index and are not listed."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INDEX_OP.search(line)
+        if m is None or scope not in line:
+            continue
+        n = int(np.prod([int(d) for d in m.group(2).split(",") if d]))
+        if m.group(3) == "gather":
+            per_element = re.search(r"slice_sizes=\{[1,]*\}", m.group(4)) is not None
+        else:
+            per_element = "update_window_dims={}" in m.group(4)
+        if per_element and n in sizes:
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("deployment", ["paper", "keyed"])
+def test_decide_has_no_per_element_index_op(one_chip, deployment):
+    """The compact POTUS decision (DESIGN.md §12.2) places streams, unsorts
+    the water-fill and prices the point target without a per-element
+    scatter or gather on an (I, C)- or (I, C+1)-sized array: the TPU runs
+    those one index at a time, and they were most of the decision's time."""
+    topo, _, compiled = _deployment_scan(one_chip, deployment, "potus")
+    I, C = topo.n_instances, topo.n_components
+    text = compiled.as_text()
+    assert "/decide/" in text
+    assert _per_element_index_ops(text, "/decide/", {I * C, I * (C + 1)}) == []
 
 
 def test_potus_schedule_kernel_compiles(one_chip):
