@@ -1,6 +1,6 @@
 """Smoke run of the fused cohort engine and the served path on one TPU.
 
-    python chip_smoke.py             # phases (a)-(d) on one chip
+    python chip_smoke.py             # phases (a)-(c) on one chip
     python chip_smoke.py --chips 4   # the instance-sharded engine on four chips
 
 Every phase drives the system through the entry points a user calls
@@ -19,10 +19,6 @@ Every phase drives the system through the entry points a user calls
 (b) the same fleet under ``k_failures`` (the caps fold of the compact step).
 (c) the served path: ``PotusDispatcher`` + ``ReplicaFleet`` of R=64
     ``SimReplica``s; tokens dispatched = served + backlog + in flight.
-(d) the Pallas slot kernel compiled for the chip (``use_pallas=True``) at the
-    largest fleet it fits, against the compact XLA step on a dyadic system:
-    backlog trajectories bitwise equal, and the kernel lowered to a
-    ``tpu_custom_call``.
 
 With ``--chips 4`` only the sharded engine runs: ``sharded=True`` at
 I=131072 over the four chips, against the same spec unsharded on one chip.
@@ -47,7 +43,6 @@ import traceback
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
 I_FLEET = 16384  # phases (a), (b)
-I_KERNEL = 256  # phase (d): the largest _cohort_fleet size the slot kernel fits
 I_SHARDED = 131072  # --chips 4: the weak-scaling tier's fleet
 T, W, AGE_CAP, V = 128, 4, 32, 2.0
 # arrivals stop DRAIN > W slots before the horizon, so the last slot's
@@ -134,34 +129,6 @@ def fleet(I, seed=0, T=T, utilization=0.85):
     rng = np.random.default_rng(seed)
     placement = rng.integers(0, net.n_containers, topo.n_instances).astype(np.int32)
     arr = poisson_arrivals(rng, feasible_rates(topo, utilization), T + W + 1)
-    arr[T - DRAIN:] = 0.0
-    return topo, net, placement, arr
-
-
-def dyadic_fleet(I, seed=0, T=T):
-    """The fleet's shape with every quantity a dyadic rational: 4 chains
-    with (I/16, I/8, I/16) instances, power-of-two arrivals and capacities,
-    so compact XLA and kernel arithmetic are exact and must agree bitwise."""
-    import numpy as np
-
-    from repro.core import (Component, build_topology, container_costs, fat_tree,
-                            spout_rate_matrix)
-
-    per = I // 4
-    apps = [[Component("src", a, True, parallelism=per // 4, successors=(1,)),
-             Component("serve", a, False, parallelism=per // 2, proc_capacity=1.0,
-                       successors=(2,)),
-             Component("sink", a, False, parallelism=per // 4, proc_capacity=2.0)]
-            for a in range(4)]
-    topo = build_topology(apps, gamma=32.0)
-    sd, _ = fat_tree(4)
-    net = container_costs(f"dyadic-fleet-{I}", sd, containers_per_server=8)
-    rng = np.random.default_rng(seed)
-    placement = rng.integers(0, net.n_containers, topo.n_instances).astype(np.int32)
-    unit = spout_rate_matrix(topo, 1.0)
-    arr = (2.0 ** rng.integers(0, 3, size=(T + W + 1, *unit.shape))).astype(np.float32)
-    arr *= rng.random(arr.shape) < 0.8
-    arr = (arr * (unit > 0)).astype(np.float32)
     arr[T - DRAIN:] = 0.0
     return topo, net, placement, arr
 
@@ -325,48 +292,6 @@ def phase_served(R=R_SERVE, T_serve=T_SERVE, seed=7):
     return ph.ok
 
 
-def phase_kernel(I=I_KERNEL):
-    """(d): the slot kernel compiled for the chip vs the compact XLA step."""
-    import numpy as np
-
-    import jax
-
-    from repro.core import simulate
-    from repro.kernels import ops as kops
-    from repro.kernels import pallas_interpret
-    from repro.kernels.potus_slot import potus_slot_call
-
-    with Phase("d:slot-kernel") as ph:
-        system = dyadic_fleet(I)
-        ph.values["I"] = system[0].n_instances
-        seen = []
-        real = kops.potus_slot_step
-
-        def spy(*args, **kw):  # the engine traces the kernel path through here
-            seen.append((args, kw))
-            return real(*args, **kw)
-
-        kops.potus_slot_step = spy
-        try:
-            kern = ph.run("kernel", lambda: simulate(spec(system, "potus", use_pallas=True)))
-        finally:
-            kops.potus_slot_step = real
-        xla = ph.run("xla", lambda: simulate(spec(system, "potus")))
-        check_result(ph, "kernel", kern)
-        check_result(ph, "xla", xla)
-        ph.check("kernel:traced", len(seen) > 0)
-        ph.check("kernel:compiled", not pallas_interpret())
-        if seen:
-            args, kw = seen[0]
-            shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
-            text = jax.jit(lambda *a: potus_slot_call(*a, **kw)).lower(*shapes).as_text()
-            ph.check("kernel:tpu_custom_call", "tpu_custom_call" in text)
-        ph.check("backlog:bitwise", np.array_equal(kern.backlog, xla.backlog))
-        ph.check("cost:close", np.allclose(kern.comm_cost, xla.comm_cost, rtol=0, atol=1e-4))
-        ph.check("avg_response:close", _close(kern.avg_response, xla.avg_response, 1e-5))
-    return ph.ok
-
-
 def phase_sharded(I=I_SHARDED, T_sh=96):
     """--chips 4: ``sharded=True`` over every chip vs the spec unsharded on
     one chip; each chip holds I/n rows of the queue state."""
@@ -450,7 +375,7 @@ def main(argv=None) -> int:
     else:
         phases = [lambda: phase_fleet("a:fleet", I_FLEET),
                   lambda: phase_fleet("b:k-failures", I_FLEET, kfail_trace),
-                  phase_served, phase_kernel]
+                  phase_served]
     ok = True
     for run in phases:
         try:

@@ -33,9 +33,9 @@ State per scenario:
 FIFO ``drain(amount)`` becomes a masked prefix-sum along the age axis
 ("water-fill over ages": ``clip(amount - cum_before, 0, bucket)``), window
 reconciliation (TP/FP/TN mis-prediction splitting, phantom pre-serves,
-admission backlog) becomes pure array ops, and the drain + split is
-optionally fused into one VMEM pass by the Pallas kernel
-``kernels/cohort_drain.py`` (behind ``use_pallas``).
+admission backlog) becomes pure array ops. On the dense ``potus-loop``
+reference path the drain + split is optionally fused into one VMEM pass by
+the Pallas kernel ``kernels/cohort_drain.py`` (behind ``use_pallas``).
 
 Deliberate deltas vs the Python engine, documented in DESIGN.md §8: queues
 serve oldest-*source-slot*-first instead of oldest-*push*-first (identical
@@ -467,48 +467,6 @@ def _fused_step(
     return state, out
 
 
-def _kernel_launches(consts, state, actual, pred, nxt, scheduler, age_cap,
-                     slots_per_launch):
-    """Drive one scenario's chunk through the Pallas slot kernel: a
-    ``lax.scan`` of K-slot megakernel launches plus one ragged-tail launch
-    (DESIGN.md §12). Shared by the dense scan and the single-shard sharded
-    scan — the kernel body contains no collectives, so under ``shard_map``
-    it only runs when the mesh has one shard (DESIGN.md §13)."""
-    from repro.kernels import ops as kops
-
-    T = actual.shape[0]
-    K = max(1, slots_per_launch)
-    nb, tail = T // K, T % K
-
-    def launch(state, xs_b, n_slots):
-        act_b, pred_b, nxt_b, t0 = xs_b
-        return kops.potus_slot_step(
-            consts, state, act_b, pred_b, nxt_b, t0,
-            scheduler=scheduler, age_cap=age_cap, n_slots=n_slots,
-        )
-
-    mets = []
-    if nb:
-        blk = (actual[: nb * K].reshape(nb, K, *actual.shape[1:]),
-               pred[: nb * K].reshape(nb, K, *pred.shape[1:]),
-               nxt[: nb * K].reshape(nb, K, *nxt.shape[1:]),
-               jnp.arange(nb, dtype=jnp.int32) * K)
-        state, m = jax.lax.scan(partial(launch, n_slots=K), state, blk)
-        mets.append(jax.tree.map(lambda y: y.reshape(nb * K), m))
-    if tail:
-        state, m = launch(
-            state,
-            (actual[nb * K:], pred[nb * K:], nxt[nb * K:], jnp.int32(nb * K)),
-            n_slots=tail,
-        )
-        mets.append(m)
-    backlog, cost, capped, served = (
-        jax.tree.map(lambda *ys: jnp.concatenate(ys), *mets)
-        if len(mets) > 1 else mets[0]
-    )
-    return state, (backlog, cost, capped.sum(), served.sum())
-
-
 def _step_consts(prob, comp_onehot, U, mu, inv_service, sel_cmp, stream_cmp,
                  valid_cmp, succ_map, term_f, adj_rows, V, beta,
                  keyed=None, key_share=None) -> StepConsts:
@@ -563,7 +521,7 @@ def _initial_carry(q_rem0: jax.Array, age_cap: int):
 
 @partial(jax.jit, static_argnames=("edges", "scheduler", "use_pallas", "age_cap",
                                    "n_components", "shared_inputs", "events_shared",
-                                   "slots_per_launch", "metrics_spec"),
+                                   "metrics_spec"),
          donate_argnames=("states",))
 def _scan_cohort_fused(
     prob,
@@ -592,7 +550,6 @@ def _scan_cohort_fused(
     n_components: int = 1,
     shared_inputs: bool = False,
     events_shared: bool = False,
-    slots_per_launch: int = 1,
     metrics_spec=None,  # static MetricsSpec | None (DESIGN.md §14)
 ):
     """Scan one chunk of slots for every scenario in the batch.
@@ -608,24 +565,15 @@ def _scan_cohort_fused(
     Scheduler routing (DESIGN.md §12): every scheduler in
     :data:`~repro.core.compact.COMPACT_SCHEDULERS` runs the one-dispatch
     :func:`~repro.core.compact.compact_slot_step` — no (I, I) tensor in the
-    slot loop, and price computation batches across the vmapped sweep axis.
-    Under ``use_pallas`` the POTUS step additionally fuses into the
-    ``kernels/potus_slot.py`` slot kernel (``slots_per_launch`` slots per
-    launch — the megakernel); the kernel falls back to the compact XLA step
-    when a disruption trace is present (per-slot caps re-fold the problem).
-    ``potus-loop`` keeps the dense reference path (and, under ``use_pallas``,
-    the ``cohort_drain`` kernel).
+    slot loop, and price computation batches across the vmapped sweep axis;
+    ``use_pallas`` changes nothing there. ``potus-loop`` keeps the dense
+    reference path (and, under ``use_pallas``, the ``cohort_drain`` kernel).
     """
     actual_s, pred_s, nxt_s = _dense_streams(lanes, pred_s, actual_s, states[0].shape[-1],
                                              mu.shape[0], n_components)
     states = _with_accumulators(states, nxt_s.shape[-3], age_cap, n_components)
     comp_onehot = jax.nn.one_hot(prob.inst_comp, n_components, dtype=mu.dtype)
     compact = scheduler in COMPACT_SCHEDULERS
-    # metrics never ride the kernel path: stream reductions (sorts) cannot
-    # lower into the Pallas slot kernel, so metrics-on falls back to the
-    # compact XLA step (metrics=None keeps the kernel — zero-cost-when-off)
-    kernel_path = (compact and use_pallas and scheduler == "potus"
-                   and events_s is None and metrics_spec is None)
     if not compact:
         sched = _get_scheduler(scheduler, use_pallas)
         u_pair = U[prob.inst_container[:, None], prob.inst_container[None, :]]
@@ -636,10 +584,7 @@ def _scan_cohort_fused(
             consts = _step_consts(prob, comp_onehot, U, mu, inv_service, sel_cmp,
                                   stream_cmp, valid_cmp, succ_map, term_f,
                                   adj_rows, V, beta, keyed, key_share)
-        if kernel_path and ev is None:
-            return _kernel_launches(consts, state, actual, pred, nxt,
-                                    scheduler, age_cap, slots_per_launch)
-        if compact:
+
             def step(st, x):
                 return compact_slot_step(consts, st, x, scheduler=scheduler,
                                          age_cap=age_cap,
@@ -665,7 +610,7 @@ def _scan_cohort_fused(
 
 @partial(jax.jit, static_argnames=("mesh", "scheduler", "use_pallas", "age_cap",
                                    "n_components", "shared_inputs", "events_shared",
-                                   "slots_per_launch", "metrics_spec"),
+                                   "metrics_spec"),
          donate_argnames=("states",))
 def _scan_cohort_sharded(
     mesh,
@@ -692,7 +637,6 @@ def _scan_cohort_sharded(
     n_components: int = 1,
     shared_inputs: bool = False,
     events_shared: bool = False,
-    slots_per_launch: int = 1,
     metrics_spec=None,  # static MetricsSpec | None (DESIGN.md §14)
 ):
     """:func:`_scan_cohort_fused` over an instance mesh (DESIGN.md §13).
@@ -709,12 +653,9 @@ def _scan_cohort_sharded(
 
     Requires ``scheduler in COMPACT_SCHEDULERS`` (the dense ``potus-loop``
     reference path materializes (I, I) and is rejected upstream with
-    ``UnsupportedEngineOption``). Under ``use_pallas`` the slot kernel runs
-    per-shard **only on a 1-shard mesh** — Pallas bodies cannot contain
-    collectives — and silently falls back to the compact XLA step on
-    multi-shard meshes (the documented megakernel fallback, DESIGN.md §13).
-    On a 1-shard mesh every collective is the identity, so this path is
-    bitwise-equal to the dense scan there.
+    ``UnsupportedEngineOption``); ``use_pallas`` changes nothing here. On a
+    1-shard mesh every collective is the identity, so this path is
+    bitwise-equal to the unsharded scan there.
     """
     if scheduler not in COMPACT_SCHEDULERS:
         raise ValueError(
@@ -726,8 +667,6 @@ def _scan_cohort_sharded(
     actual_s, pred_s, nxt_s = _dense_streams(lanes, pred_s, actual_s, states[0].shape[-1],
                                              mu.shape[0], n_components)
     states = _with_accumulators(states, nxt_s.shape[-3], age_cap, n_components)
-    kernel_path = (use_pallas and scheduler == "potus" and events_s is None
-                   and n_shards == 1 and metrics_spec is None)
 
     def local(prob_l, states_l, U, mu, inv_service, sel_cmp, stream_cmp,
               valid_cmp, succ_map, term_f, adj_rows, actual_l, pred_l, nxt_l,
@@ -740,9 +679,6 @@ def _scan_cohort_sharded(
             consts = _step_consts(prob_l, comp_onehot, U, mu, inv_service,
                                   sel_cmp, stream_cmp, valid_cmp, succ_map,
                                   term_f, adj_rows, V, beta)
-            if kernel_path and ev_one is None:
-                return _kernel_launches(consts, state, actual, pred, nxt,
-                                        scheduler, age_cap, slots_per_launch)
 
             def step(st, x):
                 return compact_slot_step(consts, st, x, scheduler=scheduler,
@@ -954,7 +890,6 @@ def _run_chunked_cohort(
     T: int,
     W: int,
     chunk: int | None,
-    slots_per_launch: int = 1,
     mesh=None,  # instance mesh -> _scan_cohort_sharded (DESIGN.md §13)
     metrics_spec=None,  # static MetricsSpec | None (DESIGN.md §14)
 ):
@@ -1023,7 +958,6 @@ def _run_chunked_cohort(
                 age_cap=age_cap,
                 n_components=n_components,
                 shared_inputs=shared,
-                slots_per_launch=slots_per_launch,
                 metrics_spec=metrics_spec,
                 **dev,
             )
@@ -1116,7 +1050,6 @@ def _run_cohort_fused_impl(
     events=None,  # EventTrace | None — disruption trace (core.events, DESIGN.md §9)
     service=None,  # (I,) | scalar — per-tuple service time in mu units (DESIGN.md §10)
     chunk: int | None = None,  # streaming scan: device slots per chunk (DESIGN.md §11.2)
-    slots_per_launch: int = 1,  # megakernel: slots fused per kernel launch (DESIGN.md §12)
     sharded: bool = False,  # shard the scan over an instance mesh (DESIGN.md §13)
     mesh=None,  # explicit mesh override (tests/benchmarks); implies sharded
     metrics=None,  # MetricsSpec | None — in-scan metric streams (DESIGN.md §14)
@@ -1144,8 +1077,6 @@ def _run_cohort_fused_impl(
         raise ValueError(f"age_cap must be >= 2, got {age_cap}")
     if chunk is not None and chunk <= 0:
         raise ValueError(f"chunk must be a positive slot count, got {chunk}")
-    if slots_per_launch < 1:
-        raise ValueError(f"slots_per_launch must be >= 1, got {slots_per_launch}")
     if mesh is None and sharded:
         mesh = instance_mesh(topo.n_instances)
     if mesh is not None:
@@ -1171,7 +1102,7 @@ def _run_cohort_fused_impl(
     resp_mass, resp_time, backlog, cost, capped, served, streams = _run_chunked_cohort(
         prob, dev, cpt, cfg.scheduler, cfg.use_pallas, age_cap, topo.n_components,
         True, *packed, [cfg.V], [cfg.beta],
-        ev_host, True, T, W, chunk, slots_per_launch, mesh=mesh,
+        ev_host, True, T, W, chunk, mesh=mesh,
         metrics_spec=metrics,
     )
     with obs_span("potus/cohort-fused/reduce"):
@@ -1228,7 +1159,6 @@ def run_fused_sweep(
     events_map: dict | None = None,  # name -> EventTrace|None, from sweep normalization
     service=None,  # (I,) | scalar — per-tuple service time in mu units (DESIGN.md §10)
     chunk: int | None = None,  # streaming scan: device slots per chunk (DESIGN.md §11.2)
-    slots_per_launch: int = 1,  # megakernel: slots fused per kernel launch (DESIGN.md §12)
     metrics=None,  # MetricsSpec | None — per-scenario metric streams (DESIGN.md §14)
 ) -> tuple[list[CohortResult], int]:
     """Run a whole :class:`repro.core.sweep.SweepSpec` grid on the fused
@@ -1245,8 +1175,6 @@ def run_fused_sweep(
     (DESIGN.md §13)."""
     if age_cap < 2:
         raise ValueError(f"age_cap must be >= 2, got {age_cap}")
-    if slots_per_launch < 1:
-        raise ValueError(f"slots_per_launch must be >= 1, got {slots_per_launch}")
     scenarios = spec.scenarios()
     # raising lookup, like arr_map: a named trace missing from the map is a
     # caller error, not an undisturbed run silently labeled as disturbed
@@ -1312,7 +1240,7 @@ def run_fused_sweep(
             prob, dev, cpt, scheduler, use_pallas, age_cap,
             topo.n_components, shared, pred_s, act_s, q0_s,
             [scn.V for scn in group], [scn.beta for scn in group],
-            ev_host, ev_shared, T, W, chunk, slots_per_launch, mesh=mesh,
+            ev_host, ev_shared, T, W, chunk, mesh=mesh,
             metrics_spec=metrics,
         )
         with obs_span("potus/cohort-fused/reduce"):

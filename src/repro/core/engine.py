@@ -2,7 +2,7 @@
 
 The four engines grew their own spellings of the same knobs — ``run_sim``
 takes ``chunk=``/``events=``/``mu=``, ``run_cohort_fused`` takes
-``service=``/``age_cap=``/``slots_per_launch=``, the sharded engine hides
+``service=``/``age_cap=``, the sharded engine hides
 behind ``SimConfig.sharded`` — and each rejected the options it lacks with an
 ad-hoc message (or silently ignored them). This module is the single front
 door:
@@ -55,7 +55,6 @@ OPTION_SUPPORT = {
     "drain_margin": ("cohort", "cohort-fused"),
     "service": ("cohort-fused",),
     "age_cap": ("cohort-fused",),
-    "slots_per_launch": ("cohort-fused",),
     # engine="sharded" *is* sharded; on cohort-fused the flag shards the
     # compact scan over the instance mesh (DESIGN.md §13)
     "sharded": ("sharded", "cohort-fused"),
@@ -63,7 +62,7 @@ OPTION_SUPPORT = {
     # (obs.ENGINE_STREAMS) and checked by check_metrics_spec (DESIGN.md §14)
     "metrics": ("jax", "sharded", "cohort", "cohort-fused"),
     # not a field: a topology with a fields grouping (DESIGN.md §15), which
-    # lands only on cohort-fused's compact XLA step, without Pallas, unsharded
+    # lands only on cohort-fused's compact step, without Pallas, unsharded
     # (check_keyed)
     "keyed": ("cohort-fused",),
 }
@@ -115,7 +114,7 @@ def check_keyed(engine: str, topo, scheduler: str, use_pallas: bool,
                 sharded: bool) -> None:
     """Refuse a topology with a fields grouping (``topo.keyed``, DESIGN.md
     §15) everywhere but the path that lands one: ``cohort-fused``'s compact
-    XLA step on one shard. Nothing falls back to routing the keyed edges as
+    step on one shard. Nothing falls back to routing the keyed edges as
     shuffle edges."""
     if not getattr(topo, "keyed", False):
         return
@@ -123,7 +122,7 @@ def check_keyed(engine: str, topo, scheduler: str, use_pallas: bool,
     from .compact import COMPACT_SCHEDULERS  # lazy: compact imports us
 
     if use_pallas:
-        why = "the Pallas slot kernel lands no fields grouping"
+        why = "no Pallas kernel lands a fields grouping"
     elif sharded:
         why = "the instance-sharded scan lands no fields grouping"
     elif scheduler not in COMPACT_SCHEDULERS:
@@ -182,7 +181,6 @@ class EngineSpec:
     warmup: int = 50
     drain_margin: int | None = None
     age_cap: int = 64
-    slots_per_launch: int = 1  # megakernel slots per launch (DESIGN.md §12)
     sharded: bool = False  # shard cohort-fused over the instance mesh (DESIGN.md §13)
     metrics: Any = None  # MetricsSpec | stream names | True (DESIGN.md §14)
 
@@ -256,6 +254,5 @@ def _simulate(spec: EngineSpec):
         spec.topo, spec.net, spec.placement, spec.arrivals, spec.predicted,
         spec.T, cfg, warmup=spec.warmup, drain_margin=spec.drain_margin,
         age_cap=spec.age_cap, events=spec.events, service=spec.service,
-        chunk=spec.chunk, slots_per_launch=spec.slots_per_launch,
-        sharded=spec.sharded, metrics=metrics,
+        chunk=spec.chunk, sharded=spec.sharded, metrics=metrics,
     )

@@ -336,12 +336,9 @@ def run_sweep(
             check_engine_option("cohort", "service")
         if opts.get("chunk") is not None:
             check_engine_option("cohort", "chunk")
-        if opts.get("slots_per_launch", 1) != 1:
-            check_engine_option("cohort", "slots_per_launch")
         opts.pop("service", None)
         opts.pop("chunk", None)
         opts.pop("age_cap", None)  # the event loop tracks ages exactly
-        opts.pop("slots_per_launch", None)  # fused-engine launch knob
         results = []
         for scn in scenarios:
             actual, predicted = arr_map[scn.arrival]

@@ -8,10 +8,7 @@ Layouts are adapted from model-native (B, S, H, D) to kernel-native
 (B, H, S, D).
 
 None of the kernels contain cross-device collectives, so under ``shard_map``
-they operate on the local shard only. The sharded cohort engine (DESIGN.md
-§13) therefore launches ``potus_slot_step`` only on single-shard meshes,
-where the per-slot decision needs no fold; multi-shard runs use the compact
-XLA step whose ``pmin``/``psum`` fold lowers outside any kernel.
+they operate on the local shard only.
 """
 from __future__ import annotations
 
@@ -22,12 +19,11 @@ from .decode_attention import decode_attention_call
 from .flash_attention import flash_attention_call
 from .potus_price import potus_price_call
 from .potus_schedule import potus_schedule_call
-from .potus_slot import potus_slot_call
 from .ssd_scan import ssd_intra_chunk_call
 
 __all__ = [
     "flash_attention", "decode_attention", "ssd_intra_chunk", "potus_price",
-    "potus_schedule_alloc", "cohort_drain_split", "potus_slot_step",
+    "potus_schedule_alloc", "cohort_drain_split",
 ]
 
 
@@ -59,19 +55,6 @@ def potus_schedule_alloc(U, q_in, q_out, inst_container, inst_comp, edge_mask, g
     before the mandatory dispatch of actual arrivals."""
     return potus_schedule_call(
         U, q_in, q_out, inst_container, inst_comp, edge_mask, gamma, V, beta)
-
-
-def potus_slot_step(consts, state, act, pred, nxt, t0, *, scheduler="potus",
-                    age_cap=64, n_slots=1):
-    """Fused one-dispatch slot step (DESIGN.md §12): schedule + drain + split
-    + serve + queue/age-mass update for ``n_slots`` consecutive slots in one
-    Pallas launch. ``n_slots > 1`` is the megakernel (double-buffered queue
-    state, see ``kernels/potus_slot.py``). Returns ``(state, metrics)`` with
-    per-slot ``metrics = (backlog, cost, capped, served)``."""
-    return potus_slot_call(
-        consts, state, act, pred, nxt, t0, scheduler=scheduler,
-        age_cap=age_cap, n_slots=n_slots,
-    )
 
 
 def cohort_drain_split(src_ext, shipped, ratio, inst_comp, age_bucket):

@@ -44,8 +44,7 @@ def run_cohort_sim(topo, net, placement, arrivals, predicted, T, cfg,
 
 def run_cohort_fused(topo, net, placement, arrivals, predicted, T, cfg,
                      warmup=50, drain_margin=None, age_cap=64, events=None,
-                     service=None, chunk=None, slots_per_launch=1,
-                     sharded=False):
+                     service=None, chunk=None, sharded=False):
     """The fused cohort engine via the facade."""
     kw = {}
     if chunk is not None:
@@ -53,6 +52,5 @@ def run_cohort_fused(topo, net, placement, arrivals, predicted, T, cfg,
     return simulate(_base(topo, net, placement, arrivals, T, cfg,
                           "cohort-fused", predicted=predicted, warmup=warmup,
                           drain_margin=drain_margin, age_cap=age_cap,
-                          events=events, service=service,
-                          slots_per_launch=slots_per_launch, sharded=sharded,
+                          events=events, service=service, sharded=sharded,
                           **kw))

@@ -329,8 +329,8 @@ class TestFusedSweep:
 class TestPallasDrain:
     def test_use_pallas_invokes_kernel_and_matches(self, dyadic_system):
         """``potus-loop`` keeps the dense reference path, whose ``use_pallas``
-        hot op is the drain+split kernel (compact schedulers route to the
-        fused slot kernel instead, DESIGN.md §12)."""
+        hot op is the drain+split kernel (the compact schedulers have no
+        kernel path, DESIGN.md §12)."""
         import repro.kernels.ops as kops
         from repro.core.cohort_fused import _scan_cohort_fused
 
@@ -362,40 +362,6 @@ class TestPallasDrain:
         finally:
             kops.cohort_drain_split = orig
 
-    def test_use_pallas_potus_routes_to_slot_kernel(self, dyadic_system):
-        """``potus`` + ``use_pallas`` runs the fused one-dispatch slot kernel
-        — one launch per slot block — and matches the XLA path bitwise on the
-        dyadic tier (POTUS' proportional split is the one non-dyadic value)."""
-        import repro.kernels.ops as kops
-        from repro.core.cohort_fused import _scan_cohort_fused
-
-        topo, net, placement = dyadic_system
-        Tp = 40
-        arr = _pow2_arrivals(topo, Tp + 8, seed=5)
-        calls = {"n": 0}
-        orig = kops.potus_slot_step
-
-        def spy(*args, **kwargs):
-            calls["n"] += 1
-            return orig(*args, **kwargs)
-
-        kops.potus_slot_step = spy
-        try:
-            _scan_cohort_fused.clear_cache()
-            cfg = SimConfig(V=2.0, window=1)
-            plain = run_cohort_fused(topo, net, placement, arr, None, Tp, cfg,
-                                     age_cap=16)
-            assert calls["n"] == 0
-            via = run_cohort_fused(topo, net, placement, arr, None, Tp,
-                                   SimConfig(V=2.0, window=1, use_pallas=True),
-                                   age_cap=16)
-            assert calls["n"] > 0, "use_pallas=True never reached the slot kernel"
-            np.testing.assert_allclose(via.backlog, plain.backlog, rtol=0, atol=1e-4)
-            np.testing.assert_allclose(via.comm_cost, plain.comm_cost, rtol=1e-6,
-                                       atol=1e-4)
-        finally:
-            kops.potus_slot_step = orig
-
     def test_kernel_matches_xla_reference(self):
         """Direct kernel parity on random (non-contiguous-component) inputs."""
         import jax.numpy as jnp
@@ -422,37 +388,33 @@ class TestPallasDrain:
 
 
 # ---------------------------------------------------------------------------
-# megakernel differential: dyadic bitwise tier across use_pallas
+# chunked differential: dyadic tier against the event-loop oracle
 # ---------------------------------------------------------------------------
 
-class TestMegakernelDifferential:
-    """The dyadic bitwise tier extended across ``use_pallas`` with the
-    multi-slot megakernel enabled: the Python event-loop oracle, the compact
-    XLA scan, and K-slots-per-launch Pallas kernel must agree on trajectories
-    (POTUS within the documented 1-ulp split tolerance). Nightly runs this
-    class by name (``-k megakernel``)."""
+class TestChunkedDifferential:
+    """The dyadic tier across chunk boundaries inside the lookahead window:
+    the compact scan streamed 1, 4 or 7 slots at a time (W=2, so chunks of
+    1 end inside every slot's window) must agree with the Python event-loop
+    oracle on trajectories (POTUS within the documented 1-ulp split
+    tolerance)."""
 
-    @pytest.mark.parametrize("slots_per_launch", [1, 4, 7])
-    def test_megakernel_bitwise_dyadic(self, dyadic_system, slots_per_launch):
+    @pytest.mark.parametrize("chunk", [1, 4, 7])
+    def test_chunked_bitwise_dyadic(self, dyadic_system, chunk):
         topo, net, placement = dyadic_system
         Tm = 120
         arr = _pow2_arrivals(topo, Tm + 16, seed=3)
         cfg = SimConfig(V=2.0, beta=0.5, window=2, scheduler="potus")
         py = run_cohort_sim(topo, net, placement, arr, None, Tm, cfg)
-        mk = run_cohort_fused(
-            topo, net, placement, arr, None, Tm,
-            SimConfig(V=2.0, beta=0.5, window=2, scheduler="potus",
-                      use_pallas=True),
-            slots_per_launch=slots_per_launch,
-        )
-        np.testing.assert_allclose(mk.backlog, py.backlog, rtol=0, atol=1e-4)
-        np.testing.assert_allclose(mk.comm_cost, py.comm_cost, rtol=0, atol=1e-4)
-        assert mk.avg_response == pytest.approx(py.avg_response, rel=0.02, abs=0.05)
+        fu = run_cohort_fused(topo, net, placement, arr, None, Tm, cfg, chunk=chunk)
+        np.testing.assert_allclose(fu.backlog, py.backlog, rtol=0, atol=1e-4)
+        np.testing.assert_allclose(fu.comm_cost, py.comm_cost, rtol=0, atol=1e-4)
+        assert fu.avg_response == pytest.approx(py.avg_response, rel=0.02, abs=0.05)
 
-    @pytest.mark.parametrize("scheduler", ["shuffle", "jsq"])
+    @pytest.mark.parametrize("scheduler", ["potus", "shuffle", "jsq"])
     def test_compact_path_exact_across_use_pallas(self, dyadic_system, scheduler):
-        """Shuffle/JSQ have no Pallas slot kernel — ``use_pallas`` is a no-op
-        on their compact path, so the two flags must match bit for bit."""
+        """No compact scheduler has a Pallas kernel path — ``use_pallas`` is
+        a no-op on the compact scan, so the two flags must match bit for
+        bit."""
         topo, net, placement = dyadic_system
         Tm = 120
         arr = _pow2_arrivals(topo, Tm + 16, seed=3)

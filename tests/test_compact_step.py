@@ -1,17 +1,17 @@
-"""Fused one-dispatch slot kernel vs the unfused composition (DESIGN.md §12).
+"""The compact one-dispatch slot step against the unfused composition
+(DESIGN.md §12).
 
-The kernel body *is* ``core.compact.compact_slot_step`` with the kernel-safe
-op substitutions, so parity is tested at three levels, in interpret mode:
+``core.compact.compact_slot_step`` is the one step every compact scheduler
+scans. It is checked
 
 * against the **unfused dense composition** (``cohort_fused._fused_step``:
-  separate schedule, drain+split, and queue-update stages) — the refactor's
-  ground truth;
-* against the **compact XLA scan** (same step, ``kernel_safe=False``) — pins
-  down the one-hot-contraction / precedence-rank substitutions, bitwise on
-  the dyadic tier;
-* in **f32 and f64** — the kernel is dtype-generic; f64 runs under the x64
+  separate schedule, drain+split and queue-update stages), scanned whole
+  and in 4-slot pieces with the state carried between them;
+* in **f32 and f64**: the step is dtype-generic, and f64 runs under the x64
   switch and must agree with the f64 unfused composition to tight relative
-  tolerance (catching any accidental f32 truncation inside the kernel).
+  tolerance (catching any accidental f32 truncation inside the step);
+* across **piece boundaries**: for every compact scheduler, the scan in
+  4-slot pieces is bitwise the whole scan.
 """
 from functools import partial
 
@@ -33,7 +33,6 @@ from repro.core import cohort_fused as cf
 from repro.core import compact as cm
 from repro.core.potus import make_problem
 from repro.core.simulator import _get_scheduler, materialize_arrivals
-from repro.kernels import ops as kops
 
 T = 40
 AGE_CAP = 16
@@ -110,13 +109,13 @@ def _setup(system, dtype):
     return prob, cpt, dev, consts, state0, xs, V, beta, comp_onehot
 
 
-def _run_dense(system, dtype):
+def _run_dense(system, dtype, scheduler="potus"):
     """The unfused composition: schedule -> drain+split -> update as separate
     stages of ``cohort_fused._fused_step``."""
     prob, cpt, dev, consts, state0, xs, V, beta, comp_onehot = _setup(system, dtype)
     u_pair = dev["U"][prob.inst_container[:, None], prob.inst_container[None, :]]
     step = partial(
-        cf._fused_step, prob, _get_scheduler("potus", False), cpt.edges,
+        cf._fused_step, prob, _get_scheduler(scheduler, False), cpt.edges,
         dev["U"], u_pair, dev["mu"], dev["inv_service"], dev["sel_cmp"],
         dev["stream_cmp"], dev["valid_cmp"], dev["succ_map"], dev["term_f"],
         comp_onehot, AGE_CAP, False, V, beta,
@@ -124,26 +123,21 @@ def _run_dense(system, dtype):
     return jax.lax.scan(step, state0, xs)
 
 
-def _run_compact(system, dtype, scheduler="potus"):
+def _run_compact(system, dtype, scheduler="potus", piece=None):
+    """The compact scan over all T slots, or ``piece`` slots at a time with
+    the state (accumulators included) carried from piece to piece."""
     prob, cpt, dev, consts, state0, xs, V, beta, _ = _setup(system, dtype)
     step = partial(cm.compact_slot_step, consts, scheduler=scheduler,
                    age_cap=AGE_CAP)
-    return jax.lax.scan(lambda s, x: step(s, x), state0, xs)
-
-
-def _run_kernel(system, dtype, n_slots, scheduler="potus"):
-    prob, cpt, dev, consts, state0, xs, V, beta, _ = _setup(system, dtype)
-    act, pred, nxt, _ = xs
-    state = state0
-    mets = []
-    for t0 in range(0, T, n_slots):
-        n = min(n_slots, T - t0)
-        state, met = kops.potus_slot_step(
-            consts, state, act[t0:t0 + n], pred[t0:t0 + n], nxt[t0:t0 + n],
-            jnp.int32(t0), scheduler=scheduler, age_cap=AGE_CAP, n_slots=n,
-        )
-        mets.append(met)
-    return state, tuple(np.concatenate([np.asarray(m[i]) for m in mets])
+    scan = jax.jit(lambda s, x: jax.lax.scan(step, s, x))
+    if piece is None:
+        fin, out = scan(state0, xs)
+        return fin, tuple(np.asarray(o) for o in out)
+    state, outs = state0, []
+    for t0 in range(0, T, piece):
+        state, out = scan(state, tuple(x[t0:t0 + piece] for x in xs))
+        outs.append(out)
+    return state, tuple(np.concatenate([np.asarray(o[i]) for o in outs])
                         for i in range(4))
 
 
@@ -152,44 +146,33 @@ def _assert_state_close(a, b, rtol, atol):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=rtol, atol=atol)
 
 
-class TestSlotKernelParity:
-    @pytest.mark.parametrize("n_slots", [1, 4])
-    def test_f32_kernel_vs_unfused_composition(self, system, n_slots):
-        fin_d, out_d = _run_dense(system, jnp.float32)
-        fin_k, out_k = _run_kernel(system, jnp.float32, n_slots)
+class TestCompactStepParity:
+    @pytest.mark.parametrize("piece", [None, 4])
+    @pytest.mark.parametrize("scheduler", ["potus", "shuffle", "jsq"])
+    def test_f32_vs_unfused_composition(self, system, scheduler, piece):
+        fin_d, out_d = _run_dense(system, jnp.float32, scheduler)
+        fin_c, out_c = _run_compact(system, jnp.float32, scheduler, piece=piece)
         # POTUS' proportional split is the one non-dyadic value (atol 1e-4,
         # same tier as tests/test_cohort_fused.py)
-        for a, b in zip(out_d[:2], out_k[:2]):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-4)
-        _assert_state_close(fin_k, fin_d, rtol=1e-5, atol=1e-4)
+        for a, b in zip(out_d[:2], out_c[:2]):
+            np.testing.assert_allclose(np.asarray(a), b, rtol=1e-6, atol=1e-4)
+        _assert_state_close(fin_c, fin_d, rtol=1e-5, atol=1e-4)
 
-    @pytest.mark.parametrize("scheduler", ["potus", "shuffle", "jsq"])
-    def test_f32_kernel_vs_compact_scan_bitwise(self, system, scheduler):
-        """Same step, kernel-safe substitutions only: dyadic-tier bitwise."""
-        fin_c, out_c = _run_compact(system, jnp.float32, scheduler)
-        fin_k, out_k = _run_kernel(system, jnp.float32, 4, scheduler)
-        np.testing.assert_array_equal(np.asarray(out_c[0]), out_k[0])  # backlog
-        atol = 1e-4 if scheduler == "potus" else 0.0
-        np.testing.assert_allclose(np.asarray(out_c[1]), out_k[1], rtol=0, atol=atol)
-        _assert_state_close(fin_k, fin_c, rtol=0, atol=atol)
-
-    @pytest.mark.parametrize("n_slots", [1, 4])
-    def test_f64_kernel_vs_unfused_composition(self, system, n_slots):
+    @pytest.mark.parametrize("piece", [None, 4])
+    def test_f64_vs_unfused_composition(self, system, piece):
         with jax.enable_x64(True):
             fin_d, out_d = _run_dense(system, jnp.float64)
-            fin_k, out_k = _run_kernel(system, jnp.float64, n_slots)
-            assert fin_k[0].dtype == jnp.float64  # no silent f32 truncation
-            for a, b in zip(out_d[:2], out_k[:2]):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           rtol=1e-12, atol=1e-9)
-            _assert_state_close(fin_k, fin_d, rtol=1e-10, atol=1e-9)
+            fin_c, out_c = _run_compact(system, jnp.float64, piece=piece)
+            assert all(x.dtype == jnp.float64 for x in fin_c)  # no f32 truncation
+            for a, b in zip(out_d[:2], out_c[:2]):
+                np.testing.assert_allclose(np.asarray(a), b, rtol=1e-12, atol=1e-9)
+            _assert_state_close(fin_c, fin_d, rtol=1e-10, atol=1e-9)
 
-    def test_megakernel_matches_single_slot_launches(self, system):
-        """K-slot double-buffered launches == K single-slot launches, bitwise
-        (the double-buffer parity walk changes no arithmetic)."""
-        fin_1, out_1 = _run_kernel(system, jnp.float32, 1)
-        fin_k, out_k = _run_kernel(system, jnp.float32, 7)
-        for a, b in zip(out_1, out_k):
+    @pytest.mark.parametrize("scheduler", ["potus", "shuffle", "jsq"])
+    def test_pieces_bitwise_equal_whole_scan(self, system, scheduler):
+        """Cutting the scan into 4-slot pieces changes no arithmetic."""
+        fin_w, out_w = _run_compact(system, jnp.float32, scheduler)
+        fin_p, out_p = _run_compact(system, jnp.float32, scheduler, piece=4)
+        for a, b in zip(out_w, out_p):
             np.testing.assert_array_equal(a, b)
-        _assert_state_close(fin_k, fin_1, rtol=0, atol=0)
+        _assert_state_close(fin_p, fin_w, rtol=0, atol=0)

